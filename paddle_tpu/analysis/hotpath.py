@@ -3,7 +3,7 @@
 Seeds are every function the codebase hands to a tracing transform —
 ``jax.jit`` (call or decorator, incl. ``functools.partial(jax.jit, …)``),
 ``pl.pallas_call``, ``jax.custom_vjp``/``defvjp``, ``jax.grad``/
-``value_and_grad``/``vjp``, ``shard_map``/``_shard_map_call``, the
+``value_and_grad``/``vjp``, ``shard_map``, the
 ``lax`` control-flow combinators — and the walk follows local calls,
 ``self.method`` calls, and imports resolvable inside the linted tree
 (``serving/engine.py → models/gpt.py`` etc.). Inside a reachable body:
@@ -36,8 +36,7 @@ _TRACE_ATTRS = {
     "cond", "custom_jvp",
 }
 _TRACE_BARE = {"jit", "pallas_call", "custom_vjp", "shard_map",
-               "_shard_map", "_shard_map_call", "value_and_grad",
-               "checkpoint", "remat"}
+               "value_and_grad", "checkpoint", "remat"}
 # which positional args of each transform are traced functions
 _FN_ARG_POS = {
     "cond": (1, 2), "fori_loop": (2,), "while_loop": (0, 1),
@@ -62,8 +61,7 @@ def _is_trace_call(call: ast.Call) -> Optional[str]:
     if isinstance(f, ast.Attribute) and f.attr in _TRACE_ATTRS:
         return f.attr
     if isinstance(f, ast.Name) and f.id in _TRACE_BARE:
-        return f.id if f.id not in ("_shard_map", "_shard_map_call") \
-            else "shard_map"
+        return f.id
     return None
 
 

@@ -2,9 +2,10 @@
 
 The reference binds its C++ core with pybind11 (paddle/fluid/pybind/
 pybind.cc); this environment has no pybind11, so the native library exports
-a C ABI consumed here via ctypes. The .so is lazy-built with the Makefile
-on first import; if the toolchain is unavailable the pure-Python fallbacks
-below keep the API working (slower, same semantics).
+a C ABI consumed here via ctypes. The .so is built with the Makefile at
+import whenever it is missing or older than its source; if the toolchain
+is unavailable the pure-Python fallbacks below keep the API working
+(slower, same semantics) and a RuntimeWarning says so.
 """
 from __future__ import annotations
 
@@ -13,25 +14,47 @@ import os
 import subprocess
 import threading
 import time
+import warnings
 from contextlib import contextmanager
 from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "lib", "libptpu_core.so")
+_SRC_PATH = os.path.join(_DIR, "csrc", "ptpu_core.cc")
 
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _lib_is_stale() -> bool:
+    """True when the .so is missing or older than its source. The .so is
+    not tracked by git, so a checkout has none and a copied working tree
+    may carry one built from an older csrc."""
+    try:
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return True
+
+
 def _build_and_load() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_LIB_PATH):
+    if _lib_is_stale():
         try:
             subprocess.run(["make", "-C", _DIR, "-s"], check=True,
                            capture_output=True, timeout=120)
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            # the pure-Python fallbacks keep the API working; a stale .so
+            # is never loaded in place of a failed rebuild
+            stderr = (getattr(e, "stderr", None) or b"").decode(
+                errors="replace").strip()
+            warnings.warn("paddle_tpu native core not built (%s); using "
+                          "the pure-Python fallbacks" % (stderr or e),
+                          RuntimeWarning, stacklevel=2)
             return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    except OSError as e:
+        warnings.warn("paddle_tpu native core not loaded (%s); using the "
+                      "pure-Python fallbacks" % e, RuntimeWarning,
+                      stacklevel=2)
         return None
     # signatures
     lib.ptpu_last_error.restype = ctypes.c_char_p
@@ -197,33 +220,9 @@ shardy = [_truthy(os.environ.get("FLAGS_shardy", "1"))]
 def apply_shardy_flag() -> None:
     """Push the cell value into jax's global lowering config (called at
     paddle_tpu import and from set_flags)."""
-    try:
-        import jax
+    import jax
 
-        jax.config.update("jax_use_shardy_partitioner", bool(shardy[0]))
-    except Exception:  # noqa: BLE001 — older jax without the option
-        pass
-
-
-@contextmanager
-def shardy_disabled():
-    """Trace/lower with the legacy GSPMD partitioner regardless of
-    FLAGS_shardy. Needed around host-callback ops (jax.pure_callback /
-    jax.debug.print): jax 0.4.x's callback lowering predates Shardy and
-    dies with `'OpSharding' object has no attribute 'build'` when the sdy
-    dialect is active."""
-    try:
-        import jax
-
-        prev = bool(jax.config.jax_use_shardy_partitioner)
-    except Exception:  # noqa: BLE001
-        yield
-        return
-    try:
-        jax.config.update("jax_use_shardy_partitioner", False)
-        yield
-    finally:
-        jax.config.update("jax_use_shardy_partitioner", prev)
+    jax.config.update("jax_use_shardy_partitioner", bool(shardy[0]))
 
 
 def _int_or_zero(value) -> int:

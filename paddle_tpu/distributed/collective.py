@@ -155,8 +155,6 @@ def _eager_fn(kind, axis, mesh, extra=None):
     """Build + cache the jitted shard_map program for an eager collective.
     The mesh itself is part of the cache key — two meshes with the same
     axis name/size but different device layouts must not share programs."""
-    from jax.experimental.shard_map import shard_map
-
     spec = P(axis)
 
     if kind == "all_reduce":
@@ -191,8 +189,8 @@ def _eager_fn(kind, axis, mesh, extra=None):
     else:  # pragma: no cover
         raise ValueError(kind)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                             check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False))
 
 
 def _run_eager(kind, arr, group, opname, extra=None):
@@ -458,7 +456,7 @@ def barrier(group=None):
 
         multihost_utils.sync_global_devices("paddle_tpu.distributed.barrier")
         return
-    jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+    jax.effects_barrier()
     (jnp.zeros(()) + 0).block_until_ready()
 
 

@@ -8,6 +8,10 @@ failure) and the elastic relaunch loop (fleet/elastic/manager.py:103).
 TPU-native process model: ONE worker process per HOST drives all local
 chips (the reference's one-proc-per-GPU maps to jax's one-proc-per-host);
 ``--nproc_per_node`` exists for CPU rehearsal and multi-host emulation.
+A chip belongs to one process at a time, so on a host with TPU chips more
+than one worker is refused unless the workers are pinned off the TPU
+(``JAX_PLATFORMS=cpu``). The launcher itself never touches jax: a parent
+that initialized a backend would hold the chips its worker needs.
 Workers get the jax.distributed coordinator env (the TCP bootstrap that
 replaces the reference's gen_comm_id_helper NCCL-id rendezvous) plus the
 PADDLE_* variables reference role-makers read. ``--elastic`` enables
@@ -18,6 +22,7 @@ framework/checkpoint.py CheckpointManager.restore_latest).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -87,10 +92,28 @@ class Pod:
                 p.kill()
 
 
+def _check_one_process_per_chip(nproc: int) -> None:
+    """Refuse several workers on a TPU host: they would all open the same
+    chips, and all but one fail or hang. Chips are found by their device
+    nodes, so this parent stays off jax."""
+    if nproc <= 1:
+        return
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return  # workers inherit a pin to another platform (CPU rehearsal)
+    if glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"):
+        raise RuntimeError(
+            f"--nproc_per_node={nproc} on a host with TPU chips: every "
+            "worker would open the same chips, and a chip belongs to one "
+            "process. Run one worker (it drives all local chips), or set "
+            "JAX_PLATFORMS=cpu for a CPU rehearsal.")
+
+
 def start_pod(script: List[str], nproc: int, log_dir: Optional[str] = None,
               extra_env_of_rank=None) -> Pod:
     """Spawn nproc workers with cluster env (reference
     start_local_trainers)."""
+    _check_one_process_per_chip(nproc)
     coordinator = f"127.0.0.1:{_free_port()}"
     endpoints = [f"127.0.0.1:{_free_port()}" for _ in range(nproc)]
     procs, logs = [], []

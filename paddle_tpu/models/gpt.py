@@ -24,6 +24,7 @@ Design notes (TPU):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -314,7 +315,21 @@ def _attention(cfg: GPTConfig, q, k, v):
                                           and not cfg.remat))))
     if use_flash:
         from ..ops.flash_attention import flash_attention_arrays
-        return flash_attention_arrays(q, k, v, causal=True, scale=scale)
+        from ..parallel.mesh import get_mesh
+
+        flash = functools.partial(flash_attention_arrays, causal=True,
+                                  scale=scale)
+        mesh = get_mesh()
+        if _on_tpu() and mesh is not None and mesh.size > 1:
+            # GSPMD cannot partition a Mosaic kernel (jax raises at
+            # lowering), so on a multi-chip mesh each device runs the
+            # kernel on its own shard: batch over the Fleet batch axes,
+            # heads over "model" — the layout gpt_param_specs and
+            # DistributedTrainStep's default batch_spec already produce
+            spec = P(("data", "sharding"), "model", None, None)
+            flash = jax.shard_map(flash, mesh=mesh, in_specs=(spec,) * 3,
+                                  out_specs=spec)
+        return flash(q, k, v)
     return _attention_reference(q, k, v, causal=True, scale=scale)
 
 

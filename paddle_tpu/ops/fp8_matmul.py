@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from ..monitor.stats import FP8_MATMUL_CALLS
 from . import autotune as _autotune
-from .flash_attention import _compiler_params, _on_tpu
+from .flash_attention import _on_tpu
 
 __all__ = ["fp8_matmul_arrays", "E4M3_MAX"]
 
@@ -107,8 +107,8 @@ def _fp8_matmul_2d(xq, wq, sx, sw, bias, out_dtype, interpret=False,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(sc, xq, wq, b2)
     return out[:M]

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from . import autotune as _autotune
-from .flash_attention import _compiler_params, _on_tpu
+from .flash_attention import _on_tpu
 
 __all__ = ["fused_ln_mlp", "fused_add_layernorm"]
 
@@ -311,8 +311,8 @@ def _fmlp_forward(x2, lns, lnb, w1, b1, w2, b2, wg, bg, act, residual,
                    pl.BlockSpec((br, 1), lambda i, j: (i, 0))),
         scratch_shapes=[pltpu.VMEM((br, H), x2.dtype),
                         pltpu.VMEM((br, H), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(x2, lns, lnb, w1, b1, w2, b2, wg, bg)
     return y, mu, rstd
@@ -364,8 +364,8 @@ def _fmlp_backward(x2, lns, lnb, w1, b1, w2, wg, bg, mu, rstd, dy2,
                         pltpu.VMEM((1, bj), jnp.float32),
                         pltpu.VMEM((H, bj), jnp.float32),
                         pltpu.VMEM((1, bj), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(x2, lns, lnb, w1, b1, w2, wg, bg, mu, rstd, dy2)
 
@@ -377,8 +377,8 @@ def _fmlp_backward(x2, lns, lnb, w1, b1, w2, wg, bg, mu, rstd, dy2,
         in_specs=common + tail,
         out_specs=pl.BlockSpec((bj, H), lambda j, i: (j, 0)),
         scratch_shapes=[pltpu.VMEM((bj, H), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(x2, lns, lnb, w1, b1, wg, bg, mu, rstd, dy2)
 
@@ -563,8 +563,8 @@ def _addln_forward(x2, y2, s, b, eps, br, interpret):
         out_specs=(row(),
                    pl.BlockSpec((br, 1), lambda i: (i, 0)),
                    pl.BlockSpec((br, 1), lambda i: (i, 0))),
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(x2, y2, s, b)
 
@@ -597,8 +597,8 @@ def _addln_bwd_rule(eps, br, interpret, res, g):
                    pl.BlockSpec((1, H), lambda i: (0, 0))),
         scratch_shapes=[pltpu.VMEM((1, H), jnp.float32),
                         pltpu.VMEM((1, H), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(x2, y2, s, mu, rstd, g.astype(x2.dtype))
     return dx, dx, ds.reshape(s.shape).astype(s.dtype), \

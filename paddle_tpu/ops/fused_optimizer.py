@@ -43,7 +43,7 @@ from ..monitor import benchmark as _bench
 from ..monitor.stats import FUSED_OPTIMIZER_STEPS
 from ..monitor.trace import span as _trace_span
 from . import autotune as _autotune
-from .flash_attention import _compiler_params, _on_tpu
+from .flash_attention import _on_tpu
 
 __all__ = ["adamw_flat", "lamb_moments_flat", "fused_adamw_update",
            "fused_lamb_update", "fused_update_from_slots",
@@ -193,8 +193,8 @@ def adamw_flat(p, g, m, v, lr, bc1, bc2, *, b1=0.9, b2=0.999, eps=1e-8,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   blk(), blk(), blk(), blk()],
         out_specs=(blk(), blk(), blk()),
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(sc, p2, g2, m2, v2)
     return (np2.reshape(-1)[:n], nm2.reshape(-1)[:n], nv2.reshape(-1)[:n])
@@ -260,8 +260,8 @@ def lamb_moments_flat(p, g, m, v, bc1, bc2, *, b1=0.9, b2=0.999, eps=1e-6,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   blk(), blk(), blk(), blk()],
         out_specs=(blk(), blk(), blk()),
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(sc, p2, g2, m2, v2)
     return (nm2.reshape(-1)[:n], nv2.reshape(-1)[:n], r2.reshape(-1)[:n])
@@ -648,8 +648,8 @@ def _adamw_bench(shape, dtype, config):
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   blk(), blk(), blk(), blk()],
         out_specs=(blk(), blk(), blk()),
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=not _on_tpu(),
     )(sc, p2, g2, m2, v2)
     jax.block_until_ready(out)

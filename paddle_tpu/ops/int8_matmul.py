@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from ..monitor.stats import INT8_MATMUL_CALLS
 from . import autotune as _autotune
-from .flash_attention import _compiler_params, _on_tpu
+from .flash_attention import _on_tpu
 
 __all__ = ["int8_matmul_arrays", "dynamic_int8_matmul"]
 
@@ -102,8 +102,8 @@ def _int8_matmul_2d(xq, wq, wscale, xscale, bias, out_dtype,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(xs, xq, wq, ws2, b2)
     return out[:M]

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from . import autotune as _autotune
-from .flash_attention import _compiler_params, _on_tpu
+from .flash_attention import _on_tpu
 
 __all__ = ["moe_dispatch_gather", "moe_combine_scatter"]
 
@@ -67,23 +67,28 @@ def _gather_pallas(x, src, hb, interpret=False):
 
     T, H = x.shape
     N = src.shape[0]
+    # rows ride a leading (untiled) dim — (T, 1, H) with (1, 1, hb)
+    # blocks — because Mosaic refuses a one-row block of a 2-D array
+    # (the second-minor block dim must be a multiple of 8 or the whole
+    # dim), and a row gather moves exactly one row per step
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(N, H // hb),
         in_specs=[
-            pl.BlockSpec((1, hb),
-                         lambda i, j, src: (jnp.maximum(src[i], 0), j)),
+            pl.BlockSpec((1, 1, hb),
+                         lambda i, j, src: (jnp.maximum(src[i], 0), 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, hb), lambda i, j, src: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, hb), lambda i, j, src: (i, 0, j)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, H), x.dtype),
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        out_shape=jax.ShapeDtypeStruct((N, 1, H), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
-    )(src, x)
+    )(src, x.reshape(T, 1, H))
+    return out.reshape(N, H)
 
 
 def _pick_hb(N, T, H, dtype) -> int:

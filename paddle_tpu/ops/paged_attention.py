@@ -7,11 +7,10 @@ The batched one-token decode step then needs attention of a single query
 per slot over that slot's *scattered* blocks — this module provides it:
 
 - :func:`paged_attention_arrays` — the routed entry every caller uses.
-  On TPU with tileable shapes it runs the Pallas kernel; anywhere else
-  (CPU/GPU, or untileable shapes) it runs the IDENTICAL composed jnp
-  math (gather blocks by table, mask, softmax) — the same fallback
-  contract as ops/flash_attention.py, pinned by interpret-mode parity
-  tests (tests/test_paged_attention.py, ``-m kernels``).
+  On TPU it runs the Pallas kernel; anywhere else (CPU/GPU) it runs the
+  IDENTICAL composed jnp math (gather blocks by table, mask, softmax),
+  pinned by interpret-mode parity tests (tests/test_paged_attention.py,
+  ``-m kernels``).
 
 Kernel design (mirrors the flash forward):
 - grid ``(batch, max_blocks_per_slot)``, kv-block innermost so the VMEM
@@ -44,7 +43,7 @@ import jax.numpy as jnp
 
 from ..core import native as _native
 from . import autotune as _autotune
-from .flash_attention import NEG_INF, _compiler_params, _on_tpu
+from .flash_attention import NEG_INF, _on_tpu
 
 __all__ = ["paged_attention_arrays"]
 
@@ -83,38 +82,39 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(i == 0)
     def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
 
     ln = lengths_ref[b]
 
     @pl.when(i * block_size < ln)
     def _compute():
-        q = q_ref[0]                                   # (nh, hd)
+        # heads ride the leading (untiled) dim and the single query is a
+        # one-row matrix: Mosaic takes a batched matmul only with rank-3
+        # operands, and this way no head count or block size is refused
+        q = q_ref[0]                                   # (nh, 1, hd)
         k = k_ref[0]                                   # (nh, bs, hd)
         v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (0,))),
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        pos = i * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < ln, s, NEG_INF)            # (nh, bs) f32
-        m_prev = m_s[:, 0:1]
-        l_prev = l_s[:, 0:1]
+        pos = i * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(pos < ln, s, NEG_INF)            # (nh, 1, bs) f32
+        m_prev = m_s[...]                              # (nh, 1, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_s[:] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, -1, keepdims=True), l_s.shape)
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (1,)), ((0,), (0,))),
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, -1, keepdims=True)
+        m_s[...] = m_new
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
     @pl.when(i == n_blocks - 1)
     def _finalize():
-        l = l_s[:, 0:1]
+        l = l_s[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_s[:] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -140,31 +140,33 @@ def _paged_decode(q, kb, vb, tables, lengths, scale, interpret=False,
     else:
         def _kv_idx(b, i, tbl, ln):
             return (tbl[b, i], 0, 0, 0)
+    q_spec = pl.BlockSpec((1, nh, 1, hd), lambda b, i, tbl, ln: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, W),
         in_specs=[
-            pl.BlockSpec((1, nh, hd), lambda b, i, tbl, ln: (b, 0, 0)),
+            q_spec,
             pl.BlockSpec((1, nh, bs, hd), _kv_idx),
             pl.BlockSpec((1, nh, bs, hd), _kv_idx),
         ],
-        out_specs=pl.BlockSpec((1, nh, hd), lambda b, i, tbl, ln: (b, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((nh, 128), jnp.float32),   # running max
-            pltpu.VMEM((nh, 128), jnp.float32),   # running sum
-            pltpu.VMEM((nh, hd), jnp.float32),    # output accumulator
+            pltpu.VMEM((nh, 1, 1), jnp.float32),    # running max
+            pltpu.VMEM((nh, 1, 1), jnp.float32),    # running sum
+            pltpu.VMEM((nh, 1, hd), jnp.float32),   # output accumulator
         ],
     )
     kernel = functools.partial(_decode_kernel, block_size=bs, n_blocks=W,
                                scale=scale)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
-        compiler_params=_compiler_params(
-            pltpu, vmem_limit_bytes=64 * 1024 * 1024),
+        out_shape=jax.ShapeDtypeStruct((B, nh, 1, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
-    )(tables, lengths, q, kb, vb)
+    )(tables, lengths, q.reshape(B, nh, 1, hd), kb, vb)
+    return out.reshape(B, nh, hd)
 
 
 def paged_attention_arrays(q, kb, vb, tables, lengths, scale=None,
@@ -180,12 +182,11 @@ def paged_attention_arrays(q, kb, vb, tables, lengths, scale=None,
     the live-length-clamped (resp. full-width) K/V sweep. Either way the
     result is bit-identical — ragged only changes DMA traffic.
 
-    Same contract as flash_attention_arrays: off-TPU (unless
-    ``interpret=True`` is forced) and on untileable shapes this returns
-    the identical composed jnp math, so callers never branch.
+    Off-TPU (unless ``interpret=True`` is forced) this returns the
+    identical composed jnp math, so callers never branch. On a TPU every
+    shape takes the kernel: there is no shape fallback.
     """
-    B, nh, hd = q.shape
-    bs = kb.shape[2]
+    hd = q.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
     if ragged is None:
@@ -195,14 +196,6 @@ def paged_attention_arrays(q, kb, vb, tables, lengths, scale=None,
         if not _on_tpu():
             return _paged_attention_reference(q, kb, vb, tables, lengths,
                                               scale)
-    if not interpret and ((hd % 128 != 0 and hd != 64) or bs % 8 != 0
-                          or nh % 8 != 0):
-        _autotune.note_fallback(
-            "paged_attention", (B, nh, hd),
-            "head_dim=%d (needs 64 or a multiple of 128) or "
-            "block_size=%d / n_heads=%d not a multiple of 8"
-            % (hd, bs, nh))
-        return _paged_attention_reference(q, kb, vb, tables, lengths, scale)
     return _paged_decode(q, kb, vb, jnp.asarray(tables, jnp.int32),
                          jnp.asarray(lengths, jnp.int32), float(scale),
                          interpret=bool(interpret), ragged=bool(ragged))

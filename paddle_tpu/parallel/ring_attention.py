@@ -34,37 +34,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import get_mesh
 
-# jax.shard_map is top-level only from 0.5; 0.4.x ships it under
-# jax.experimental (same signature)
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _shard_map_call(fn, mesh, in_specs, out_specs):
-    """check_rep=False on 0.4.x (its replication checker rejects the
-    lax.switch hop branches; the newer vma typing path needs no flag and
-    has no such kwarg)."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-
 __all__ = ["ring_attention", "ring_attention_sharded"]
 
 _NEG_INF = -1e30
-
-def _axis_size(axis_name):
-    """jax.lax.axis_size compat (added in jax 0.5): psum of the literal 1
-    is evaluated statically from the axis env on 0.4.x."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
 
 
 def _block_attn(q, k, v, mask, scale):
@@ -92,7 +64,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
                    scale: Optional[float] = None):
     """Blockwise ring attention; call INSIDE shard_map with the seq dim of
     q/k/v sharded over ``axis_name``. Shapes: (B, H, S_local, D)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_loc = q.shape[2]
     if scale is None:
@@ -125,15 +97,10 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
     b, h, _, d = q.shape
     # mark the zero-init carries as device-varying over the same manual
     # axes as the inputs so the scan carry type matches its output
-    # (shard_map vma typing; older jax has neither typeof().vma nor pcast
-    # and needs no cast at all)
-    try:
-        vma = (set(jax.typeof(q).vma) | set(jax.typeof(k).vma)
-               | set(jax.typeof(v).vma))
-        pcast = jax.lax.pcast
-        pv = lambda x: pcast(x, tuple(vma), to="varying")
-    except (AttributeError, TypeError):
-        pv = lambda x: x
+    # (shard_map vma typing)
+    vma = tuple(set(jax.typeof(q).vma) | set(jax.typeof(k).vma)
+                | set(jax.typeof(v).vma))
+    pv = lambda x: jax.lax.pcast(x, vma, to="varying")
     o0 = pv(jnp.zeros((b, h, s_loc, d), jnp.float32))
     m0 = pv(jnp.full((b, h, s_loc, 1), _NEG_INF, jnp.float32))
     l0 = pv(jnp.zeros((b, h, s_loc, 1), jnp.float32))
@@ -159,5 +126,6 @@ def ring_attention_sharded(q, k, v, causal: bool = True,
 
     fn = functools.partial(ring_attention, axis_name=seq_axis,
                            causal=causal, scale=scale)
-    mapped = _shard_map_call(fn, mesh, (spec, spec, spec), spec)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec)
     return mapped(q, k, v)

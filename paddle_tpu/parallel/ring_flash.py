@@ -37,41 +37,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map is top-level only from 0.5; 0.4.x ships it under
-# jax.experimental (same signature)
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _shard_map_call(fn, mesh, in_specs, out_specs):
-    """check_rep=False on 0.4.x (its replication checker rejects the
-    lax.switch hop branches; the newer vma typing path needs no flag and
-    has no such kwarg)."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-
-from ..ops.flash_attention import (_attention_reference, _flash_backward,
-                                   _flash_forward, _on_tpu)
+from ..ops.flash_attention import (_flash_backward, _flash_forward, _on_tpu,
+                                   flash_attention_arrays)
 from .mesh import get_mesh
 
 __all__ = ["ring_flash_attention", "ring_flash_attention_sharded"]
 
 _NEG = -1e30
-
-def _axis_size(axis_name):
-    """jax.lax.axis_size compat (added in jax 0.5): psum of the literal 1
-    is evaluated statically from the axis env on 0.4.x."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
 
 # chunk relations (lax.switch branch indices)
 _FULL, _DIAG, _DEAD = 0, 1, 2
@@ -189,7 +161,7 @@ def _merge(o1, lse1, o2, lse2):
 
 
 def _ring_fwd_impl(q, k, v, axis_name, causal, scale):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, s_loc, d = q.shape
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -225,7 +197,7 @@ def _ring_fwd_impl(q, k, v, axis_name, causal, scale):
 
 
 def _ring_bwd_impl(q, k, v, out, lse, g, axis_name, causal, scale):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -265,19 +237,15 @@ def _ring_bwd_impl(q, k, v, out, lse, g, axis_name, causal, scale):
 
 def _pv_like(zeros_trees, ref_trees):
     """Mark fresh zero carries device-varying over the same manual axes
-    as the real inputs (shard_map vma typing; no-op on older jax)."""
-    try:
-        vma = set()
-        for r in ref_trees:
-            vma |= set(jax.typeof(r).vma)
-        pcast = jax.lax.pcast
-        out = []
-        for z in zeros_trees:
-            need = tuple(vma - set(jax.typeof(z).vma))
-            out.append(pcast(z, need, to="varying") if need else z)
-        return tuple(out)
-    except (AttributeError, TypeError):
-        return zeros_trees
+    as the real inputs (shard_map vma typing)."""
+    vma = set()
+    for r in ref_trees:
+        vma |= set(jax.typeof(r).vma)
+    out = []
+    for z in zeros_trees:
+        need = tuple(vma - set(jax.typeof(z).vma))
+        out.append(jax.lax.pcast(z, need, to="varying") if need else z)
+    return tuple(out)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -319,14 +287,19 @@ def ring_flash_attention_sharded(q, k, v, causal: bool = True,
     mesh = mesh or get_mesh()
     if mesh is None:
         raise RuntimeError("ring_flash_attention_sharded needs a mesh")
+    spec = P(batch_axis, head_axis, seq_axis, None)
     if dict(mesh.shape).get(seq_axis, 1) == 1 and _on_tpu():
         # degenerate ring (context degree 1): no hop to take — the block
-        # computation IS full flash attention; skip the shard_map wrapper
-        from ..ops.flash_attention import flash_attention_arrays
-
-        return flash_attention_arrays(q, k, v, causal=causal, scale=scale)
-    spec = P(batch_axis, head_axis, seq_axis, None)
-    fn = functools.partial(ring_flash_attention, axis_name=seq_axis,
-                           causal=causal, scale=scale)
-    mapped = _shard_map_call(fn, mesh, (spec, spec, spec), spec)
+        # computation IS full flash attention
+        fn = functools.partial(flash_attention_arrays, causal=causal,
+                               scale=scale)
+        if mesh.size == 1:
+            return fn(q, k, v)
+    else:
+        fn = functools.partial(ring_flash_attention, axis_name=seq_axis,
+                               causal=causal, scale=scale)
+    # also at degree 1 on a multi-chip mesh: GSPMD cannot partition a
+    # Mosaic kernel, each device runs it on its own shard
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec)
     return mapped(q, k, v)

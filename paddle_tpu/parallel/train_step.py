@@ -32,7 +32,6 @@ from ..monitor.trace import span as _trace_span
 from ..resilience import faults as _faults
 from ..resilience import sentinel as _sentinel
 from .mesh import get_mesh, mesh_shape
-from .ring_attention import _shard_map_call
 from .sharding import zero_shard_specs
 
 __all__ = ["DistributedTrainStep", "pure_adamw_init", "pure_adamw_update",
@@ -580,8 +579,8 @@ class DistributedTrainStep:
                     return jax.lax.pmean(loss, axes), g
 
                 g_specs = self._zspecs if rs2 else P()
-                loss, grads = _shard_map_call(
-                    local_step, self.mesh,
+                loss, grads = jax.shard_map(
+                    local_step, mesh=self.mesh,
                     in_specs=(P(), self._batch_spec, P()),
                     out_specs=(P(), g_specs))(params, batch, scale)
                 new_aux = aux
@@ -848,11 +847,11 @@ class DistributedTrainStep:
         param_sh = self._param_sh
         full_j = jax.jit(full, in_shardings=(param_sh, self._batch_sh),
                          out_shardings=param_sh)
-        comp_j = jax.jit(_shard_map_call(
-            compute_only, self.mesh, in_specs=(P(), self._batch_spec),
-            out_specs=P()))
-        comm_j = jax.jit(_shard_map_call(
-            comm_only, self.mesh, in_specs=(P(),), out_specs=P()))
+        comp_j = jax.jit(jax.shard_map(
+            compute_only, mesh=self.mesh,
+            in_specs=(P(), self._batch_spec), out_specs=P()))
+        comm_j = jax.jit(jax.shard_map(
+            comm_only, mesh=self.mesh, in_specs=(P(),), out_specs=P()))
         zeros = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, p.dtype), self.params)
 

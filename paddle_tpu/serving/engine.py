@@ -1190,6 +1190,32 @@ class InferenceEngine:
             self._die_tick = self._ticks + max(1, int(ticks_ahead))
             self._cv.notify_all()
 
+    def lower_decode(self, table_width: Optional[int] = None):
+        """Lower the batched one-token decode program over this engine's
+        own weights and cache — assert-on-HLO testing, the serving twin
+        of ``DistributedTrainStep.lower``. ``table_width`` picks the
+        paged program's block-table width bucket (default: the widest);
+        the fixed-slot program has one shape. Nothing runs and nothing is
+        donated; ``.compile().as_text()`` is the HLO the tick executes."""
+        n = self.n_slots
+        i32 = np.zeros(n, np.int32)
+        tail = (i32, i32, self._base_key, i32, i32, np.zeros(n, np.float32),
+                i32, np.ones(n, np.float32), self._mask_dev)
+
+        def lower(eng):
+            if eng.paged:
+                width = eng.cache.table_width if table_width is None \
+                    else eng._width_bucket(int(table_width))
+                return eng._decode_paged_jit.lower(
+                    eng._decode_params, eng.cache.kb, eng.cache.vb,
+                    np.zeros((n, width), np.int32), *tail)
+            return eng._decode_jit.lower(
+                eng._decode_params, eng.cache.k, eng.cache.v, *tail)
+
+        # on the scheduler thread: between ticks no donated buffer is
+        # mid-flight
+        return self.run_on_scheduler(lower)
+
     # -- KV-block streaming (pod disaggregation, serving/pod.py, ISSUE 19) ---
     def run_on_scheduler(self, fn, timeout: Optional[float] = None):
         """Run ``fn(engine)`` ON the scheduler thread, between ticks, and

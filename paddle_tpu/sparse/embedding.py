@@ -44,7 +44,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..monitor import stats as _mstats
 from ..monitor.trace import span as _trace_span
 from ..parallel.mesh import get_mesh, mesh_shape
-from ..parallel.ring_attention import _shard_map_call
 
 __all__ = ["ShardedEmbedding", "sharded_lookup", "sparse_lookup",
            "stored_rows", "to_stored", "to_logical"]
@@ -201,9 +200,9 @@ def sharded_lookup(table, ids, mesh=None, axis: str = "model",
             [flat, jnp.full((pad,), rows, flat.dtype)])
     body = functools.partial(_exchange_body, axis=axis, n_shards=n_shards,
                              rows=rows, rps=rps)
-    out = _shard_map_call(body, mesh,
-                          in_specs=(P(axis, None), P(axis)),
-                          out_specs=P(axis, None))(table, flat)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(P(axis, None), P(axis)),
+                        out_specs=P(axis, None))(table, flat)
     if pad:
         out = out[:n]
     return out.reshape(ids.shape + (out.shape[-1],))

@@ -856,12 +856,10 @@ def Print(input, first_n=-1, message=None, summarize=20,  # noqa: A002,N802
     """Debug print op (reference controlflow/print_op.cc): prints the
     tensor when the op executes (jax.debug.print inside jit) and passes
     the value through."""
-    from ..core.native import shardy_disabled
     from ..framework.core import apply_op
 
-    with shardy_disabled():  # debug-callback lowering predates Shardy
-        return apply_op(_print_impl, input, message=message or "",
-                        summarize=int(summarize), op_name="Print")
+    return apply_op(_print_impl, input, message=message or "",
+                    summarize=int(summarize), op_name="Print")
 
 
 def auc(input, label, curve="ROC", num_thresholds=4095,  # noqa: A002

@@ -555,7 +555,4 @@ def py_func(func, x, out, backward_func=None, skip_vars_in_backward_input=None):
             *arrays)
         return tuple(res) if multi else res[0]
 
-    from ..core.native import shardy_disabled
-
-    with shardy_disabled():  # callback lowering predates Shardy (jax 0.4.x)
-        return apply_op(_impl, *xs, op_name="py_func")
+    return apply_op(_impl, *xs, op_name="py_func")

@@ -112,7 +112,6 @@ class TestTracedCollectives:
     """In-trace semantics through shard_map directly."""
 
     def test_psum_inside_shard_map(self, _mesh=None):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.parallel.mesh import get_mesh
@@ -123,14 +122,13 @@ class TestTracedCollectives:
         def body(x):
             return dist.psum(x, "data")
 
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                              out_specs=P("data"), check_rep=False))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data"), check_vma=False))
         out = np.asarray(f(x))
         want = np.broadcast_to(x.sum(0, keepdims=True), x.shape)
         np.testing.assert_allclose(out, want, rtol=1e-6)
 
     def test_send_with_explicit_src_in_trace(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from paddle_tpu.parallel.mesh import get_mesh
@@ -141,7 +139,7 @@ class TestTracedCollectives:
         def body(x):
             return dist.send(x, dst=2, src=0)
 
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                              out_specs=P("data"), check_rep=False))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data"), check_vma=False))
         out = np.asarray(f(x))
         np.testing.assert_allclose(out[2], x[0], rtol=1e-6)

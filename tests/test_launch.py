@@ -37,6 +37,31 @@ class TestClusterEnv:
         assert env["JAX_COORDINATOR_ADDRESS"] == "127.0.0.1:9000"
 
 
+class TestOneProcessPerChip:
+    """A chip belongs to one process: several workers on a TPU host are
+    refused, and the launcher parent never opens a backend itself."""
+
+    def test_refuses_many_workers_on_tpu_host(self, monkeypatch):
+        from paddle_tpu.distributed import launch as L
+
+        monkeypatch.setattr(L.glob, "glob", lambda pat: ["/dev/accel0"])
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with pytest.raises(RuntimeError, match=r"one\s+process"):
+            L.start_pod(["-c", "pass"], nproc=2)
+        L._check_one_process_per_chip(1)           # one worker: fine
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        L._check_one_process_per_chip(4)           # CPU rehearsal: fine
+
+    @pytest.mark.slow  # a fresh interpreter importing the package
+    def test_launcher_parent_opens_no_backend(self):
+        code = ("import paddle_tpu.distributed.launch\n"
+                "from jax._src import xla_bridge\n"
+                "assert not xla_bridge._backends, xla_bridge._backends\n")
+        proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                              capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 class TestLauncher:
     def test_two_workers_env_wiring(self, tmp_path):
         script = _write(tmp_path, "worker.py", """

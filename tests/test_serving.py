@@ -568,6 +568,16 @@ class TestPagedEngine:
 
 
 class TestObservability:
+    def test_lower_decode_exposes_the_tick_program(self, engine):
+        """Assert-on-HLO surface (chip_smoke.py reads it for the Mosaic
+        call): the lowered decode program of both cache layouts, at the
+        width bucket asked for."""
+        fixed = engine()
+        assert "func.func public @main" in fixed.lower_decode().as_text()
+        paged = engine(paged=True, block_size=8, prefill_chunk=16)
+        text = paged.lower_decode(table_width=3).as_text()
+        assert "tensor<2x4xi32>" in text      # 3 blocks -> width bucket 4
+
     def _trace_report(self):
         spec = importlib.util.spec_from_file_location(
             "trace_report", os.path.join(_ROOT, "tools", "trace_report.py"))
