@@ -1,0 +1,6 @@
+"""``fwd_ms.train``: device self time a step in phase ``forward``."""
+from benchmarks.readers import phases
+
+
+def read(ctx):
+    return phases.read_phase(ctx, "forward")

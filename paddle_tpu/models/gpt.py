@@ -288,6 +288,7 @@ def gpt_param_specs(cfg: GPTConfig) -> Dict[str, Any]:
 # forward
 # --------------------------------------------------------------------------
 
+@jax.named_scope("ln")
 def _layer_norm(x, scale, bias, eps=1e-5):
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -333,6 +334,7 @@ def _attention(cfg: GPTConfig, q, k, v):
     return _attention_reference(q, k, v, causal=True, scale=scale)
 
 
+@jax.named_scope("attn")
 def _attn_half(cfg: GPTConfig, p, x):
     """Attention half of a block (LN1 → QKV → attention → proj +
     residual); p leaves have no layer dim. Returns (x, (kh, vh))."""
@@ -350,6 +352,7 @@ def _attn_half(cfg: GPTConfig, p, x):
     return x + o @ p["proj_w"].astype(cd) + p["proj_b"].astype(cd), (kh, vh)
 
 
+@jax.named_scope("mlp")
 def _mlp_half(cfg: GPTConfig, p, x):
     """Dense MLP half of a block (LN2 → gelu MLP + residual)."""
     cd = cfg.dtype
@@ -426,6 +429,7 @@ def _layer_params(tree, i, keys):
     return {k: tree[k][i] for k in keys}
 
 
+@jax.named_scope("mlp")
 def _moe_mlp_half(cfg: GPTConfig, p, pm, x, capacity_factor):
     """MoE MLP half (LN2 → routed expert FFN + residual). x (B, S, H);
     returns (x, aux, z, counts (E,), dropped). Dropped assignments
@@ -480,6 +484,7 @@ def _hidden_moe(cfg: GPTConfig, params, x, capacity_factor):
     return x, aux, zl, counts, dropped
 
 
+@jax.named_scope("embed")
 def _embed(cfg: GPTConfig, params, tokens):
     emb = params["wte"].astype(cfg.dtype)[tokens]
     pos = params["wpe"].astype(cfg.dtype)[: tokens.shape[1]]
@@ -497,6 +502,7 @@ def _logits(params, x, compute_dtype=jnp.bfloat16):
                       preferred_element_type=jnp.float32)
 
 
+@jax.named_scope("head")
 def _head(cfg: GPTConfig, params, x):
     x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
     return _logits(params, x)
@@ -597,18 +603,21 @@ def gpt_loss(cfg: GPTConfig, params, batch, n_micro: int = 1,
                                            cfg.moe_capacity_factor)
         else:
             x = _block_stack(cfg, params["blocks"], x)
-    x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
-    if loss_chunk and tokens.shape[1] > loss_chunk:
-        if tokens.shape[1] % loss_chunk != 0:
-            raise ValueError(
-                f"loss_chunk={loss_chunk} must divide seq_len="
-                f"{tokens.shape[1]} (the memory saver would otherwise be "
-                "silently disabled)")
-        ce = _chunked_ce(params, x, labels, loss_chunk)
-    else:
-        logp = jax.nn.log_softmax(_logits(params, x), axis=-1)
-        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        ce = -jnp.mean(ll)
+    if loss_chunk and tokens.shape[1] > loss_chunk \
+            and tokens.shape[1] % loss_chunk != 0:
+        raise ValueError(
+            f"loss_chunk={loss_chunk} must divide seq_len="
+            f"{tokens.shape[1]} (the memory saver would otherwise be "
+            "silently disabled)")
+    with jax.named_scope("head_loss"):
+        x = _layer_norm(x, params["lnf_s"], params["lnf_b"])
+        if loss_chunk and tokens.shape[1] > loss_chunk:
+            ce = _chunked_ce(params, x, labels, loss_chunk)
+        else:
+            logp = jax.nn.log_softmax(_logits(params, x), axis=-1)
+            ll = jnp.take_along_axis(logp, labels[..., None],
+                                     axis=-1)[..., 0]
+            ce = -jnp.mean(ll)
     if aux is not None:
         n = len(moe_ids)
         ce = ce + cfg.moe_aux_weight * (aux / n) \
@@ -672,6 +681,7 @@ def _dec_mm(x, w, cd):
     return x @ w.astype(cd)
 
 
+@jax.named_scope("attn")
 def _dec_attn(cfg: GPTConfig, p, x, kc_l, vc_l, positions):
     """Attention half of the one-token block step (cache write + attend
     + proj residual). x (B, 1, H); kc_l/vc_l (B, nh, max_len, hd);
@@ -705,6 +715,7 @@ def _dec_attn(cfg: GPTConfig, p, x, kc_l, vc_l, positions):
     return x, kc_l, vc_l
 
 
+@jax.named_scope("mlp")
 def _dec_mlp(cfg: GPTConfig, p, x):
     """Dense MLP half of the one-token block step (LN2 → gelu MLP +
     residual; weights may be int8-quantized dicts)."""
@@ -714,6 +725,7 @@ def _dec_mlp(cfg: GPTConfig, p, x):
     return x + _dec_mm(h, p["out_w"], cd) + p["out_b"].astype(cd)
 
 
+@jax.named_scope("mlp")
 def _dec_moe_mlp(cfg: GPTConfig, pa, pm, x):
     """MoE MLP half of the one-token block step — DROPLESS, so decode
     quality never depends on which requests share the tick. x (B, 1, H);
@@ -797,8 +809,9 @@ def gpt_decode_step(cfg: GPTConfig, params, cache, positions, tokens):
     k_cache, v_cache = cache
     cd = cfg.dtype
     L = k_cache.shape[1]
-    x = (params["wte"].astype(cd)[tokens]
-         + params["wpe"].astype(cd)[positions])[:, None, :]   # (B, 1, H)
+    with jax.named_scope("embed"):
+        x = (params["wte"].astype(cd)[tokens]
+             + params["wpe"].astype(cd)[positions])[:, None, :]  # (B, 1, H)
 
     if cfg.moe_layer_ids:
         moe_ids = set(cfg.moe_layer_ids)
@@ -849,6 +862,13 @@ def _block_verify(cfg: GPTConfig, p, x, kc_l, vc_l, positions):
     ONE dynamic_update_slice per slot writes them all; each query j then
     attends over the slot masked to ``pos <= positions + j`` — the math
     per query equals :func:`_block_decode` run token-by-token."""
+    x, kc_l, vc_l = _verify_attn(cfg, p, x, kc_l, vc_l, positions)
+    return _dec_mlp(cfg, p, x), kc_l, vc_l
+
+
+@jax.named_scope("attn")
+def _verify_attn(cfg: GPTConfig, p, x, kc_l, vc_l, positions):
+    """Attention half of :func:`_block_verify`."""
     B, C, _ = x.shape
     nh, hd = cfg.n_heads, cfg.head_dim
     cd = cfg.dtype
@@ -875,9 +895,6 @@ def _block_verify(cfg: GPTConfig, p, x, kc_l, vc_l, positions):
     o = o.transpose(0, 2, 1, 3).reshape(B, C, nh * hd)
 
     x = x + _dec_mm(o, p["proj_w"], cd) + p["proj_b"].astype(cd)
-    h = _layer_norm(x, p["ln2_s"], p["ln2_b"])
-    h = jax.nn.gelu(_dec_mm(h, p["fc_w"], cd) + p["fc_b"].astype(cd))
-    x = x + _dec_mm(h, p["out_w"], cd) + p["out_b"].astype(cd)
     return x, kc_l, vc_l
 
 
@@ -905,7 +922,9 @@ def gpt_verify_step(cfg: GPTConfig, params, cache, positions, tokens):
     L = k_cache.shape[1]
     C = tokens.shape[1]
     qpos = positions[:, None] + jnp.arange(C)[None, :]
-    x = params["wte"].astype(cd)[tokens] + params["wpe"].astype(cd)[qpos]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cd)[tokens] \
+            + params["wpe"].astype(cd)[qpos]
 
     def step(carry, inp):
         x, kc, vc = carry
@@ -934,6 +953,7 @@ def gpt_verify_step(cfg: GPTConfig, params, cache, positions, tokens):
 # point at it, so stale batch lanes scatter their garbage K/V somewhere
 # no live slot ever reads.
 
+@jax.named_scope("attn")
 def _dec_attn_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
     """Attention half of the paged one-token block step (pool write +
     paged attention + proj residual). Returns (x, kb_l, vb_l)."""
@@ -992,8 +1012,9 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
     kb, vb = pool
     cd = cfg.dtype
     L = kb.shape[1]
-    x = (params["wte"].astype(cd)[tokens]
-         + params["wpe"].astype(cd)[positions])[:, None, :]   # (B, 1, H)
+    with jax.named_scope("embed"):
+        x = (params["wte"].astype(cd)[tokens]
+             + params["wpe"].astype(cd)[positions])[:, None, :]  # (B, 1, H)
 
     if cfg.moe_layer_ids:
         moe_ids = set(cfg.moe_layer_ids)
@@ -1003,10 +1024,13 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
         di = mi = 0
         for i in range(cfg.n_layers):
             pa = _layer_params(blocks, i, _ATTN_KEYS)
-            x, kb_l, vb_l = _dec_attn_paged(cfg, pa, x, kb[:, i], vb[:, i],
+            with jax.named_scope("kv_pool"):
+                kb_i, vb_i = kb[:, i], vb[:, i]
+            x, kb_l, vb_l = _dec_attn_paged(cfg, pa, x, kb_i, vb_i,
                                             tables, positions)
-            kb = kb.at[:, i].set(kb_l)
-            vb = vb.at[:, i].set(vb_l)
+            with jax.named_scope("kv_pool"):
+                kb = kb.at[:, i].set(kb_l)
+                vb = vb.at[:, i].set(vb_l)
             if i in moe_ids:
                 pm = _layer_params(params["moe"], mi, _MOE_KEYS)
                 mi += 1
@@ -1021,12 +1045,14 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
     def step(carry, inp):
         x, kb, vb = carry
         layer_p, li = inp
-        kb_l = jnp.take(kb, li, axis=1)
-        vb_l = jnp.take(vb, li, axis=1)
+        with jax.named_scope("kv_pool"):
+            kb_l = jnp.take(kb, li, axis=1)
+            vb_l = jnp.take(vb, li, axis=1)
         x, kb_l, vb_l = _block_decode_paged(cfg, layer_p, x, kb_l, vb_l,
                                             tables, positions)
-        kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
-        vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
+        with jax.named_scope("kv_pool"):
+            kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
+            vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
         return (x, kb, vb), None
 
     (x, kb, vb), _ = jax.lax.scan(
@@ -1044,6 +1070,14 @@ def _block_verify_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables,
     bs``. Attention is the composed table gather (the multi-query shape
     the Pallas decode kernel does not cover); the table width W is
     already bucketed by the engine, so gather work tracks live tokens."""
+    x, kb_l, vb_l = _verify_attn_paged(cfg, p, x, kb_l, vb_l, tables,
+                                       positions)
+    return _dec_mlp(cfg, p, x), kb_l, vb_l
+
+
+@jax.named_scope("attn")
+def _verify_attn_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
+    """Attention half of :func:`_block_verify_paged`."""
     B, C, _ = x.shape
     nh, hd = cfg.n_heads, cfg.head_dim
     bs = kb_l.shape[2]
@@ -1077,9 +1111,6 @@ def _block_verify_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables,
     o = o.transpose(0, 2, 1, 3).reshape(B, C, nh * hd)
 
     x = x + _dec_mm(o, p["proj_w"], cd) + p["proj_b"].astype(cd)
-    h = _layer_norm(x, p["ln2_s"], p["ln2_b"])
-    h = jax.nn.gelu(_dec_mm(h, p["fc_w"], cd) + p["fc_b"].astype(cd))
-    x = x + _dec_mm(h, p["out_w"], cd) + p["out_b"].astype(cd)
     return x, kb_l, vb_l
 
 
@@ -1105,18 +1136,22 @@ def gpt_verify_step_paged(cfg: GPTConfig, params, pool, tables, positions,
     def step(carry, inp):
         x, kb, vb = carry
         layer_p, li = inp
-        kb_l = jnp.take(kb, li, axis=1)
-        vb_l = jnp.take(vb, li, axis=1)
+        with jax.named_scope("kv_pool"):
+            kb_l = jnp.take(kb, li, axis=1)
+            vb_l = jnp.take(vb, li, axis=1)
         x, kb_l, vb_l = _block_verify_paged(cfg, layer_p, x, kb_l, vb_l,
                                             tables, positions)
-        kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
-        vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
+        with jax.named_scope("kv_pool"):
+            kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
+            vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
         return (x, kb, vb), None
 
     cd = cfg.dtype
     C = tokens.shape[1]
     qpos = positions[:, None] + jnp.arange(C)[None, :]
-    x = params["wte"].astype(cd)[tokens] + params["wpe"].astype(cd)[qpos]
+    with jax.named_scope("embed"):
+        x = params["wte"].astype(cd)[tokens] \
+            + params["wpe"].astype(cd)[qpos]
     (x, kb, vb), _ = jax.lax.scan(
         step, (x, kb, vb), (params["blocks"], jnp.arange(L)))
     return _head(cfg, params, x), (kb, vb)
@@ -1145,6 +1180,7 @@ def gpt_prefill_prefix(cfg: GPTConfig, params, pool, table_row, tokens,
                                  tokens)
 
 
+@jax.named_scope("attn")
 def _chunk_attn(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
     """Attention half of the chunked-prefill block step (pool write +
     full-prefix attention + proj residual). Returns (x, kb_l, vb_l)."""
@@ -1183,6 +1219,15 @@ def _chunk_attn(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
         kb_l, vb_l
 
 
+@jax.named_scope("mlp")
+def _chunk_mlp(cfg: GPTConfig, p, x):
+    """Dense MLP half of the chunked-prefill block step."""
+    cd = cfg.dtype
+    h = _layer_norm(x, p["ln2_s"], p["ln2_b"])
+    h = jax.nn.gelu(h @ p["fc_w"].astype(cd) + p["fc_b"].astype(cd))
+    return x + h @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
+
+
 def _block_chunk(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
     """One transformer block over one prefill CHUNK against the pool.
 
@@ -1193,12 +1238,8 @@ def _block_chunk(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
     attend over every cached position (previous chunks + the chunk
     itself) under the global causal mask, so the math equals one whole
     causal pass over the same prefix."""
-    cd = cfg.dtype
     x, kb_l, vb_l = _chunk_attn(cfg, p, x, kb_l, vb_l, table_row, start)
-    h = _layer_norm(x, p["ln2_s"], p["ln2_b"])
-    h = jax.nn.gelu(h @ p["fc_w"].astype(cd) + p["fc_b"].astype(cd))
-    x = x + h @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
-    return x, kb_l, vb_l
+    return _chunk_mlp(cfg, p, x), kb_l, vb_l
 
 
 def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
@@ -1220,9 +1261,10 @@ def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
     C = tokens.shape[1]
     L = kb.shape[1]
 
-    pos_emb = jax.lax.dynamic_slice(
-        params["wpe"], (start, 0), (C, params["wpe"].shape[1]))
-    x = params["wte"].astype(cd)[tokens] + pos_emb.astype(cd)[None]
+    with jax.named_scope("embed"):
+        pos_emb = jax.lax.dynamic_slice(
+            params["wpe"], (start, 0), (C, params["wpe"].shape[1]))
+        x = params["wte"].astype(cd)[tokens] + pos_emb.astype(cd)[None]
 
     if cfg.moe_layer_ids:
         moe_ids = set(cfg.moe_layer_ids)
@@ -1230,10 +1272,13 @@ def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
         di = mi = 0
         for i in range(cfg.n_layers):
             pa = _layer_params(blocks, i, _ATTN_KEYS)
-            x, kb_l, vb_l = _chunk_attn(cfg, pa, x, kb[:, i], vb[:, i],
+            with jax.named_scope("kv_pool"):
+                kb_i, vb_i = kb[:, i], vb[:, i]
+            x, kb_l, vb_l = _chunk_attn(cfg, pa, x, kb_i, vb_i,
                                         table_row, start)
-            kb = kb.at[:, i].set(kb_l)
-            vb = vb.at[:, i].set(vb_l)
+            with jax.named_scope("kv_pool"):
+                kb = kb.at[:, i].set(kb_l)
+                vb = vb.at[:, i].set(vb_l)
             if i in moe_ids:
                 pm = _layer_params(params["moe"], mi, _MOE_KEYS)
                 mi += 1
@@ -1241,21 +1286,20 @@ def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
             else:
                 pd = _layer_params(blocks, di, _MLP_KEYS)
                 di += 1
-                h = _layer_norm(x, pa["ln2_s"], pa["ln2_b"])
-                h = jax.nn.gelu(h @ pd["fc_w"].astype(cd)
-                                + pd["fc_b"].astype(cd))
-                x = x + h @ pd["out_w"].astype(cd) + pd["out_b"].astype(cd)
+                x = _chunk_mlp(cfg, {**pa, **pd}, x)
         return _head(cfg, params, x), (kb, vb)
 
     def step(carry, inp):
         x, kb, vb = carry
         layer_p, li = inp
-        kb_l = jnp.take(kb, li, axis=1)
-        vb_l = jnp.take(vb, li, axis=1)
+        with jax.named_scope("kv_pool"):
+            kb_l = jnp.take(kb, li, axis=1)
+            vb_l = jnp.take(vb, li, axis=1)
         x, kb_l, vb_l = _block_chunk(cfg, layer_p, x, kb_l, vb_l, table_row,
                                      start)
-        kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
-        vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
+        with jax.named_scope("kv_pool"):
+            kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
+            vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
         return (x, kb, vb), None
 
     (x, kb, vb), _ = jax.lax.scan(

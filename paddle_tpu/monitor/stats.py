@@ -295,6 +295,7 @@ DEFAULT_STATS = (
     "serving_decode_ms",       # cumulative batched decode-tick wall time (ms)
     "serving_tokens_per_s",    # gauge: recent generation rate (tokens/s)
     "serving_evictions",       # sequences evicted from slots (eos/len/deadline/cancel)
+    "serving_prefill_chunks",  # prefill work quanta dispatched (paged: chunks)
     # paged KV cache (ISSUE 7)
     "kv_blocks_free",          # gauge: pool blocks on the free list
     "kv_blocks_used",          # gauge: pool blocks owned by live slots
@@ -413,6 +414,7 @@ SERVING_PREFILL_MS = _registry.get_stat("serving_prefill_ms")
 SERVING_DECODE_MS = _registry.get_stat("serving_decode_ms")
 SERVING_TOKENS_PER_S = _registry.get_stat("serving_tokens_per_s")
 SERVING_EVICTIONS = _registry.get_stat("serving_evictions")
+SERVING_PREFILL_CHUNKS = _registry.get_stat("serving_prefill_chunks")
 KV_BLOCKS_FREE = _registry.get_stat("kv_blocks_free")
 KV_BLOCKS_USED = _registry.get_stat("kv_blocks_used")
 KV_FRAGMENTATION = _registry.get_stat("kv_fragmentation")
@@ -507,10 +509,14 @@ DEFAULT_HISTOGRAMS = (
      "queue wait before work starts: WFQ lane wait and engine "
      "admission wait (ms)"),
     ("serving_decode_tick_ms",
-     "batched decode tick wall latency (ms)"),
+     "batched decode tick wall latency, dispatch to tokens on the host; "
+     "in paged mode it includes the device time of any prefill chunk "
+     "queued ahead of the tick (ms)"),
     ("serving_prefill_chunk_ms",
-     "prefill work quantum wall latency: one chunk (paged) or one "
-     "whole-prompt prefill (fixed) (ms)"),
+     "prefill work quantum host latency: one whole-prompt prefill, "
+     "awaited (fixed), or the asynchronous DISPATCH of one chunk "
+     "(paged: about a millisecond whatever the chunk costs the device, "
+     "which shows in the next tick or first-token wait) (ms)"),
     ("moe_expert_share_pct",
      "per-expert share of routed assignments per decode tick (%) — "
      "one observation per expert per tick, so the spread IS the "

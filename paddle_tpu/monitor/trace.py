@@ -15,18 +15,30 @@ so an idle process records nothing and allocates nothing.
 Timestamps are `time.perf_counter()` seconds converted to the format's
 microseconds — one monotonic clock for every producer keeps spans from
 different layers aligned on the same timeline.
+
+While tracing is on (and jax is already imported) every `span` also
+enters a ``jax.profiler.TraceAnnotation`` of the same name, so when a
+jax profiler session runs beside it the program's spans sit in the same
+``.xplane.pb`` as the device ops — the operator's view. `on_stop`
+callbacks let a program add what it can only know about itself when the
+window closes (``op_scopes``: which phase and ``named_scope`` each
+compiled instruction came from — the profiler's device events carry the
+bare instruction name and nothing else).
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import os
+import re
+import sys
 import threading
 import time
 
 __all__ = ["TraceWriter", "TRACING", "FLIGHT", "is_tracing",
            "start_tracing", "stop_tracing", "get_writer", "span",
-           "recording", "emit_complete", "emit_instant", "emit_flow"]
+           "recording", "emit_complete", "emit_instant", "emit_flow",
+           "on_stop", "op_scopes", "emit_op_scopes"]
 
 # shared mutable gate — hot paths read TRACING[0] directly
 TRACING = [False]
@@ -82,6 +94,13 @@ class TraceWriter:
                 else tid,
                 "ts": int(ts * 1e6),
             })
+
+    def add_metadata(self, name: str, args: dict) -> None:
+        """One "M" (metadata) event: a table about the trace itself,
+        with no place on the timeline (``op_scopes``)."""
+        with self._lock:
+            self._events.append({"name": name, "ph": "M", "pid": self.pid,
+                                 "tid": 0, "args": args})
 
     def add_counter(self, name: str, ts: float, values: dict) -> None:
         """One "C" (counter) event — e.g. the stat gauges over time."""
@@ -158,7 +177,29 @@ def start_tracing(clear: bool = True) -> TraceWriter:
 
 def stop_tracing() -> TraceWriter:
     TRACING[0] = False
+    with _stop_lock:
+        fns, _on_stop[:] = list(_on_stop), []
+    for fn in fns:
+        try:
+            fn(_writer)
+        except Exception as e:  # noqa: BLE001 — a table that cannot be
+            # made must not cost the caller its trace
+            _writer.add_instant(
+                "on_stop_failed: %s: %s" % (type(e).__name__, e),
+                time.perf_counter())
     return _writer
+
+
+_on_stop: list = []
+_stop_lock = threading.Lock()
+
+
+def on_stop(fn) -> None:
+    """Run ``fn(writer)`` once, at the next ``stop_tracing()``, after the
+    gate is off: it writes straight to the writer. An exception becomes
+    one instant event naming it and never reaches the caller."""
+    with _stop_lock:
+        _on_stop.append(fn)
 
 
 def recording() -> bool:
@@ -207,6 +248,11 @@ def span(name: str, cat: str = "op", args: dict | None = None,
     if not TRACING[0] and FLIGHT[0] is None:
         yield
         return
+    # the same span on the jax profiler's own timeline (a no-op TraceMe
+    # unless a profiler session runs); never imports jax itself
+    jax = sys.modules.get("jax") if TRACING[0] else None
+    note = jax.profiler.TraceAnnotation(name) if jax is not None \
+        else contextlib.nullcontext()
     t0 = time.perf_counter()
     if flow is not None:
         # flow events keep the constant "request" name: name-based event
@@ -214,7 +260,87 @@ def span(name: str, cat: str = "op", args: dict | None = None,
         # span's name
         emit_flow("t", flow, t0)
     try:
-        yield
+        with note:
+            yield
     finally:
         emit_complete(name, t0, time.perf_counter() - t0,
                       cat=cat, args=args)
+
+
+# -- instruction -> phase/scope table ---------------------------------------
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$", re.M)
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+# a scope is named like an identifier: not ``jit(f)``, not an einsum's
+# ``bsh,vh->bsv``, not ``branch_0_fun``
+_SCOPE = re.compile(r"^(?!branch_\d)[A-Za-z_][\w.\-]*$")
+# name-stack entries jax pushes itself: never a user's named_scope
+_JAX_STACK = frozenset((
+    "while", "body", "cond", "body_pred", "scan", "checkpoint", "remat",
+    "rematted_computation", "closed_call", "core_call", "custom_jvp_call",
+    "custom_vjp_call", "custom_vjp_call_jaxpr", "custom_lin", "shard_map",
+    "pallas_call", "xla_pmap", "named_call"))
+
+
+def _scopes_of(op_name: str) -> list:
+    """The ``jax.named_scope`` names in an op_name, outermost first:
+    ``jit(step)/transpose(jvp(mlp))/ln/mul`` -> [mlp, ln]. A transform
+    wraps the scope it traced through (``jvp(mlp)``); ``jit(f)`` is a
+    function, the last entry the primitive."""
+    out = []
+    for part in op_name.split("/")[:-1]:
+        m = _WRAPPED.match(part)
+        while m and m.group(1) not in ("jit", "pjit"):
+            part = m.group(2)
+            m = _WRAPPED.match(part)
+        if _SCOPE.match(part) and part not in _JAX_STACK:
+            out.append(part)
+    return out
+
+
+def op_scopes(hlo_text: str) -> dict:
+    """{instruction name: "phase/scope"} from ``compiled.as_text()``.
+
+    Phase is ``optimizer`` if a scope of that name is in the
+    instruction's ``op_name``, else ``backward`` if ``transpose(`` is
+    (forward work recomputed for the backward counts there), else
+    ``forward``; scope is the innermost ``named_scope`` (``backward/attn``,
+    or the bare phase where there is none). An instruction the compiler
+    made without metadata (the async copies and slices that prefetch an
+    operand) takes the label of the first instruction that uses it; one
+    nothing labelled uses is left out, and a reader counts it unplaced."""
+    instrs = _HLO_INSTR.findall(hlo_text)
+    table = {}
+    for name, rest in instrs:
+        op = _OP_NAME.search(rest)
+        if op is None:
+            continue
+        scopes = _scopes_of(op.group(1))
+        if "optimizer" in scopes:
+            phase = "optimizer"
+            scopes = [s for s in scopes if s != "optimizer"]
+        elif "transpose(" in op.group(1):
+            phase = "backward"
+        else:
+            phase = "forward"
+        table[name] = phase + "/" + scopes[-1] if scopes else phase
+    # users come after definitions: walking back, each labelled user
+    # hands its label to the operands that have none (the earliest wins)
+    inherited = {}
+    for name, rest in reversed(instrs):
+        label = table.get(name) or inherited.get(name)
+        if label is None:
+            continue
+        for operand in _OPERAND.findall(rest):
+            if operand not in table:
+                inherited[operand] = label
+    inherited.update(table)
+    return inherited
+
+
+def emit_op_scopes(writer: TraceWriter, program: str, hlo_text: str) -> None:
+    """The ``op_scopes`` metadata event of one compiled program."""
+    writer.add_metadata("op_scopes", {"program": program,
+                                      "scopes": op_scopes(hlo_text)})

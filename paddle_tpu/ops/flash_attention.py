@@ -191,6 +191,7 @@ def _flash_forward(q, k, v, causal=False, scale=None, block_q=512,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="flash_forward",
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d), lse
 
@@ -315,6 +316,7 @@ def _flash_backward(q, k, v, o, lse, g, causal=False, scale=None,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="flash_backward",
     )(qr, kr, vr, dor, lse, delta)
 
     dq = jnp.sum(dqp, axis=0).astype(q.dtype) if n_kv > 1 else dqp[0]

@@ -110,6 +110,7 @@ def _fp8_matmul_2d(xq, wq, sx, sw, bias, out_dtype, interpret=False,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="fp8_matmul",
     )(sc, xq, wq, b2)
     return out[:M]
 

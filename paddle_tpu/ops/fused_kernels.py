@@ -314,6 +314,7 @@ def _fmlp_forward(x2, lns, lnb, w1, b1, w2, b2, wg, bg, act, residual,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="fused_mlp_forward",
     )(x2, lns, lnb, w1, b1, w2, b2, wg, bg)
     return y, mu, rstd
 
@@ -367,6 +368,7 @@ def _fmlp_backward(x2, lns, lnb, w1, b1, w2, wg, bg, mu, rstd, dy2,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="fused_mlp_backward",
     )(x2, lns, lnb, w1, b1, w2, wg, bg, mu, rstd, dy2)
 
     dw2 = pl.pallas_call(
@@ -380,6 +382,7 @@ def _fmlp_backward(x2, lns, lnb, w1, b1, w2, wg, bg, mu, rstd, dy2,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="fused_mlp_backward_dw2",
     )(x2, lns, lnb, w1, b1, wg, bg, mu, rstd, dy2)
 
     dy32 = dy2.astype(jnp.float32)
@@ -566,6 +569,7 @@ def _addln_forward(x2, y2, s, b, eps, br, interpret):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="add_ln_forward",
     )(x2, y2, s, b)
 
 
@@ -600,6 +604,7 @@ def _addln_bwd_rule(eps, br, interpret, res, g):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="add_ln_backward",
     )(x2, y2, s, mu, rstd, g.astype(x2.dtype))
     return dx, dx, ds.reshape(s.shape).astype(s.dtype), \
         db.reshape(s.shape).astype(s.dtype)

@@ -196,6 +196,7 @@ def adamw_flat(p, g, m, v, lr, bc1, bc2, *, b1=0.9, b2=0.999, eps=1e-8,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="fused_adamw",
     )(sc, p2, g2, m2, v2)
     return (np2.reshape(-1)[:n], nm2.reshape(-1)[:n], nv2.reshape(-1)[:n])
 
@@ -263,6 +264,7 @@ def lamb_moments_flat(p, g, m, v, bc1, bc2, *, b1=0.9, b2=0.999, eps=1e-6,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="fused_lamb_moments",
     )(sc, p2, g2, m2, v2)
     return (nm2.reshape(-1)[:n], nv2.reshape(-1)[:n], r2.reshape(-1)[:n])
 
@@ -651,6 +653,7 @@ def _adamw_bench(shape, dtype, config):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=not _on_tpu(),
+        name="fused_adamw_bench",
     )(sc, p2, g2, m2, v2)
     jax.block_until_ready(out)
 

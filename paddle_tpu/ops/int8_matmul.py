@@ -105,6 +105,7 @@ def _int8_matmul_2d(xq, wq, wscale, xscale, bias, out_dtype,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="int8_matmul",
     )(xs, xq, wq, ws2, b2)
     return out[:M]
 

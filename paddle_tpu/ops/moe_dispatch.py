@@ -87,6 +87,7 @@ def _gather_pallas(x, src, hb, interpret=False):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="moe_gather",
     )(src, x.reshape(T, 1, H))
     return out.reshape(N, H)
 

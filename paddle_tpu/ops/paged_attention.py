@@ -165,6 +165,7 @@ def _paged_decode(q, kb, vb, tables, lengths, scale, interpret=False,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
+        name="pallas_paged_decode",
     )(tables, lengths, q.reshape(B, nh, 1, hd), kb, vb)
     return out.reshape(B, nh, hd)
 

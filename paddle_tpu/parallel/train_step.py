@@ -28,6 +28,7 @@ from ..core.native import sanitize as _sanitize
 from ..framework.core import AsyncLoss as _AsyncLoss
 from ..monitor import benchmark as _bench
 from ..monitor import stats as _mstats
+from ..monitor import trace as _trace
 from ..monitor.trace import span as _trace_span
 from ..resilience import faults as _faults
 from ..resilience import sentinel as _sentinel
@@ -603,21 +604,29 @@ class DistributedTrainStep:
                     lambda g, s: jax.lax.with_sharding_constraint(g, s),
                     grads, self._grad_sh)
             if scaler_state is not None:
-                inv = (1.0 / scale)
-                grads = jax.tree_util.tree_map(
-                    lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
-                    grads)
-                finite = jnp.array(True)
-                for g in jax.tree_util.tree_leaves(grads):
-                    finite &= jnp.all(jnp.isfinite(g.astype(jnp.float32)))
+                with jax.named_scope("optimizer"), \
+                        jax.named_scope("loss_scale"):
+                    inv = (1.0 / scale)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: (g.astype(jnp.float32)
+                                   * inv).astype(g.dtype), grads)
+                    finite = jnp.array(True)
+                    for g in jax.tree_util.tree_leaves(grads):
+                        finite &= jnp.all(
+                            jnp.isfinite(g.astype(jnp.float32)))
             # raw (pre-clip) global grad norm: clipping would cap exactly
             # the spikes the sentinel exists to catch
             sent_gnorm = (_sentinel.global_grad_norm(grads)
                           if sent_state is not None else None)
+            # phase scopes (metadata only): monitor.trace.op_scopes
+            # tells forward, backward and optimizer apart by these
             if self._clip is not None:
-                grads, _ = global_norm_clip(grads, self._clip)
-            new_params, new_opt = self._update_fn(
-                params, grads, opt_state, lr, **self._opt_kwargs)
+                with jax.named_scope("optimizer"), \
+                        jax.named_scope("grad_clip"):
+                    grads, _ = global_norm_clip(grads, self._clip)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = self._update_fn(
+                    params, grads, opt_state, lr, **self._opt_kwargs)
             if scaler_state is not None:
                 # gate the whole update on the finite flag (reference
                 # check_finite_and_unscale semantics: a skipped step leaves
@@ -683,6 +692,9 @@ class DistributedTrainStep:
         self._lr_cache = (None, None)
         # guardian lr_backoff multiplier (scale_lr); 1.0 = untouched
         self._lr_scale = 1.0
+        # avals of the last batch stepped while tracing, until the
+        # window's stop turns them into the op_scopes table
+        self._traced_batch = None
 
     def current_lr(self) -> float:
         if callable(self._lr):
@@ -724,6 +736,10 @@ class DistributedTrainStep:
             self._seen_batch_avals.add(sig)
             _mstats.JIT_CACHE_MISS.add()
             _mstats.JIT_COMPILE.add()
+        if _trace.TRACING[0] and self._traced_batch is None:
+            self._traced_batch = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
+            _trace.on_stop(self._emit_op_scopes)
         donated = (self.params, self.opt_state,
                    self.aux if self._has_aux else None) \
             if _sanitize[0] else None
@@ -779,10 +795,28 @@ class DistributedTrainStep:
     def lower(self, batch):
         """Expose the lowered/compiled artifact (assert-on-HLO testing —
         the TPU analog of the reference's assert-on-op-list meta-optimizer
-        tests, SURVEY.md §4.6)."""
-        return self._step.lower(self.params, self.opt_state, self.aux, batch,
-                                jnp.float32(self.current_lr()),
-                                self.scaler_state, self.sentinel_state)
+        tests, SURVEY.md §4.6). ``batch`` may be avals. Lowered under
+        the mesh as ``__call__`` runs it, so this IS the step's program
+        (and compiling it again finds jax's own copy, no compiler run)."""
+        return self._lower(batch, jnp.float32(self.current_lr()))
+
+    def _lower(self, batch, lr):
+        with self.mesh:
+            return self._step.lower(
+                self.params, self.opt_state, self.aux, batch, lr,
+                self.scaler_state, self.sentinel_state)
+
+    def _emit_op_scopes(self, writer) -> None:
+        """``on_stop`` callback: the compiled step's instruction ->
+        phase/scope table as one metadata event. The caller has blocked
+        on every step in flight before stopping, so the device is quiet
+        while the step's executable is looked up again (jax keeps it:
+        no compiler runs, no compile event fires)."""
+        batch, self._traced_batch = self._traced_batch, None
+        # an aval for lr too: nothing runs on the device from here
+        lr = jax.ShapeDtypeStruct((), jnp.float32)
+        _trace.emit_op_scopes(writer, "jit_step",
+                              self._lower(batch, lr).compile().as_text())
 
     def measure_overlap(self, batch, reps: int = 2) -> dict:
         """Comm-vs-compute overlap diagnostic (FLAGS_overlap_grads).

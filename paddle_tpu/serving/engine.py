@@ -143,7 +143,14 @@ shard-balance verdicts.
 Observability v2 (ISSUE 15): latency HISTOGRAMS recorded at the source
 (serving_first_token_ms / serving_per_token_ms / serving_queue_wait_ms
 / serving_decode_tick_ms / serving_prefill_chunk_ms — live under the
-front end's Prometheus ``GET /metrics``); CAUSAL TRACING — a request
+front end's Prometheus ``GET /metrics``). In paged mode
+serving_prefill_chunk_ms times only the asynchronous DISPATCH of a chunk
+(about a millisecond whatever the chunk costs the device: nothing in the
+span waits for it), and serving_decode_tick_ms runs from the tick's
+dispatch to its tokens on the host, so it INCLUDES the device time of a
+chunk queued ahead of the tick; the device's own times are on the
+profiler's trace (the benchmark's ``decode_tick_ms.serve`` /
+``prefill_chunk_ms.serve``). CAUSAL TRACING — a request
 submitted with ``trace=TraceContext`` stamps every span it touches
 (prefill, each chunk, each decode tick via per-request
 ``serving.decode_tick`` events, the ``serving.failover_hop`` of an
@@ -154,6 +161,25 @@ FLIGHT RECORDER — ``flight_dir=`` arms a process-wide bounded ring of
 recent spans/gauge deltas that ``_abort`` and the watchdog-restart
 path dump as self-contained chrome-trace files at the moment of
 failure (pod-aware naming, multi-host merge in trace_report).
+
+The program read off its own trace (ISSUE 26): while anything records,
+every scheduler turn is one span tree — ``serving.turn`` (admission to
+the end of the decode tick) ⊃ ``serving.admit``, ``serving.prefill_chunk``,
+``serving.first_token`` (the eager slice + sample + ``int()`` of a
+prompt's first token: where the host waits for the chunk),
+``serving.decode_prep`` (sweep, grow, host arrays, block tables),
+``serving.decode_step`` ⊃ ``serving.device_wait`` (the blocking read of
+the tick's tokens), ``serving.emit`` (push / finish / evict / gauges) —
+each carrying the turn's id as ``tick``. Every request, with or without
+a front-end TraceContext, leaves one chain keyed by ``rid``:
+``serving.queue_wait`` (submit → admit), ``serving.admit_to_first``
+(admit → first token, with its ``chunks``), ``serving.request_done``.
+Span args are built only under ``recording()``; ``serving_prefill_chunks``
+counts prefill work quanta, always. The jitted programs carry
+``jax.named_scope``s (``kv_pool``, ``sampling``, and the model's
+``embed`` / ``ln`` / ``attn`` / ``mlp`` / ``head``) for xprof; the engine
+registers no ``on_stop`` table: it keeps serving while another thread
+stops the trace.
 """
 from __future__ import annotations
 
@@ -180,7 +206,8 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              SERVING_DECODE_MS, SERVING_DECODE_TICK_MS,
                              SERVING_EVICTIONS, SERVING_FIRST_TOKEN_MS,
                              SERVING_PER_TOKEN_MS, SERVING_PREEMPTIONS,
-                             SERVING_PREFILL_CHUNK_MS, SERVING_PREFILL_MS,
+                             SERVING_PREFILL_CHUNK_MS, SERVING_PREFILL_CHUNKS,
+                             SERVING_PREFILL_MS,
                              SERVING_QUEUE_DEPTH, SERVING_QUEUE_WAIT_MS,
                              SERVING_SHARDS, SERVING_SLOT_OCCUPANCY,
                              SERVING_TOKENS_PER_S,
@@ -191,8 +218,7 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
 from ..resilience import faults as _faults
 from ..resilience.sentinel import logits_finite
 from ..monitor.flight import arm_flight_recorder, dump_flight
-from ..monitor.trace import (emit_complete, emit_flow, emit_instant,
-                             recording, span)
+from ..monitor.trace import emit_complete, emit_flow, recording, span
 from .kv_cache import KVCache, PagedKVCache, cache_insert
 from .prefix_cache import RadixPrefixCache
 from .sampling import (DRAFT_SALT, sample_tokens, sample_tokens_streams,
@@ -319,12 +345,19 @@ class GenerationRequest:
             SERVING_PER_TOKEN_MS.observe(
                 (time.monotonic() - self._t_first) * 1e3
                 / (len(self.tokens) - 1))
-        if self.trace is not None and recording():
+        if recording():
+            # the last link of the request's chain (queue_wait ->
+            # admit_to_first -> request_done), keyed by rid whether or
+            # not a front end minted a TraceContext
             t = time.perf_counter()
+            args = {"rid": self.rid, "reason": reason,
+                    "tokens": len(self.tokens)}
+            if self.trace is not None:
+                args = self.trace.args(**args)
             emit_complete("serving.request_done", t, 0.0, cat="serving",
-                          args=self.trace.args(rid=self.rid, reason=reason,
-                                               tokens=len(self.tokens)))
-            emit_flow("f", self.trace.trace_id, t)
+                          args=args)
+            if self.trace is not None:
+                emit_flow("f", self.trace.trace_id, t)
 
     # -- user side -----------------------------------------------------------
     @property
@@ -421,7 +454,8 @@ class _Slot:
     """Host-side state of one occupied cache slot."""
 
     __slots__ = ("req", "length", "last_token", "generated", "pending",
-                 "resume_last", "admit_order", "tail_mode")
+                 "resume_last", "admit_order", "tail_mode", "t_admit",
+                 "chunks")
 
     def __init__(self, req: GenerationRequest, length: int, last_token: int):
         self.req = req
@@ -433,6 +467,8 @@ class _Slot:
         self.admit_order = 0          # paged: preemption picks the youngest
         self.tail_mode = False        # prefix hit: chunks continue from an
         #                               unaligned cached length (_tail_jit)
+        self.t_admit = time.perf_counter()  # serving.admit_to_first starts
+        self.chunks = 0               # prefill work quanta run so far
 
 
 class InferenceEngine:
@@ -844,9 +880,10 @@ class InferenceEngine:
     # -- compiled programs ---------------------------------------------------
     def _sample_args(self, logits, base_key, rids, steps, temps, top_ks,
                     top_ps, mask):
-        keys = stream_keys(base_key, rids, steps)
-        return sample_tokens_streams(logits, keys, temps, top_ks, top_ps,
-                                     mask=mask)
+        with jax.named_scope("sampling"):
+            keys = stream_keys(base_key, rids, steps)
+            return sample_tokens_streams(logits, keys, temps, top_ks,
+                                         top_ps, mask=mask)
 
     def _decode_fn(self, params, k, v, positions, tokens, base_key, rids,
                    steps, temps, top_ks, top_ps, mask):
@@ -872,10 +909,11 @@ class InferenceEngine:
         # exact, and the logits/cache rows past true_len are never read
         logits, (ke, ve) = gpt_prefill(self.cfg, params, tokens)
         k, v = cache_insert(k, v, slot, ke[0], ve[0])
-        last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
-                                            keepdims=False)
-        tok = sample_tokens(last[None], key, temp[None], top_k[None],
-                            top_p[None], mask=mask)[0]
+        with jax.named_scope("sampling"):
+            last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
+                                                keepdims=False)
+            tok = sample_tokens(last[None], key, temp[None], top_k[None],
+                                top_p[None], mask=mask)[0]
         return tok, k, v
 
     def _prefill_spec_fn(self, params, dparams, k, v, dk, dv, tokens, slot,
@@ -886,10 +924,11 @@ class InferenceEngine:
         k, v = cache_insert(k, v, slot, ke[0], ve[0])
         _, (dke, dve) = gpt_prefill(self.draft_cfg, dparams, tokens)
         dk, dv = cache_insert(dk, dv, slot, dke[0], dve[0])
-        last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
-                                            keepdims=False)
-        tok = sample_tokens(last[None], key, temp[None], top_k[None],
-                            top_p[None], mask=mask)[0]
+        with jax.named_scope("sampling"):
+            last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
+                                                keepdims=False)
+            tok = sample_tokens(last[None], key, temp[None], top_k[None],
+                                top_p[None], mask=mask)[0]
         return tok, k, v, dk, dv
 
     def _decode_paged_fn(self, params, kb, vb, tables, positions, tokens,
@@ -957,10 +996,12 @@ class InferenceEngine:
         for j in range(self.spec_k):
             lg, (dk, dv) = gpt_decode_step(self.draft_cfg, dparams,
                                            (dk, dv), positions + j, cur)
-            keys = stream_keys(base_key, rids, steps + j)
-            dkeys = jax.vmap(
-                lambda kk: jax.random.fold_in(kk, DRAFT_SALT))(keys)
-            cur = sample_tokens_streams(lg, dkeys, temps, top_ks, top_ps)
+            with jax.named_scope("sampling"):
+                keys = stream_keys(base_key, rids, steps + j)
+                dkeys = jax.vmap(
+                    lambda kk: jax.random.fold_in(kk, DRAFT_SALT))(keys)
+                cur = sample_tokens_streams(lg, dkeys, temps, top_ks,
+                                            top_ps)
             d_toks.append(cur)
             d_logits.append(lg)
         return (jnp.stack(d_toks, axis=1), jnp.stack(d_logits, axis=1),
@@ -974,9 +1015,10 @@ class InferenceEngine:
         vtokens = jnp.concatenate([tokens[:, None], d_toks], axis=1)
         t_logits, (k, v) = gpt_verify_step(self.cfg, params, (k, v),
                                            positions, vtokens)
-        keys = stream_keys(base_key, rids, steps)
-        out, n_emit = spec_accept(t_logits, d_logits, d_toks, keys, temps,
-                                  top_ks, top_ps)
+        with jax.named_scope("sampling"):
+            keys = stream_keys(base_key, rids, steps)
+            out, n_emit = spec_accept(t_logits, d_logits, d_toks, keys,
+                                      temps, top_ks, top_ps)
         if self._watchdog is not None:
             # per-slot finite verdict over ALL k+1 verify positions —
             # trace-time gated like the plain tick, so watchdog=off spec
@@ -995,9 +1037,10 @@ class InferenceEngine:
         vtokens = jnp.concatenate([tokens[:, None], d_toks], axis=1)
         t_logits, (kb, vb) = gpt_verify_step_paged(
             self.cfg, params, (kb, vb), tables, positions, vtokens)
-        keys = stream_keys(base_key, rids, steps)
-        out, n_emit = spec_accept(t_logits, d_logits, d_toks, keys, temps,
-                                  top_ks, top_ps)
+        with jax.named_scope("sampling"):
+            keys = stream_keys(base_key, rids, steps)
+            out, n_emit = spec_accept(t_logits, d_logits, d_toks, keys,
+                                      temps, top_ks, top_ps)
         if self._watchdog is not None:
             health = logits_finite(
                 jnp.reshape(t_logits, (t_logits.shape[0], -1)))
@@ -1590,11 +1633,17 @@ class InferenceEngine:
                         raise _faults.InjectedCrash(
                             f"injected replica crash (replica "
                             f"{self.replica_id}, tick {self._ticks})")
-                self._admit()
-                if self.paged and native.serving_jit[0]:
-                    self._prefill_chunk_tick()
-                if any(s is not None for s in self._slots):
-                    self._decode_tick()
+                # one span tree per scheduler turn; every child carries
+                # the turn's id as ``tick``
+                with span("serving.turn", cat="serving",
+                          args=self._tick_args()):
+                    with span("serving.admit", cat="serving",
+                              args=self._tick_args()):
+                        self._admit()
+                    if self.paged and native.serving_jit[0]:
+                        self._prefill_chunk_tick()
+                    if any(s is not None for s in self._slots):
+                        self._decode_tick()
         except BaseException as e:  # noqa: BLE001 — fail every request, not silently
             self._abort(e)
         finally:
@@ -1614,6 +1663,21 @@ class InferenceEngine:
             for s, st in enumerate(self._slots):
                 if st is not None:
                     self._evict(s, SHUTDOWN)
+
+    def _tick_args(self, **extra) -> Optional[dict]:
+        """Span args carrying the turn's id — built only when something
+        records (the off path allocates nothing)."""
+        if not recording():
+            return None
+        extra["tick"] = self._ticks
+        return extra
+
+    def _chain_args(self, req: GenerationRequest, **extra) -> dict:
+        """Args of a link in a request's chain: its ``rid`` always, its
+        trace ids too where a front end minted a context (call under
+        ``recording()`` only: a trace span id is allocated)."""
+        args = self._tick_args(rid=req.rid, **extra)
+        return args if req.trace is None else req.trace.args(**args)
 
     def _check_open(self) -> None:
         """Fail fast once the scheduler is gone: nothing will ever drain
@@ -1725,6 +1789,14 @@ class InferenceEngine:
                 SERVING_QUEUE_WAIT_MS.observe(wait_ms)
                 if self.overload is not None:
                     self.overload.observe_queue_wait(wait_ms)
+                if recording():
+                    # first link of the request's chain: submit -> admit
+                    emit_complete(
+                        "serving.queue_wait",
+                        time.perf_counter() - wait_ms / 1e3, wait_ms / 1e3,
+                        cat="serving",
+                        args=self._chain_args(
+                            req, resumed=req._resume is not None))
             slot = self.cache.alloc(prefer_shard=shard) if paged \
                 else self.cache.alloc()
             if paged:
@@ -1930,7 +2002,9 @@ class InferenceEngine:
         pf_ms = (time.perf_counter() - t0) * 1e3
         self._note_ms(SERVING_PREFILL_MS, "_prefill_ms", pf_ms)
         SERVING_PREFILL_CHUNK_MS.observe(pf_ms)
+        SERVING_PREFILL_CHUNKS.add(1)
         st = _Slot(req, length=S, last_token=tok)
+        st.t_admit, st.chunks = t0, 1
         self._slots[slot] = st
         self.cache.lengths[slot] = S
         if resume is not None:
@@ -1939,11 +2013,21 @@ class InferenceEngine:
             st.last_token = resume[1]
             st.generated = len(req.tokens)
             return
-        req._push(tok)
-        self._note_tokens(1)
+        self._push_first(st, tok)
         reason = self._finish_reason(st, tok)
         if reason is not None:
             self._evict(slot, reason)
+
+    def _push_first(self, st: _Slot, tok: int) -> None:
+        """Stream a request's first token and close the middle link of
+        its chain: ``serving.admit_to_first`` (admit -> first token)."""
+        st.req._push(tok)
+        self._note_tokens(1)
+        if recording():
+            emit_complete(
+                "serving.admit_to_first", st.t_admit,
+                time.perf_counter() - st.t_admit, cat="serving",
+                args=self._chain_args(st.req, chunks=st.chunks))
 
     # -- paged mode: chunked prefill + preemption ----------------------------
     def _open_decode_streams(self) -> int:
@@ -2032,6 +2116,8 @@ class InferenceEngine:
         ck_ms = (time.perf_counter() - t0) * 1e3
         self._note_ms(SERVING_PREFILL_MS, "_prefill_ms", ck_ms)
         SERVING_PREFILL_CHUNK_MS.observe(ck_ms)
+        SERVING_PREFILL_CHUNKS.add(1)
+        st.chunks += 1
         st.length += c_true
         self.cache.lengths[slot] = st.length
         st.pending = None if last else pending[c_true:]
@@ -2049,19 +2135,22 @@ class InferenceEngine:
             st.last_token = st.resume_last
             st.resume_last = None
             return
-        tok = int(sample_tokens(
-            logits[0:1, c_true - 1], self._stream_key(st.req.rid, 0),
-            jnp.float32(st.req.temperature)[None],
-            jnp.int32(st.req.top_k)[None],
-            jnp.float32(st.req.top_p)[None],
-            mask=jnp.asarray(self._mask_row(st.req)))[0])
-        st.last_token = tok
-        st.generated = 1
-        st.req._push(tok)
-        self._note_tokens(1)
-        reason = self._finish_reason(st, tok)
-        if reason is not None:
-            self._evict(slot, reason)
+        # the eager slice + sample + int() is where the host waits for
+        # the chunk (and whatever was queued ahead of it) to finish
+        with span("serving.first_token", cat="serving",
+                  args=self._tick_args(rid=st.req.rid, slot=slot)):
+            tok = int(sample_tokens(
+                logits[0:1, c_true - 1], self._stream_key(st.req.rid, 0),
+                jnp.float32(st.req.temperature)[None],
+                jnp.int32(st.req.top_k)[None],
+                jnp.float32(st.req.top_p)[None],
+                mask=jnp.asarray(self._mask_row(st.req)))[0])
+            st.last_token = tok
+            st.generated = 1
+            self._push_first(st, tok)
+            reason = self._finish_reason(st, tok)
+            if reason is not None:
+                self._evict(slot, reason)
 
     def _youngest_slot(self, exclude: int) -> Optional[int]:
         best = None
@@ -2140,80 +2229,84 @@ class InferenceEngine:
         return load
 
     def _decode_tick(self) -> None:
-        now = time.monotonic()
-        for s, st in enumerate(self._slots):
-            if st is None:
-                continue
-            if st.req._cancelled:
-                self._evict(s, CANCELLED)
-            elif st.req.deadline is not None and now > st.req.deadline:
-                self._evict(s, DEADLINE)
-        active = [s for s in range(self.n_slots)
-                  if self._slots[s] is not None
-                  and self._slots[s].pending is None]
-        if not active:
-            return
-        # speculation needs k+1 positions of cache headroom on every
-        # active slot; a near-cap slot drops the whole tick to the plain
-        # one-token program (correct, just unaccelerated) rather than
-        # splitting the batch across two programs. Constrained rows
-        # force the same fallback: draft proposals are not mask-aware,
-        # so speculating through an automaton would emit illegal tokens.
-        constrained = [s for s in active
-                       if self._slots[s].req.constraint is not None]
-        use_spec = (self.draft is not None and native.serving_jit[0]
-                    and (self.overload is None
-                         or self.overload.spec_allowed())
-                    and all(self._slots[s].length + self.spec_k + 1
-                            <= self.max_len for s in active))
-        if use_spec and constrained:
-            use_spec = False
-            CONSTRAINED_FALLBACK_TICKS.add(1)
-        if self.paged and native.serving_jit[0]:
-            if use_spec:
-                use_spec = self._try_spec_grow(active)
-            if not use_spec:
-                active = self._grow_for_decode(active)
-                if not active:
-                    return
+        # the tick's host work ahead of the dispatch: sweep, grow, the
+        # batch's host arrays and block tables
+        with span("serving.decode_prep", cat="serving",
+                  args=self._tick_args()):
+            now = time.monotonic()
+            for s, st in enumerate(self._slots):
+                if st is None:
+                    continue
+                if st.req._cancelled:
+                    self._evict(s, CANCELLED)
+                elif st.req.deadline is not None and now > st.req.deadline:
+                    self._evict(s, DEADLINE)
+            active = [s for s in range(self.n_slots)
+                      if self._slots[s] is not None
+                      and self._slots[s].pending is None]
+            if not active:
+                return
+            # speculation needs k+1 positions of cache headroom on every
+            # active slot; a near-cap slot drops the whole tick to the plain
+            # one-token program (correct, just unaccelerated) rather than
+            # splitting the batch across two programs. Constrained rows
+            # force the same fallback: draft proposals are not mask-aware,
+            # so speculating through an automaton would emit illegal tokens.
+            constrained = [s for s in active
+                           if self._slots[s].req.constraint is not None]
+            use_spec = (self.draft is not None and native.serving_jit[0]
+                        and (self.overload is None
+                             or self.overload.spec_allowed())
+                        and all(self._slots[s].length + self.spec_k + 1
+                                <= self.max_len for s in active))
+            if use_spec and constrained:
+                use_spec = False
+                CONSTRAINED_FALLBACK_TICKS.add(1)
+            if self.paged and native.serving_jit[0]:
+                if use_spec:
+                    use_spec = self._try_spec_grow(active)
+                if not use_spec:
+                    active = self._grow_for_decode(active)
+                    if not active:
+                        return
 
-        if _faults.ENABLED[0]:
-            # serving_nan fault (FLAGS_fault_inject, keyed by REQUEST id):
-            # NaN the slot's cached K/V — the deterministic stand-in for
-            # poisoned HBM — so the watchdog path is testable on CPU
+            if _faults.ENABLED[0]:
+                # serving_nan fault (FLAGS_fault_inject, keyed by REQUEST id):
+                # NaN the slot's cached K/V — the deterministic stand-in for
+                # poisoned HBM — so the watchdog path is testable on CPU
+                for s in active:
+                    f = _faults.FAULTS.take_request("serving_nan",
+                                                   self._slots[s].req.rid)
+                    if f is not None:
+                        FAULTS_INJECTED.add()
+                        self._poison_slot(s)
+
+            positions = np.zeros(self.n_slots, np.int32)
+            tokens = np.zeros(self.n_slots, np.int32)
+            temps = np.zeros(self.n_slots, np.float32)
+            top_ks = np.zeros(self.n_slots, np.int32)
+            top_ps = np.ones(self.n_slots, np.float32)
+            rids = np.zeros(self.n_slots, np.int32)
+            steps = np.zeros(self.n_slots, np.int32)
             for s in active:
-                f = _faults.FAULTS.take_request("serving_nan",
-                                               self._slots[s].req.rid)
-                if f is not None:
-                    FAULTS_INJECTED.add()
-                    self._poison_slot(s)
-
-        positions = np.zeros(self.n_slots, np.int32)
-        tokens = np.zeros(self.n_slots, np.int32)
-        temps = np.zeros(self.n_slots, np.float32)
-        top_ks = np.zeros(self.n_slots, np.int32)
-        top_ps = np.ones(self.n_slots, np.float32)
-        rids = np.zeros(self.n_slots, np.int32)
-        steps = np.zeros(self.n_slots, np.int32)
-        for s in active:
-            st = self._slots[s]
-            positions[s] = st.length
-            tokens[s] = st.last_token
-            temps[s] = st.req.temperature
-            top_ks[s] = st.req.top_k
-            top_ps[s] = st.req.top_p
-            rids[s] = st.req.rid % (2**31 - 1)
-            steps[s] = len(st.req.tokens)
-        # per-slot sampling mask: the device-resident all-true buffer on
-        # unconstrained ticks (no per-tick transfer), a fresh host array
-        # carrying each constrained row's automaton mask otherwise
-        if constrained:
-            masks = self._ones_mask.copy()
-            for s in constrained:
-                masks[s] = self._mask_row(self._slots[s].req)[0]
-            mask_arg = jnp.asarray(masks)
-        else:
-            mask_arg = self._mask_dev
+                st = self._slots[s]
+                positions[s] = st.length
+                tokens[s] = st.last_token
+                temps[s] = st.req.temperature
+                top_ks[s] = st.req.top_k
+                top_ps[s] = st.req.top_p
+                rids[s] = st.req.rid % (2**31 - 1)
+                steps[s] = len(st.req.tokens)
+            # per-slot sampling mask: the device-resident all-true buffer on
+            # unconstrained ticks (no per-tick transfer), a fresh host array
+            # carrying each constrained row's automaton mask otherwise
+            if constrained:
+                masks = self._ones_mask.copy()
+                for s in constrained:
+                    masks[s] = self._mask_row(self._slots[s].req)[0]
+                mask_arg = jnp.asarray(masks)
+            else:
+                mask_arg = self._mask_dev
 
         span_args = {"batch": len(active), "tick": self._ticks}
         if self.replica_id is not None:
@@ -2266,7 +2359,9 @@ class InferenceEngine:
                         out, health, self.cache.k, self.cache.v = got
                     else:
                         out, self.cache.k, self.cache.v = got
-                out = np.asarray(out)
+                with span("serving.device_wait", cat="serving",
+                          args=self._tick_args()):
+                    out = np.asarray(out)
                 n_emit = None
                 if moe_stats is not None:
                     self._note_moe(moe_stats, span_args)
@@ -2311,48 +2406,51 @@ class InferenceEngine:
                 return
             self._watchdog_latency(tick_ms)
 
-        emitted = 0
-        traced = []       # (req, tokens pushed) for per-request tick events
-        for s in active:
-            st = self._slots[s]
-            burst = [int(out[s])] if n_emit is None \
-                else [int(t) for t in out[s, :int(n_emit[s])]]
-            pushed = 0
-            for tok in burst:
-                st.length += 1
-                st.generated += 1
-                st.last_token = tok
-                self.cache.lengths[s] = st.length
-                st.req._push(tok)
-                emitted += 1
-                pushed += 1
-                reason = self._finish_reason(st, tok)
-                if reason is not None:
-                    self._evict(s, reason)
-                    break
-            if st.req.trace is not None:
-                traced.append((st.req, pushed))
-        if traced and recording():
-            # one per-request decode-tick event per traced participant:
-            # the causal twin of the BATCHED serving.decode_step span,
-            # letting request_report/chrome attribute this tick's time
-            # to each request riding it (gated — no cost untraced)
-            dur = tick_ms / 1e3
-            for req, n_toks in traced:
-                rq_args = req.trace.args(rid=req.rid, tokens=n_toks,
-                                         tick=self._ticks)
-                if self.replica_id is not None:
-                    rq_args["replica"] = self.replica_id
-                emit_complete("serving.decode_tick", t0, dur,
-                              cat="serving", args=rq_args)
-                emit_flow("t", req.trace.trace_id, t0)
-        if use_spec:
-            self._note_spec(self.spec_k * len(active),
-                            int(sum(int(n_emit[s]) - 1 for s in active)))
-        self._note_tokens(emitted)
-        SERVING_SLOT_OCCUPANCY.set(self.cache.occupancy)
-        if self.paged:
-            self.cache.update_gauges()   # refresh kv_fragmentation vs lengths
+        # push, finish, evict and the gauges: host work after the wait
+        with span("serving.emit", cat="serving", args=self._tick_args()):
+            emitted = 0
+            traced = []   # (req, tokens pushed) for per-request tick events
+            for s in active:
+                st = self._slots[s]
+                burst = [int(out[s])] if n_emit is None \
+                    else [int(t) for t in out[s, :int(n_emit[s])]]
+                pushed = 0
+                for tok in burst:
+                    st.length += 1
+                    st.generated += 1
+                    st.last_token = tok
+                    self.cache.lengths[s] = st.length
+                    st.req._push(tok)
+                    emitted += 1
+                    pushed += 1
+                    reason = self._finish_reason(st, tok)
+                    if reason is not None:
+                        self._evict(s, reason)
+                        break
+                if st.req.trace is not None:
+                    traced.append((st.req, pushed))
+            if traced and recording():
+                # one per-request decode-tick event per traced participant:
+                # the causal twin of the BATCHED serving.decode_step span,
+                # letting request_report/chrome attribute this tick's time
+                # to each request riding it (gated — no cost untraced)
+                dur = tick_ms / 1e3
+                for req, n_toks in traced:
+                    rq_args = req.trace.args(rid=req.rid, tokens=n_toks,
+                                             tick=self._ticks)
+                    if self.replica_id is not None:
+                        rq_args["replica"] = self.replica_id
+                    emit_complete("serving.decode_tick", t0, dur,
+                                  cat="serving", args=rq_args)
+                    emit_flow("t", req.trace.trace_id, t0)
+            if use_spec:
+                self._note_spec(self.spec_k * len(active),
+                                int(sum(int(n_emit[s]) - 1 for s in active)))
+            self._note_tokens(emitted)
+            SERVING_SLOT_OCCUPANCY.set(self.cache.occupancy)
+            if self.paged:
+                # refresh kv_fragmentation vs lengths
+                self.cache.update_gauges()
 
     def _spec_dispatch(self, active, positions, tokens, rids, steps, temps,
                        top_ks, top_ps):
@@ -2389,8 +2487,10 @@ class InferenceEngine:
             else:
                 (out, n_emit, self.cache.k, self.cache.v,
                  self.draft_cache.k, self.draft_cache.v) = got
-        return (np.asarray(out), np.asarray(n_emit),
-                None if health is None else np.asarray(health))
+        with span("serving.device_wait", cat="serving",
+                  args=self._tick_args()):
+            return (np.asarray(out), np.asarray(n_emit),
+                    None if health is None else np.asarray(health))
 
     def _finish_reason(self, st: _Slot, tok: int) -> Optional[str]:
         """Why generation stops after emitting ``tok`` (None = keep
@@ -2448,9 +2548,6 @@ class InferenceEngine:
         if self._slow_ticks >= int(self._watchdog["latency_trips"]):
             self._slow_ticks = 0
             SERVING_WATCHDOG_TRIPS.add()
-            if recording():
-                emit_instant("serving.watchdog_stall", time.perf_counter(),
-                             cat="serving")
 
     def _watchdog_restart(self, poisoned: List[int]) -> None:
         """Engine auto-restart from the last healthy state: fail ONLY the
@@ -2477,31 +2574,28 @@ class InferenceEngine:
         healthy = sorted(
             ((st.admit_order, s) for s, st in enumerate(self._slots)
              if st is not None and s not in bad), reverse=True)
-        with span("serving.watchdog_restart", cat="serving",
-                  args={"poisoned": sorted(bad), "healthy": len(healthy),
-                        "restart": self._restarts, "tick": self._ticks}):
-            for s in bad:
-                st = self._slots[s]
-                self._slots[s] = None
-                SERVING_EVICTIONS.add(1)
-                st.req._finish(WATCHDOG, WatchdogTripped(
-                    f"non-finite decode logits (request {st.req.rid})"))
-            # youngest first through appendleft => oldest ends up at the
-            # queue head, preserving admission order on replay
-            for _, s in healthy:
-                st = self._slots[s]
-                self._slots[s] = None
-                if st.req.tokens:
-                    seq = np.concatenate(
-                        [st.req.prompt,
-                         np.asarray(st.req.tokens[:-1],
-                                    np.int32)]).astype(np.int32)
-                    st.req._resume = (seq, int(st.req.tokens[-1]))
-                else:
-                    st.req._resume = None   # mid-prefill: just start over
-                with self._cv:
-                    self._queue.appendleft(st.req)
-            self._reset_cache()
+        for s in bad:
+            st = self._slots[s]
+            self._slots[s] = None
+            SERVING_EVICTIONS.add(1)
+            st.req._finish(WATCHDOG, WatchdogTripped(
+                f"non-finite decode logits (request {st.req.rid})"))
+        # youngest first through appendleft => oldest ends up at the
+        # queue head, preserving admission order on replay
+        for _, s in healthy:
+            st = self._slots[s]
+            self._slots[s] = None
+            if st.req.tokens:
+                seq = np.concatenate(
+                    [st.req.prompt,
+                     np.asarray(st.req.tokens[:-1],
+                                np.int32)]).astype(np.int32)
+                st.req._resume = (seq, int(st.req.tokens[-1]))
+            else:
+                st.req._resume = None   # mid-prefill: just start over
+            with self._cv:
+                self._queue.appendleft(st.req)
+        self._reset_cache()
         with self._cv:
             SERVING_QUEUE_DEPTH.set(len(self._queue))
         SERVING_SLOT_OCCUPANCY.set(0)

@@ -1,0 +1,446 @@
+"""ISSUE 26 — the program read off its own trace.
+
+- every scheduler turn of a paged engine is one span tree: the children
+  nest in their ``serving.turn`` and carry its ``tick``;
+- a request submitted WITHOUT a front end still yields its chain
+  (``serving.queue_wait`` -> ``serving.admit_to_first`` ->
+  ``serving.request_done``) under one ``rid``, and ``request_report``
+  reads it;
+- tracing off records nothing, and greedy tokens are identical on or off;
+- ``monitor.trace.op_scopes`` labels forward, backward and optimizer on a
+  CPU-compiled step; a traced ``DistributedTrainStep`` emits the table
+  once at ``stop_tracing()``; a failing ``on_stop`` callback never raises;
+- ``span()`` puts a ``jax.profiler.TraceAnnotation`` beside its event;
+- ``serving_prefill_chunks`` is registered and incremented, and graftlint
+  GL005/GL006 stay clean.
+"""
+import collections
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle  # noqa: F401 — jax/mesh bootstrap
+from paddle_tpu import monitor
+from paddle_tpu.models import gpt_init, gpt_loss, gpt_param_specs, gpt_tiny
+from paddle_tpu.monitor import trace
+from paddle_tpu.parallel import DistributedTrainStep, create_mesh
+from paddle_tpu.serving import InferenceEngine
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+CFG = gpt_tiny(dtype=jnp.float32, seq_len=128)
+PARAMS = gpt_init(CFG, seed=26)
+TURN = "serving.turn"
+CHILDREN = {"serving.admit", "serving.prefill_chunk", "serving.first_token",
+            "serving.decode_prep", "serving.decode_step", "serving.emit"}
+CHAIN = ["serving.queue_wait", "serving.admit_to_first",
+         "serving.request_done"]
+
+
+def _prompt(n, seed):
+    return np.random.default_rng(seed).integers(
+        0, CFG.vocab_size, n).astype(np.int32)
+
+
+@pytest.fixture(autouse=True)
+def _clean():
+    yield
+    monitor.stop_tracing()
+
+
+@pytest.fixture
+def engine():
+    engines = []
+
+    def make(**kw):
+        kw.setdefault("n_slots", 2)
+        kw.setdefault("paged", True)
+        kw.setdefault("block_size", 8)
+        kw.setdefault("prefill_chunk", 16)
+        kw.setdefault("seed", 0)
+        eng = InferenceEngine(CFG, PARAMS, **kw)
+        engines.append(eng)
+        return eng
+
+    yield make
+    for eng in engines:
+        eng.shutdown(drain=False, timeout=30)
+
+
+def _traced_run(eng, lengths=(40, 9, 33), new=5):
+    """Run a few requests under tracing; returns the serving events."""
+    writer = monitor.start_tracing()
+    try:
+        reqs = [eng.submit(_prompt(n, i), max_new_tokens=new)
+                for i, n in enumerate(lengths)]
+        toks = [r.result(timeout=120) for r in reqs]
+    finally:
+        monitor.stop_tracing()
+    return [e for e in writer.events()
+            if e.get("name", "").startswith("serving.")], reqs, toks
+
+
+class TestTurnTree:
+    @pytest.fixture(scope="class")
+    def events(self):
+        eng = InferenceEngine(CFG, PARAMS, n_slots=2, paged=True,
+                              block_size=8, prefill_chunk=16, seed=0)
+        try:
+            eng.submit(_prompt(20, 9), max_new_tokens=2).result(timeout=120)
+            evs, _, _ = _traced_run(eng)
+        finally:
+            eng.shutdown(drain=False, timeout=30)
+        return evs
+
+    def test_every_kind_of_child_appears(self, events):
+        names = {e["name"] for e in events}
+        assert CHILDREN | {TURN, "serving.device_wait"} <= names
+
+    @pytest.mark.parametrize("child", sorted(CHILDREN))
+    def test_children_nest_in_their_turn_and_share_its_tick(self, events,
+                                                            child):
+        turns = {e["args"]["tick"]: e for e in events if e["name"] == TURN}
+        assert len(turns) >= 3
+        kids = [e for e in events if e["name"] == child]
+        assert kids
+        for e in kids:
+            turn = turns[e["args"]["tick"]]
+            # microsecond stamps are truncated: allow one on either side
+            assert turn["ts"] - 1 <= e["ts"]
+            assert e["ts"] + e["dur"] <= turn["ts"] + turn["dur"] + 1
+
+    def test_device_wait_nests_in_decode_step(self, events):
+        steps = {e["args"]["tick"]: e for e in events
+                 if e["name"] == "serving.decode_step"}
+        waits = [e for e in events if e["name"] == "serving.device_wait"]
+        assert waits and len(waits) == len(steps)
+        for e in waits:
+            step = steps[e["args"]["tick"]]
+            assert step["ts"] - 1 <= e["ts"]
+            assert e["ts"] + e["dur"] <= step["ts"] + step["dur"] + 1
+
+    def test_turn_children_do_not_overlap(self, events):
+        by_tick = collections.defaultdict(list)
+        for e in events:
+            if e["name"] in CHILDREN:
+                by_tick[e["args"]["tick"]].append(e)
+        for kids in by_tick.values():
+            kids.sort(key=lambda e: e["ts"])
+            for a, b in zip(kids, kids[1:]):
+                assert a["ts"] + a["dur"] <= b["ts"] + 1
+
+
+class TestRequestChain:
+    def test_chain_under_one_rid_without_a_front_end(self, engine):
+        events, reqs, _ = _traced_run(engine())
+        for req in reqs:
+            assert req.trace is None
+            mine = sorted((e for e in events if e["name"] in CHAIN
+                           and e["args"].get("rid") == req.rid),
+                          key=lambda e: e["ts"])
+            assert [e["name"] for e in mine] == CHAIN
+            wait, first, done = mine
+            assert "trace" not in first["args"]
+            # submit -> admit ends where admit -> first token starts
+            assert abs(wait["ts"] + wait["dur"] - first["ts"]) < 5e3
+            assert first["ts"] + first["dur"] <= done["ts"] + 1
+            assert first["args"]["chunks"] == -(-req.prompt.size // 16)
+            assert done["args"]["tokens"] == 5
+            assert done["args"]["reason"] == "length"
+
+    def test_fixed_mode_chain_counts_one_quantum(self, engine):
+        events, reqs, _ = _traced_run(engine(paged=False), lengths=(12,))
+        first = [e for e in events
+                 if e["name"] == "serving.admit_to_first"]
+        assert len(first) == 1 and first[0]["args"]["chunks"] == 1
+        assert first[0]["args"]["rid"] == reqs[0].rid
+
+    def test_traced_request_keeps_its_trace_ids_on_the_chain(self, engine):
+        eng = engine()
+        ctx = monitor.mint_trace()
+        writer = monitor.start_tracing()
+        try:
+            eng.submit(_prompt(20, 3), max_new_tokens=3,
+                       trace=ctx).result(timeout=120)
+        finally:
+            monitor.stop_tracing()
+        mine = [e for e in writer.events() if e.get("name") in CHAIN]
+        assert [e["name"] for e in sorted(mine, key=lambda e: e["ts"])] \
+            == CHAIN
+        assert all(e["args"]["trace"] == ctx.trace_id for e in mine)
+
+    def test_request_report_reads_the_rid_chain(self, engine):
+        events, reqs, _ = _traced_run(engine())
+        spec = importlib.util.spec_from_file_location(
+            "trace_report", os.path.join(_ROOT, "tools", "trace_report.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        with open(os.devnull, "w") as sink:
+            out = mod.request_report(events, file=sink, top=10)
+        assert out["requests"] == len(reqs) == out["completed"]
+        rows = {r["rid"]: r for r in out["slowest"]}
+        assert set(rows) == {r.rid for r in reqs}
+        for r in rows.values():
+            assert r["trace"] is None and r["finish"] == "length"
+            assert r["admit_to_first_ms"] > 0
+            assert abs(r["lane_wait_ms"] + r["prefill_ms"] + r["decode_ms"]
+                       - r["total_ms"]) < 0.01
+
+
+class TestOffPath:
+    def test_tracing_off_records_nothing(self, engine):
+        writer = monitor.get_writer()
+        writer.clear()
+        engine().submit(_prompt(20, 1), max_new_tokens=4).result(timeout=120)
+        assert len(writer) == 0
+
+    @pytest.mark.parametrize("paged", [True, False])
+    def test_greedy_tokens_identical_on_and_off(self, engine, paged):
+        prompts = (40, 9, 33)
+        base = [engine(paged=paged).submit(
+            _prompt(n, i), max_new_tokens=5).result(timeout=120)
+            for i, n in enumerate(prompts)]
+        _, _, traced = _traced_run(engine(paged=paged), lengths=prompts)
+        assert traced == base
+
+    def test_off_path_builds_no_args(self, engine):
+        eng = engine()
+        assert eng._tick_args() is None and eng._tick_args(rid=1) is None
+
+
+class TestChunkCounter:
+    def test_registered_and_incremented(self, engine):
+        assert "serving_prefill_chunks" in monitor.DEFAULT_STATS
+        before = monitor.stat_get("serving_prefill_chunks")
+        engine().submit(_prompt(40, 2), max_new_tokens=2).result(timeout=120)
+        assert monitor.stat_get("serving_prefill_chunks") - before == 3
+        engine(paged=False).submit(
+            _prompt(12, 2), max_new_tokens=2).result(timeout=120)
+        assert monitor.stat_get("serving_prefill_chunks") - before == 4
+
+    def test_graftlint_gauges_clean(self):
+        from paddle_tpu.analysis import run_lint
+
+        findings = [f for f in run_lint([os.path.join(_ROOT, "paddle_tpu")])
+                    if f.rule in ("GL005", "GL006")]
+        assert findings == [], [f.format() for f in findings]
+
+    def test_histogram_help_says_what_paged_mode_times(self):
+        from paddle_tpu.monitor.stats import HISTOGRAM_HELP
+
+        assert "DISPATCH" in HISTOGRAM_HELP["serving_prefill_chunk_ms"]
+        assert "queued ahead" in HISTOGRAM_HELP["serving_decode_tick_ms"]
+
+
+# -- op_scopes / on_stop / TraceAnnotation ----------------------------------
+
+def _tiny_step(**kw):
+    cfg = gpt_tiny()
+    mesh = create_mesh(devices=jax.devices()[:1])
+    step = DistributedTrainStep(
+        lambda p, b: gpt_loss(cfg, p, b), gpt_init(cfg, 0),
+        gpt_param_specs(cfg), optimizer="adamw", lr=1e-3, mesh=mesh, **kw)
+    tok = np.zeros((2, 32), np.int32)
+    return step, (tok, tok)
+
+
+class TestOpScopes:
+    @pytest.mark.parametrize("op_name, want", [
+        ("jit(step)/jvp(mlp)/dot_general", "forward/mlp"),
+        ("jit(step)/transpose(jvp(mlp))/dot_general", "backward/mlp"),
+        ("jit(step)/transpose(jvp())/closed_call/checkpoint/"
+         "rematted_computation/mlp/ln/mul", "backward/ln"),
+        ("jit(step)/jvp(head_loss)/jit(log_softmax)/reduce_max",
+         "forward/head_loss"),
+        ("jit(step)/optimizer/grad_clip/mul", "optimizer/grad_clip"),
+        ("jit(step)/optimizer/mul", "optimizer"),
+        ("jit(step)/jvp(attn)/bhqd,bhkd->bhqk/dot_general", "forward/attn"),
+        ("jit(step)/jvp(attn)/jit(_flash_forward)/flash_forward/"
+         "pallas_call", "forward/flash_forward"),
+        ("jit(step)/while/body/cond/branch_1_fun/add", "forward"),
+    ])
+    def test_label_of_an_op_name(self, op_name, want):
+        hlo = (f'  %fusion.7 = f32[2]{{0}} fusion(%p), kind=kLoop, '
+               f'metadata={{op_name="{op_name}" source_line=3}}\n')
+        assert trace.op_scopes(hlo)["fusion.7"] == want
+
+    def test_no_metadata_takes_its_first_users_label(self):
+        hlo = ('  %f.1 = f32[2] fusion(%p.0), metadata={op_name='
+               '"jit(s)/transpose(jvp(mlp))/mul"}\n'
+               '  %copy-start.3 = (f32[2], f32[2]) copy-start(%f.1)\n'
+               '  %copy-done.3 = f32[2] copy-done(%copy-start.3)\n'
+               '  %lone.9 = f32[2] copy(%p.0)\n'
+               '  ROOT %add.5 = f32[2] add(%copy-done.3, %f.1), '
+               'metadata={op_name="jit(s)/optimizer/add"}\n')
+        table = trace.op_scopes(hlo)
+        assert table["copy-start.3"] == table["copy-done.3"] == "optimizer"
+        assert table["f.1"] == "backward/mlp"
+        assert "lone.9" not in table
+
+    def test_cpu_compiled_step_has_all_three_phases(self):
+        step, batch = _tiny_step(clip_norm=1.0)
+        table = trace.op_scopes(step.lower(batch).compile().as_text())
+        labels = collections.Counter(table.values())
+        phases = {v.split("/")[0] for v in labels}
+        assert phases == {"forward", "backward", "optimizer"}
+        for want in ("forward/attn", "forward/mlp", "forward/ln",
+                     "forward/embed", "forward/head_loss", "backward/attn",
+                     "backward/mlp", "optimizer/grad_clip", "optimizer"):
+            assert labels[want] > 0, (want, labels)
+
+    def test_loss_scale_scope_sits_under_the_optimizer(self):
+        step, batch = _tiny_step(dynamic_scale={"init_scale": 8.0})
+        table = trace.op_scopes(step.lower(batch).compile().as_text())
+        assert "optimizer/loss_scale" in set(table.values())
+
+    @pytest.mark.parametrize("fn, scope", [
+        ("_decode_paged_fn", "kv_pool"), ("_decode_paged_fn", "sampling"),
+        ("_decode_paged_fn", "attn"), ("_chunk_fn", "kv_pool"),
+        ("_chunk_fn", "mlp"), ("_chunk_fn", "embed")])
+    def test_engine_programs_carry_scopes(self, engine, fn, scope):
+        eng = engine()
+        i32 = np.zeros(eng.n_slots, np.int32)
+        if fn == "_decode_paged_fn":
+            low = eng._decode_paged_jit.lower(
+                eng._decode_params, eng.cache.kb, eng.cache.vb,
+                np.zeros((eng.n_slots, 4), np.int32), i32, i32,
+                eng._base_key, i32, i32, np.zeros(eng.n_slots, np.float32),
+                i32, np.ones(eng.n_slots, np.float32), eng._mask_dev)
+        else:
+            low = eng._chunk_jit.lower(
+                eng._params, eng.cache.kb, eng.cache.vb,
+                np.zeros(4, np.int32), np.zeros((1, 16), np.int32),
+                np.int32(0))
+        labels = set(trace.op_scopes(low.compile().as_text()).values())
+        assert "forward/" + scope in labels, labels
+
+
+class TestOnStop:
+    def test_traced_step_emits_the_table_once(self):
+        step, batch = _tiny_step()
+        jax.block_until_ready(step(batch))
+        writer = monitor.start_tracing()
+        loss = step(batch)
+        loss = step(batch)
+        jax.block_until_ready(loss)
+        monitor.stop_tracing()
+        tables = [e for e in writer.events() if e["ph"] == "M"]
+        assert len(tables) == 1 and tables[0]["name"] == "op_scopes"
+        assert tables[0]["args"]["program"] == "jit_step"
+        phases = {v.split("/")[0]
+                  for v in tables[0]["args"]["scopes"].values()}
+        assert phases == {"forward", "backward", "optimizer"}
+        spans = [e for e in writer.events()
+                 if e["name"] == "DistributedTrainStep.step"]
+        assert len(spans) == 2
+        # a second stop has nothing left to run; the next window asks again
+        monitor.stop_tracing()
+        assert len([e for e in writer.events() if e["ph"] == "M"]) == 1
+        writer = monitor.start_tracing()
+        jax.block_until_ready(step(batch))
+        monitor.stop_tracing()
+        assert len([e for e in writer.events() if e["ph"] == "M"]) == 1
+
+    def test_the_table_costs_no_compile(self):
+        import jax.monitoring
+
+        step, batch = _tiny_step()
+        jax.block_until_ready(step(batch))
+        seen = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda name, *a, **kw: seen.append(kw.get("fun_name"))
+            if name.endswith("backend_compile_duration") else None)
+        monitor.start_tracing()
+        jax.block_until_ready(step(batch))
+        mark = len(seen)
+        monitor.stop_tracing()
+        assert seen[mark:] == []
+
+    def test_untraced_step_registers_nothing(self):
+        step, batch = _tiny_step()
+        jax.block_until_ready(step(batch))
+        assert step._traced_batch is None
+        writer = monitor.start_tracing()
+        monitor.stop_tracing()
+        assert [e for e in writer.events() if e["ph"] == "M"] == []
+
+    def test_failing_callback_does_not_raise(self):
+        ran = []
+
+        def boom(writer):
+            raise ValueError("no table today")
+
+        writer = monitor.start_tracing()
+        trace.on_stop(boom)
+        trace.on_stop(lambda w: ran.append(w))
+        assert monitor.stop_tracing() is writer      # nothing raised
+        assert ran == [writer]
+        notes = [e for e in writer.events() if e["ph"] == "i"]
+        assert len(notes) == 1
+        assert "ValueError" in notes[0]["name"]
+        assert "no table today" in notes[0]["name"]
+        assert not trace.is_tracing()
+
+    def test_callback_runs_after_the_gate_is_off(self):
+        seen = []
+        monitor.start_tracing()
+        trace.on_stop(lambda w: seen.append(trace.is_tracing()))
+        monitor.stop_tracing()
+        assert seen == [False]
+
+
+class TestAnnotation:
+    def test_span_enters_a_trace_annotation_only_when_tracing(self,
+                                                               monkeypatch):
+        entered = []
+
+        class Note:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                entered.append("/" + self.name)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Note)
+        with trace.span("quiet"):
+            pass
+        assert entered == []
+        writer = monitor.start_tracing()
+        with trace.span("loud"):
+            entered.append("body")
+        monitor.stop_tracing()
+        assert entered == ["loud", "body", "/loud"]
+        assert [e["name"] for e in writer.events()] == ["loud"]
+
+    def test_pallas_kernels_are_named(self):
+        """Every ``pallas_call`` under ops/ bears a ``name=``, and the
+        three names the benchmark's roofline patterns look for are the
+        ones those patterns match."""
+        import ast
+        import glob
+        import re
+
+        names = []
+        for path in glob.glob(os.path.join(_ROOT, "paddle_tpu", "ops",
+                                           "*.py")):
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Call) \
+                        and isinstance(node.func, ast.Attribute) \
+                        and node.func.attr == "pallas_call":
+                    kw = {k.arg: k.value for k in node.keywords}
+                    assert "name" in kw, (path, node.lineno)
+                    names.append(kw["name"].value)
+        assert len(names) == 14 and len(set(names)) == 14
+        for pattern, kernel in (("flash_forward", "flash_forward"),
+                                ("flash_backward", "flash_backward"),
+                                ("_paged_decode", "pallas_paged_decode")):
+            assert [n for n in names if re.search(pattern, n)] == [kernel]

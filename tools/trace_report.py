@@ -844,6 +844,10 @@ def resilience_report(events: list, rows: list, file=None,
     return out
 
 
+_RID_CHAIN = ("serving.queue_wait", "serving.admit_to_first",
+              "serving.request_done")
+
+
 def request_report(events: list, file=None, top: int = 5) -> dict:
     """Per-request critical path from the causal trace context
     (ISSUE 15).
@@ -859,10 +863,20 @@ def request_report(events: list, file=None, top: int = 5) -> dict:
     request's time go: lane wait, prefill, decode, or unattributed
     STALL (scheduler queueing between ticks, failover gaps) — and the
     slowest-N breakdown says whether the tail is an admission problem
-    or a decode problem."""
+    or a decode problem. The engine's ``serving.queue_wait`` (submit →
+    admit) counts as lane wait; a request no front end traced is still
+    reported, from its rid-keyed chain (``serving.queue_wait`` →
+    ``serving.admit_to_first`` → ``serving.request_done``)."""
     traces: dict = {}
     for e in events:
-        tid = (e.get("args") or {}).get("trace")
+        a = e.get("args") or {}
+        tid = a.get("trace")
+        if tid is None and e.get("name") in _RID_CHAIN \
+                and a.get("rid") is not None:
+            # no front end minted a context: the engine's own chain
+            # (queue_wait -> admit_to_first -> request_done) is keyed
+            # by request id; negative keys cannot meet a trace id
+            tid = -1 - int(a["rid"])
         if tid is not None:
             traces.setdefault(tid, []).append(e)
     if not traces:
@@ -882,16 +896,33 @@ def request_report(events: list, file=None, top: int = 5) -> dict:
                                           "serving.prefill_chunk")) / 1e3
         decode_ms = sum(float(e.get("dur", 0)) for e in evs
                         if e["name"] == "serving.decode_tick") / 1e3
+        # the engine's own queue (submit -> admit) and admit -> first
+        # token: where a paged prompt waits behind other requests' work
+        queue_ms = sum(float(e.get("dur", 0)) for e in evs
+                       if e["name"] == "serving.queue_wait") / 1e3
+        first = [e for e in evs if e["name"] == "serving.admit_to_first"]
+        first_ms = sum(float(e.get("dur", 0)) for e in first) / 1e3
         hops = [e for e in evs if e["name"] == "serving.failover_hop"]
         total_ms = (t1 - t0) / 1e3
-        stall_ms = max(0.0, total_ms - lane_ms - prefill_ms - decode_ms)
-        phases = {"lane_wait": lane_ms, "prefill": prefill_ms,
+        if tid < 0:
+            # rid-keyed chain: no per-request chunk or tick spans, so
+            # admit -> first token stands for prefill, the rest is decode
+            prefill_ms = first_ms
+            decode_ms = max(0.0, total_ms - queue_ms - first_ms)
+        stall_ms = max(0.0, total_ms - lane_ms - queue_ms - prefill_ms
+                       - decode_ms)
+        phases = {"lane_wait": lane_ms + queue_ms, "prefill": prefill_ms,
                   "decode": decode_ms, "stall": stall_ms}
         replicas = sorted({a_of(e)["replica"] for e in evs
                            if a_of(e).get("replica") is not None})
         rows.append({
-            "trace": tid, "total_ms": round(total_ms, 3),
-            "lane_wait_ms": round(lane_ms, 3),
+            "trace": tid if tid >= 0 else None,
+            "rid": a_of(evs[0]).get("rid") if tid < 0 else None,
+            "total_ms": round(total_ms, 3),
+            "lane_wait_ms": round(lane_ms + queue_ms, 3),
+            "queue_ms": round(queue_ms, 3),
+            "admit_to_first_ms": round(first_ms, 3),
+            "chunks": a_of(first[-1]).get("chunks") if first else None,
             "prefill_ms": round(prefill_ms, 3),
             "decode_ms": round(decode_ms, 3),
             "stall_ms": round(stall_ms, 3),
@@ -930,7 +961,9 @@ def request_report(events: list, file=None, top: int = 5) -> dict:
     print(f"  {'trace':<16}{'total':>9}{'lane':>8}{'prefill':>9}"
           f"{'decode':>8}{'stall':>8}{'hops':>6}  finish", file=file)
     for r in rows[:top]:
-        print(f"  {r['trace']:<16x}{r['total_ms']:>9.1f}"
+        who = f"rid:{r['rid']}" if r["trace"] is None \
+            else f"{r['trace']:x}"
+        print(f"  {who:<16}{r['total_ms']:>9.1f}"
               f"{r['lane_wait_ms']:>8.1f}{r['prefill_ms']:>9.1f}"
               f"{r['decode_ms']:>8.1f}{r['stall_ms']:>8.1f}"
               f"{r['hops']:>6}  {r['finish']}", file=file)
