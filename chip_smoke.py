@@ -263,6 +263,29 @@ def run_traffic(eng, cfg):
     return out
 
 
+def pool_slab_moves(hlo, pool_shape):
+    """Instructions of a compiled paged program that materialise a
+    layer's slab of the KV pool or copy the pool whole: anything that
+    yields an array of the slab's shape ``(n_blocks, nh, bs, hd)``, and
+    a pool-shaped ``copy`` (alone or in a fusion's name, as in
+    ``copy_dynamic-update-slice_fusion``). The paged steps read and
+    write the pool in place; any of these is a relapse."""
+    dims = lambda shape: "[" + ",".join(str(int(d)) for d in shape) + "]"
+    slab = dims(pool_shape[:1] + pool_shape[2:])
+    pool = dims(pool_shape)
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+(\[[\d,]*\])\S* "
+                     r"([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        if (shape == slab and op != "parameter") or (
+                shape == pool and "copy" in (op, *re.split(r"[_.]", name))):
+            found.append(f"{name} = {shape} {op}")
+    return found
+
+
 def phase_serve(cfg=None, n_slots=8):
     import jax.numpy as jnp
 
@@ -286,6 +309,9 @@ def phase_serve(cfg=None, n_slots=8):
                 table_width=PROMPT_LENS[0] // BLOCK + 1).compile().as_text()
         check(MOSAIC in hlo, "serve: the engine's decode program holds no "
                              "Mosaic custom call")
+        moves = pool_slab_moves(hlo, tuple(eng.cache.kb.shape))
+        check(not moves, "serve: the decode program moves a layer's slab of "
+                         f"the KV pool, or the pool whole: {moves[:4]}")
         t0 = time.perf_counter()
         cold = run_traffic(eng, cfg)
         cold_s = time.perf_counter() - t0

@@ -952,14 +952,70 @@ def gpt_verify_step(cfg: GPTConfig, params, cache, positions, tokens):
 # garbage sink: table padding (and whole tables of unoccupied slots)
 # point at it, so stale batch lanes scatter their garbage K/V somewhere
 # no live slot ever reads.
+#
+# Every paged step addresses the WHOLE pool by (block, layer): the pool
+# is the layer scan's carry (donated by the engine's programs, so XLA
+# updates it in place), the new tokens' rows are written straight into
+# it and the table's blocks are read straight out of it. A layer's
+# (n_blocks, nh, block_size, hd) slab is never cut out, copied or put
+# back. ``li`` below is the layer: the scan's traced index, or a Python
+# int in the unrolled MoE branches.
+
+def _pool_put(pool, update, at):
+    """One in-place write of ``update`` into the pool at ``at`` (block,
+    layer, 0, offset, 0). A dynamic-update-slice keeps the pool's own
+    layout (a scatter makes XLA re-lay the whole pool out around it);
+    block ids and offsets are never negative, so no index is wrapped."""
+    return jax.lax.dynamic_update_slice(
+        pool, update.astype(pool.dtype), at, allow_negative_indices=False)
+
+
+@jax.named_scope("kv_pool")
+def _pool_write_rows(kb, vb, li, blk, off, k, v):
+    """Write new tokens' K/V into the pool at layer ``li``, in place.
+
+    blk/off (...,) int32 — each token's block and offset in it; k/v
+    (..., nh, hd). Live slots own their blocks exclusively, so the only
+    collisions are stale lanes piling onto a garbage sink."""
+    nh, hd = k.shape[-2:]
+    blk, off = blk.reshape(-1), off.reshape(-1)
+    k = k.reshape(-1, 1, 1, nh, 1, hd)
+    v = v.reshape(-1, 1, 1, nh, 1, hd)
+    for n in range(blk.shape[0]):
+        at = (blk[n], li, 0, off[n], 0)
+        kb, vb = _pool_put(kb, k[n], at), _pool_put(vb, v[n], at)
+    return kb, vb
+
+
+@jax.named_scope("kv_pool")
+def _pool_write_blocks(kb, vb, li, bids, k, v):
+    """Write whole blocks of K/V into the pool at layer ``li``, in
+    place: k/v (nh, n * bs, hd) fill blocks ``bids`` (n,) in order."""
+    bs = kb.shape[3]
+    for j in range(bids.shape[0]):
+        at = (bids[j], li, 0, 0, 0)
+        rows = slice(j * bs, (j + 1) * bs)
+        kb = _pool_put(kb, k[None, None, :, rows], at)
+        vb = _pool_put(vb, v[None, None, :, rows], at)
+    return kb, vb
+
+
+@jax.named_scope("kv_pool")
+def _pool_gather(kb, vb, li, tables):
+    """The blocks ``tables`` (..., W) names at layer ``li``, as
+    contiguous K and V (..., nh, W * bs, hd): W blocks a row are read,
+    not the layer's slab."""
+    from ..ops.paged_attention import gather_blocks
+    return gather_blocks(kb, tables, li), gather_blocks(vb, tables, li)
+
 
 @jax.named_scope("attn")
-def _dec_attn_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
-    """Attention half of the paged one-token block step (pool write +
-    paged attention + proj residual). Returns (x, kb_l, vb_l)."""
+def _dec_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions):
+    """Attention half of the paged one-token block step at layer ``li``
+    (pool write + paged attention + proj residual). Returns (x, kb, vb)."""
     B = x.shape[0]
     nh, hd = cfg.n_heads, cfg.head_dim
-    bs = kb_l.shape[2]
+    bs = kb.shape[3]
     cd = cfg.dtype
 
     h = _layer_norm(x, p["ln1_s"], p["ln1_b"])
@@ -968,33 +1024,27 @@ def _dec_attn_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
     to_heads = lambda t: t.reshape(B, nh, hd)
     q, k, v = to_heads(q), to_heads(k), to_heads(v)
 
-    # scatter each slot's new K/V into (its block, its offset); slots own
-    # their blocks exclusively so the only index collisions are stale
-    # lanes colliding on garbage block 0
     blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
-    off = positions % bs
-    kb_l = kb_l.at[blk, :, off, :].set(k.astype(kb_l.dtype))
-    vb_l = vb_l.at[blk, :, off, :].set(v.astype(vb_l.dtype))
+    kb, vb = _pool_write_rows(kb, vb, li, blk, positions % bs, k, v)
 
     from ..ops.paged_attention import paged_attention_arrays
-    o = paged_attention_arrays(q, kb_l, vb_l, tables, positions + 1,
-                               scale=1.0 / math.sqrt(hd))
+    o = paged_attention_arrays(q, kb, vb, tables, positions + 1,
+                               scale=1.0 / math.sqrt(hd), layer=li)
     o = o.reshape(B, 1, nh * hd)
 
     x = x + _dec_mm(o, p["proj_w"], cd) + p["proj_b"].astype(cd)
-    return x, kb_l, vb_l
+    return x, kb, vb
 
 
-def _block_decode_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
-    """One-token block step against one layer's slice of the block pool.
+def _block_decode_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions):
+    """One-token block step at layer ``li`` of the block pool.
 
-    x (B, 1, H); kb_l/vb_l (n_blocks, nh, block_size, hd); tables (B, W)
-    int32; positions (B,) int32 — where each slot's incoming token
-    lands. Attention routes through ops.paged_attention (Pallas kernel
-    on TPU, identical composed gather elsewhere)."""
-    x, kb_l, vb_l = _dec_attn_paged(cfg, p, x, kb_l, vb_l, tables,
-                                    positions)
-    return _dec_mlp(cfg, p, x), kb_l, vb_l
+    x (B, 1, H); kb/vb the whole pool (n_blocks, L, nh, block_size, hd);
+    tables (B, W) int32; positions (B,) int32 — where each slot's
+    incoming token lands. Attention routes through ops.paged_attention
+    (Pallas kernel on TPU, identical composed gather elsewhere)."""
+    x, kb, vb = _dec_attn_paged(cfg, p, x, kb, vb, li, tables, positions)
+    return _dec_mlp(cfg, p, x), kb, vb
 
 
 def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
@@ -1024,13 +1074,8 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
         di = mi = 0
         for i in range(cfg.n_layers):
             pa = _layer_params(blocks, i, _ATTN_KEYS)
-            with jax.named_scope("kv_pool"):
-                kb_i, vb_i = kb[:, i], vb[:, i]
-            x, kb_l, vb_l = _dec_attn_paged(cfg, pa, x, kb_i, vb_i,
-                                            tables, positions)
-            with jax.named_scope("kv_pool"):
-                kb = kb.at[:, i].set(kb_l)
-                vb = vb.at[:, i].set(vb_l)
+            x, kb, vb = _dec_attn_paged(cfg, pa, x, kb, vb, i, tables,
+                                        positions)
             if i in moe_ids:
                 pm = _layer_params(params["moe"], mi, _MOE_KEYS)
                 mi += 1
@@ -1045,14 +1090,8 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
     def step(carry, inp):
         x, kb, vb = carry
         layer_p, li = inp
-        with jax.named_scope("kv_pool"):
-            kb_l = jnp.take(kb, li, axis=1)
-            vb_l = jnp.take(vb, li, axis=1)
-        x, kb_l, vb_l = _block_decode_paged(cfg, layer_p, x, kb_l, vb_l,
-                                            tables, positions)
-        with jax.named_scope("kv_pool"):
-            kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
-            vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
+        x, kb, vb = _block_decode_paged(cfg, layer_p, x, kb, vb, li,
+                                        tables, positions)
         return (x, kb, vb), None
 
     (x, kb, vb), _ = jax.lax.scan(
@@ -1060,27 +1099,27 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
     return _head(cfg, params, x)[:, 0], (kb, vb)
 
 
-def _block_verify_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables,
+def _block_verify_paged(cfg: GPTConfig, p, x, kb, vb, li, tables,
                         positions):
-    """C-token block step against one layer's slice of the block pool.
+    """C-token block step at layer ``li`` of the block pool.
 
-    x (B, C, H); kb_l/vb_l (n_blocks, nh, block_size, hd); tables (B, W)
-    int32; positions (B,) int32 — token j of row b lands at block
-    ``tables[b, (positions[b]+j) // bs]``, offset ``(positions[b]+j) %
-    bs``. Attention is the composed table gather (the multi-query shape
-    the Pallas decode kernel does not cover); the table width W is
-    already bucketed by the engine, so gather work tracks live tokens."""
-    x, kb_l, vb_l = _verify_attn_paged(cfg, p, x, kb_l, vb_l, tables,
-                                       positions)
-    return _dec_mlp(cfg, p, x), kb_l, vb_l
+    x (B, C, H); kb/vb the whole pool (n_blocks, L, nh, block_size, hd);
+    tables (B, W) int32; positions (B,) int32 — token j of row b lands
+    at block ``tables[b, (positions[b]+j) // bs]``, offset
+    ``(positions[b]+j) % bs``. Attention is the composed table gather
+    (the multi-query shape the Pallas decode kernel does not cover); the
+    table width W is already bucketed by the engine, so gather work
+    tracks live tokens."""
+    x, kb, vb = _verify_attn_paged(cfg, p, x, kb, vb, li, tables, positions)
+    return _dec_mlp(cfg, p, x), kb, vb
 
 
 @jax.named_scope("attn")
-def _verify_attn_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
+def _verify_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions):
     """Attention half of :func:`_block_verify_paged`."""
     B, C, _ = x.shape
     nh, hd = cfg.n_heads, cfg.head_dim
-    bs = kb_l.shape[2]
+    bs = kb.shape[3]
     cd = cfg.dtype
     W = tables.shape[1]
 
@@ -1091,17 +1130,12 @@ def _verify_attn_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
     kh = k.reshape(B, C, nh, hd)
     vh = v.reshape(B, C, nh, hd)
 
-    # scatter the C new K/V of every row; live slots own their blocks
-    # exclusively (positions contiguous), so the only index collisions
-    # are stale lanes piling onto their garbage sink
+    # the C new K/V of every row (positions contiguous)
     qpos = positions[:, None] + jnp.arange(C)[None, :]        # (B, C)
     blk = jnp.take_along_axis(tables, qpos // bs, axis=1)
-    off = qpos % bs
-    kb_l = kb_l.at[blk, :, off, :].set(kh.astype(kb_l.dtype))
-    vb_l = vb_l.at[blk, :, off, :].set(vh.astype(vb_l.dtype))
+    kb, vb = _pool_write_rows(kb, vb, li, blk, qpos % bs, kh, vh)
 
-    kg = kb_l[tables].transpose(0, 2, 1, 3, 4).reshape(B, nh, W * bs, hd)
-    vg = vb_l[tables].transpose(0, 2, 1, 3, 4).reshape(B, nh, W * bs, hd)
+    kg, vg = _pool_gather(kb, vb, li, tables)     # (B, nh, W * bs, hd)
     s = jnp.einsum("bhqd,bhkd->bhqk", qh, kg.astype(qh.dtype)) \
         * (1.0 / math.sqrt(hd))
     live = jnp.arange(W * bs)[None, None, :] <= qpos[:, :, None]
@@ -1111,7 +1145,7 @@ def _verify_attn_paged(cfg: GPTConfig, p, x, kb_l, vb_l, tables, positions):
     o = o.transpose(0, 2, 1, 3).reshape(B, C, nh * hd)
 
     x = x + _dec_mm(o, p["proj_w"], cd) + p["proj_b"].astype(cd)
-    return x, kb_l, vb_l
+    return x, kb, vb
 
 
 def gpt_verify_step_paged(cfg: GPTConfig, params, pool, tables, positions,
@@ -1136,14 +1170,8 @@ def gpt_verify_step_paged(cfg: GPTConfig, params, pool, tables, positions,
     def step(carry, inp):
         x, kb, vb = carry
         layer_p, li = inp
-        with jax.named_scope("kv_pool"):
-            kb_l = jnp.take(kb, li, axis=1)
-            vb_l = jnp.take(vb, li, axis=1)
-        x, kb_l, vb_l = _block_verify_paged(cfg, layer_p, x, kb_l, vb_l,
-                                            tables, positions)
-        with jax.named_scope("kv_pool"):
-            kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
-            vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
+        x, kb, vb = _block_verify_paged(cfg, layer_p, x, kb, vb, li,
+                                        tables, positions)
         return (x, kb, vb), None
 
     cd = cfg.dtype
@@ -1181,12 +1209,13 @@ def gpt_prefill_prefix(cfg: GPTConfig, params, pool, table_row, tokens,
 
 
 @jax.named_scope("attn")
-def _chunk_attn(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
-    """Attention half of the chunked-prefill block step (pool write +
-    full-prefix attention + proj residual). Returns (x, kb_l, vb_l)."""
+def _chunk_attn(cfg: GPTConfig, p, x, kb, vb, li, table_row, start):
+    """Attention half of the chunked-prefill block step at layer ``li``
+    (pool write + full-prefix attention + proj residual). Returns
+    (x, kb, vb)."""
     _, C, H = x.shape
     nh, hd = cfg.n_heads, cfg.head_dim
-    bs = kb_l.shape[2]
+    bs = kb.shape[3]
     cd = cfg.dtype
     W = table_row.shape[0]
 
@@ -1196,17 +1225,10 @@ def _chunk_attn(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
     to_heads = lambda t: t[0].reshape(C, nh, hd).transpose(1, 0, 2)
     q, k, v = to_heads(q), to_heads(k), to_heads(v)   # (nh, C, hd)
 
-    for j in range(C // bs):
-        bid = jnp.take(table_row, start // bs + j)
-        kb_l = jax.lax.dynamic_update_slice(
-            kb_l, k[None, :, j * bs:(j + 1) * bs].astype(kb_l.dtype),
-            (bid, 0, 0, 0))
-        vb_l = jax.lax.dynamic_update_slice(
-            vb_l, v[None, :, j * bs:(j + 1) * bs].astype(vb_l.dtype),
-            (bid, 0, 0, 0))
+    bids = jnp.take(table_row, start // bs + jnp.arange(C // bs))
+    kb, vb = _pool_write_blocks(kb, vb, li, bids, k, v)
 
-    kg = kb_l[table_row].transpose(1, 0, 2, 3).reshape(nh, W * bs, hd)
-    vg = vb_l[table_row].transpose(1, 0, 2, 3).reshape(nh, W * bs, hd)
+    kg, vg = _pool_gather(kb, vb, li, table_row)      # (nh, W * bs, hd)
     s = jnp.einsum("hqd,hkd->hqk", q, kg.astype(q.dtype)) \
         * (1.0 / math.sqrt(hd))
     live = jnp.arange(W * bs)[None, :] <= (start + jnp.arange(C))[:, None]
@@ -1215,8 +1237,7 @@ def _chunk_attn(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
     o = jnp.einsum("hqk,hkd->hqd", w, vg.astype(q.dtype))
     o = o.transpose(1, 0, 2).reshape(1, C, H)
 
-    return x + o @ p["proj_w"].astype(cd) + p["proj_b"].astype(cd), \
-        kb_l, vb_l
+    return x + o @ p["proj_w"].astype(cd) + p["proj_b"].astype(cd), kb, vb
 
 
 @jax.named_scope("mlp")
@@ -1228,18 +1249,19 @@ def _chunk_mlp(cfg: GPTConfig, p, x):
     return x + h @ p["out_w"].astype(cd) + p["out_b"].astype(cd)
 
 
-def _block_chunk(cfg: GPTConfig, p, x, kb_l, vb_l, table_row, start):
-    """One transformer block over one prefill CHUNK against the pool.
+def _block_chunk(cfg: GPTConfig, p, x, kb, vb, li, table_row, start):
+    """One transformer block over one prefill CHUNK at layer ``li`` of
+    the pool.
 
-    x (1, C, H) — C is the block_size-padded chunk length; kb_l/vb_l
-    (n_blocks, nh, block_size, hd); table_row (W,) int32 — this slot's
-    table; start — tokens already cached (block-aligned, traced). The
-    chunk's K/V are written into the pool FIRST, then chunk queries
-    attend over every cached position (previous chunks + the chunk
-    itself) under the global causal mask, so the math equals one whole
-    causal pass over the same prefix."""
-    x, kb_l, vb_l = _chunk_attn(cfg, p, x, kb_l, vb_l, table_row, start)
-    return _chunk_mlp(cfg, p, x), kb_l, vb_l
+    x (1, C, H) — C is the block_size-padded chunk length; kb/vb the
+    whole pool (n_blocks, L, nh, block_size, hd); table_row (W,) int32
+    — this slot's table; start — tokens already cached (block-aligned,
+    traced). The chunk's K/V are written into the pool FIRST, then chunk
+    queries attend over every cached position (previous chunks + the
+    chunk itself) under the global causal mask, so the math equals one
+    whole causal pass over the same prefix."""
+    x, kb, vb = _chunk_attn(cfg, p, x, kb, vb, li, table_row, start)
+    return _chunk_mlp(cfg, p, x), kb, vb
 
 
 def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
@@ -1272,13 +1294,8 @@ def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
         di = mi = 0
         for i in range(cfg.n_layers):
             pa = _layer_params(blocks, i, _ATTN_KEYS)
-            with jax.named_scope("kv_pool"):
-                kb_i, vb_i = kb[:, i], vb[:, i]
-            x, kb_l, vb_l = _chunk_attn(cfg, pa, x, kb_i, vb_i,
-                                        table_row, start)
-            with jax.named_scope("kv_pool"):
-                kb = kb.at[:, i].set(kb_l)
-                vb = vb.at[:, i].set(vb_l)
+            x, kb, vb = _chunk_attn(cfg, pa, x, kb, vb, i, table_row,
+                                    start)
             if i in moe_ids:
                 pm = _layer_params(params["moe"], mi, _MOE_KEYS)
                 mi += 1
@@ -1292,14 +1309,8 @@ def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
     def step(carry, inp):
         x, kb, vb = carry
         layer_p, li = inp
-        with jax.named_scope("kv_pool"):
-            kb_l = jnp.take(kb, li, axis=1)
-            vb_l = jnp.take(vb, li, axis=1)
-        x, kb_l, vb_l = _block_chunk(cfg, layer_p, x, kb_l, vb_l, table_row,
-                                     start)
-        with jax.named_scope("kv_pool"):
-            kb = jax.lax.dynamic_update_index_in_dim(kb, kb_l, li, 1)
-            vb = jax.lax.dynamic_update_index_in_dim(vb, vb_l, li, 1)
+        x, kb, vb = _block_chunk(cfg, layer_p, x, kb, vb, li, table_row,
+                                 start)
         return (x, kb, vb), None
 
     (x, kb, vb), _ = jax.lax.scan(

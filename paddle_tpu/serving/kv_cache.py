@@ -112,6 +112,14 @@ class PagedKVCache:
 
         kb, vb : (n_blocks, n_layers, n_heads, block_size, head_dim)
 
+    Block-major: one (block, layer) pair is one contiguous
+    ``(n_heads, block_size, head_dim)`` run, which is what the paged
+    steps (models/gpt.py) address — they write the new tokens' rows and
+    read a table's blocks in place, at a layer, and never cut a layer's
+    ``(n_blocks, n_heads, block_size, head_dim)`` slab out of the pool —
+    and a whole block (every layer) is one contiguous run too, which
+    keeps the copy-on-write block copy cheap.
+
     Unlike :class:`KVCache`, a slot does not own a contiguous max_len
     strip — it owns however many ``block_size``-token blocks its prompt
     and generation have actually filled, named in order by its block

@@ -2,7 +2,9 @@
 
 The smoke itself only means something on a TPU; what can be held here is
 that it refuses to run anywhere else, that the compile cache is placed
-from outside, and that the native core is rebuilt from what git commits.
+from outside, that the native core is rebuilt from what git commits, and
+that the serve leg's reading of a compiled program finds a KV-pool slab
+move where there is one.
 """
 import os
 import shutil
@@ -10,6 +12,7 @@ import subprocess
 import sys
 
 import jax
+import pytest
 
 from paddle_tpu.device import enable_compile_cache
 
@@ -25,6 +28,38 @@ def test_smoke_refuses_to_run_without_a_tpu():
     assert "'cpu'" in proc.stderr            # names the platform it found
     assert proc.stdout == ""                 # no header, no phase, no result
     assert "paddle_tpu" not in proc.stderr   # stopped before the package
+
+
+_POOL = (1025, 24, 16, 16, 128)
+_HLO_CASES = {
+    # a relapse: a layer's slab sliced out, copied, put back; the pool re-laid out
+    "slab_slice": ("%constant_dynamic-slice_fusion.4 = bf16[1025,16,16,128]"
+                   "{3,2,1,0:T(8,128)(2,1)} fusion(%p, %i), kind=kLoop", 1),
+    "slab_copy": ("%copy.63 = bf16[1025,16,16,128]{3,1,2,0} copy(%f)", 1),
+    "slab_put_back": ("ROOT %copy_dynamic-update-slice_fusion.5 = "
+                      "bf16[1025,24,16,16,128]{4,3,2,1,0} fusion(%a, %b)", 1),
+    "pool_relayout": ("%copy.9 = bf16[1025,24,16,16,128]{4,2,3,1,0} "
+                      "copy(%pool_0_.1)", 1),
+    # in place: a row written into the carried pool, blocks gathered
+    "row_write": ("%dynamic_update_slice.608 = bf16[1025,24,16,16,128]"
+                  "{4,3,2,1,0} dynamic-update-slice(%g, %u, %a, %b, %c)", 0),
+    "block_gather": ("%fusion.12 = bf16[64,16,16,128]{3,2,1,0} "
+                     "fusion(%param_0.5, %tables)", 0),
+    "slab_parameter": ("%param_0.1 = bf16[1025,16,16,128]{3,2,1,0} "
+                       "parameter(0)", 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HLO_CASES))
+def test_pool_slab_moves_reads_a_compiled_program(case):
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    line, want = _HLO_CASES[case]
+    hlo = "HloModule m\n\nENTRY %main {\n  " + line + "\n}\n"
+    assert len(chip_smoke.pool_slab_moves(hlo, _POOL)) == want
 
 
 class TestCompileCachePlacement:

@@ -218,3 +218,158 @@ class TestPagedDecodeStep:
             jnp.asarray([tok, 0], jnp.int32))
         np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
                                    rtol=2e-4, atol=2e-4)
+
+
+class TestPoolInPlace:
+    """The paged steps address the WHOLE 5-D pool by (block, layer): the
+    kernel entry reads a layer's blocks straight out of it, and no paged
+    model step cuts a layer's slab out of the pool, copies it or puts it
+    back."""
+
+    @pytest.mark.parametrize("layer", [0, 2, 4])
+    @pytest.mark.parametrize("ragged", [False, True])
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                           (jnp.bfloat16, 2e-2)])
+    def test_layer_entry_matches_reference_on_the_slice(self, dtype, tol,
+                                                        ragged, layer):
+        """The 5-D ``layer=`` entry (interpret mode) against the composed
+        reference on that layer's slice, traced layer index as the
+        model's scan passes it."""
+        L, nh, hd, bs, W, nb, B = 5, 8, 64, 16, 4, 12, 3
+        kb = jnp.asarray(RNG.normal(size=(nb, L, nh, bs, hd)), dtype)
+        vb = jnp.asarray(RNG.normal(size=(nb, L, nh, bs, hd)), dtype)
+        q = jnp.asarray(RNG.normal(size=(B, nh, hd)), dtype)
+        tables = _tables([[5, 2, 9], [1, 7, 3, 11], [4]], W)
+        lengths = jnp.asarray([37, 64, 1], jnp.int32)
+        want = _paged_attention_reference(q, kb[:, layer], vb[:, layer],
+                                          tables, lengths, 0.125)
+        got = jax.jit(lambda li: paged_attention_arrays(
+            q, kb, vb, tables, lengths, scale=0.125, interpret=True,
+            ragged=ragged, layer=li))(jnp.int32(layer))
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=tol, atol=tol)
+        # off-TPU routing: the composed gather at (block, layer) is the
+        # reference on the slice, bit for bit
+        routed = paged_attention_arrays(q, kb, vb, tables, lengths,
+                                        scale=0.125, layer=layer)
+        np.testing.assert_array_equal(np.asarray(routed), np.asarray(want))
+
+    @staticmethod
+    def _walk(jaxpr, in_scan=False):
+        """(equation, inside a scan body?) for every equation of a jaxpr
+        and of every jaxpr nested in it."""
+        for eqn in jaxpr.eqns:
+            yield eqn, in_scan
+            inner = in_scan or eqn.primitive.name == "scan"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from TestPoolInPlace._walk(sub, inner)
+
+    @pytest.mark.parametrize("step", ["decode", "chunk", "verify"])
+    def test_no_step_materialises_a_slab(self, step):
+        """Structure of the three paged programs: inside the layer scan
+        no equation yields an array of the slab's shape
+        (n_blocks, nh, bs, hd), and the only pool-shaped values are the
+        carry and the in-place writes into it (with the pin that keeps
+        its layout)."""
+        from paddle_tpu.models import (gpt_decode_step_paged, gpt_init,
+                                       gpt_prefill_chunk, gpt_tiny,
+                                       gpt_verify_step_paged)
+        from paddle_tpu.serving import PagedKVCache
+
+        cfg = gpt_tiny(dtype=jnp.float32, seq_len=64)
+        params = gpt_init(cfg, seed=0)
+        # 7 blocks: no other array of the programs has the slab's shape
+        cache = PagedKVCache(cfg, n_slots=2, block_size=8, n_blocks=7)
+        pool = (cache.kb, cache.vb)
+        tables = jnp.zeros((2, 3), jnp.int32)
+        pos = jnp.asarray([9, 0], jnp.int32)
+        if step == "decode":
+            jaxpr = jax.make_jaxpr(
+                lambda p, kv: gpt_decode_step_paged(
+                    cfg, p, kv, tables, pos, jnp.asarray([1, 2])))(
+                        params, pool)
+        elif step == "chunk":
+            jaxpr = jax.make_jaxpr(
+                lambda p, kv: gpt_prefill_chunk(
+                    cfg, p, kv, tables[0], jnp.zeros((1, 16), jnp.int32),
+                    jnp.int32(8)))(params, pool)
+        else:
+            jaxpr = jax.make_jaxpr(
+                lambda p, kv: gpt_verify_step_paged(
+                    cfg, p, kv, tables, pos,
+                    jnp.zeros((2, 3), jnp.int32)))(params, pool)
+        pool_shape = tuple(cache.kb.shape)
+        slab_shape = pool_shape[:1] + pool_shape[2:]
+        writes = scans = 0
+        for eqn, in_scan in self._walk(jaxpr.jaxpr):
+            scans += eqn.primitive.name == "scan"
+            for out in eqn.outvars:
+                shape = tuple(getattr(out.aval, "shape", ()))
+                assert shape != slab_shape, (
+                    f"{step}: {eqn.primitive.name} yields a layer's slab "
+                    f"{shape}")
+                if shape == pool_shape and in_scan:
+                    assert eqn.primitive.name in (
+                        "dynamic_update_slice", "layout_constraint"), (
+                        f"{step}: {eqn.primitive.name} yields a pool-"
+                        "shaped value inside the layer scan")
+                    writes += eqn.primitive.name == "dynamic_update_slice"
+        assert scans == 1
+        # K and V: one row a token (decode 2, verify 2 x 3), or one
+        # block a 8-token block of the chunk (16 / 8)
+        assert writes == 2 * {"decode": 2, "chunk": 2, "verify": 6}[step]
+
+    def test_moe_paged_steps_match_contiguous(self):
+        """The unrolled MoE branches (a Python-int layer) write and read
+        the pool in place like the scanned ones: chunked prefill into the
+        pool and one paged decode step against the contiguous cache."""
+        from paddle_tpu.models import (gpt_decode_step,
+                                       gpt_decode_step_paged, gpt_init,
+                                       gpt_prefill, gpt_prefill_chunk,
+                                       gpt_tiny)
+        from paddle_tpu.serving import KVCache, PagedKVCache, cache_insert
+
+        cfg = gpt_tiny(dtype=jnp.float32, seq_len=64, moe_experts=4,
+                       moe_top_k=2, moe_every=2)
+        assert cfg.moe_layer_ids == (1, 3)
+        params = gpt_init(cfg, seed=3)
+        prompt = RNG.integers(0, cfg.vocab_size, 9).astype(np.int32)
+        S = prompt.size
+        logits, (ke, ve) = gpt_prefill(cfg, params,
+                                       jnp.asarray(prompt[None]))[:2]
+        cache = KVCache(cfg, n_slots=2)
+        k, v = cache_insert(cache.k, cache.v, 0, ke[0], ve[0])
+        tok = int(jnp.argmax(logits[0, S - 1]))
+        pos = jnp.asarray([S, 0], jnp.int32)
+        toks1 = jnp.asarray([tok, 0], jnp.int32)
+        want = gpt_decode_step(cfg, params, (k, v), pos, toks1)
+
+        paged = PagedKVCache(cfg, n_slots=2, block_size=8)
+        assert paged.grow(0, 16)
+        row = jnp.asarray(paged.table_row(0))
+        toks = np.zeros((1, 16), np.int32)
+        toks[0, :S] = prompt
+        lg, (kb, vb) = gpt_prefill_chunk(
+            cfg, params, (paged.kb, paged.vb), row, jnp.asarray(toks),
+            jnp.int32(0))
+        np.testing.assert_allclose(np.asarray(lg[0, :S]),
+                                   np.asarray(logits[0]),
+                                   rtol=2e-5, atol=2e-5)
+        # the chunk wrote every layer's rows where the table says
+        for li in range(cfg.n_layers):
+            np.testing.assert_allclose(
+                np.asarray(kb[row[0], li, :, :8]),
+                np.asarray(ke[0, li, :, :8]), rtol=1e-6, atol=1e-6)
+        got = gpt_decode_step_paged(
+            cfg, params, (kb, vb), jnp.asarray(paged.tables_array([0])),
+            pos, toks1)
+        np.testing.assert_allclose(np.asarray(got[0][0]),
+                                   np.asarray(want[0][0]),
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_array_equal(np.asarray(got[2][0]),
+                                      np.asarray(want[2][0]))
+        # the new token's row landed at (block of position 9, offset 1)
+        np.testing.assert_allclose(
+            np.asarray(got[1][0][row[1], :, :, 1]),
+            np.asarray(want[1][0][0, :, :, S]), rtol=1e-5, atol=1e-5)
