@@ -163,19 +163,29 @@ class TraceWindow:
         return self.t_stop - self.t_start
 
     def reduce(self):
-        """-> (xplane.Trace, host spans on the session clock). The
-        trace's files are deleted once read: a run writes little."""
+        """-> (xplane.Trace, its host spans). The trace's files are
+        deleted once read: a run writes little."""
         trace = xplane.Trace(xplane.find_xplane(self.dir))
         shutil.rmtree(self.dir, ignore_errors=True)
-        spans = list(trace.annotations)
-        sync = trace.sync_start()
-        if sync is not None:
-            off = sync - self._sync_perf_ns        # perf ns -> session ns
-            for ev in self.program_events:
-                if ev.get("ph") == "X":
-                    spans.append(xplane.Event(
-                        ev["name"], ev["ts"] * 1e3 + off, ev["dur"] * 1e3))
-        return trace, spans
+        return trace, host_spans(trace, self.program_events,
+                                 self._sync_perf_ns)
+
+
+def host_spans(trace, program_events, sync_perf_ns):
+    """[Event] on the session clock: the benchmark's annotations and,
+    moved there through the sync annotation (entered at
+    ``sync_perf_ns`` on ``perf_counter``), the program's spans of what
+    the host was doing. A span of one request (it says whose: ``rid``)
+    lasts that request's wait and covers every gap in it: left out."""
+    spans = list(trace.annotations)
+    sync = trace.sync_start()
+    if sync is not None:
+        off = sync - sync_perf_ns                  # perf ns -> session ns
+        for ev in program_events:
+            if ev.get("ph") == "X" and "rid" not in (ev.get("args") or {}):
+                spans.append(xplane.Event(
+                    ev["name"], ev["ts"] * 1e3 + off, ev["dur"] * 1e3))
+    return spans
 
 
 def trace_context(spec, env, tw, stats0, stats1, values):

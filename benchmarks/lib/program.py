@@ -1,6 +1,8 @@
 """The one module that touches the program under test: its builders,
-its two entry points, its counters. Everything measured or compared
-lives in the other modules of ``benchmarks/lib``."""
+its two entry points, its counters. The loss and the parameter specs of
+a model come from its family (``benchmarks/families/<family>/``).
+Everything measured or compared lives in the other modules of
+``benchmarks/lib`` and in the families."""
 import dataclasses
 import importlib
 
@@ -17,7 +19,7 @@ def build_config(config):
     sizes = config["sizes"]
     got = dataclasses.asdict(cfg)
     for k, want in sizes.items():
-        if k == "init_std":          # the benchmark's own, see weights.py
+        if k == "init_std":          # the benchmark's own, its family's
             continue
         have = got[k]
         if k in ("dtype", "param_dtype"):
@@ -29,18 +31,18 @@ def build_config(config):
     return cfg
 
 
-def build_train_step(cfg, params, step):
+def build_train_step(cfg, params, step, family):
     """``DistributedTrainStep`` on a one-device mesh, as a user builds
-    it. ``step``: the workload file's ``step`` group."""
+    it. ``step``: the workload file's ``step`` group; ``family``: the
+    configuration's, for the model's loss and parameter specs."""
     import jax
 
-    from paddle_tpu.models import gpt_loss, gpt_param_specs
     from paddle_tpu.parallel import DistributedTrainStep, create_mesh
 
     mesh = create_mesh(devices=jax.devices()[:1])
     o = step["opt"]
     return DistributedTrainStep(
-        lambda p, b: gpt_loss(cfg, p, b), params, gpt_param_specs(cfg),
+        family.train_loss(cfg), params, family.param_specs(cfg),
         optimizer=step["optimizer"], lr=step["lr"], zero=step["zero"],
         mesh=mesh, opt_kwargs={"beta1": o["beta1"], "beta2": o["beta2"],
                                "eps": o["eps"],

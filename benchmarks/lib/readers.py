@@ -7,6 +7,10 @@ A reader returns a number, or None when it finds nothing to read; the
 harness then leaves the metric out of the line. It never returns 0 for
 a share of a roofline or of a peak.
 
+The operations and bytes of a kernel come from the configuration's
+family: a ``device_trace_kernel`` file's ``"work"`` names an entry of its
+``KERNEL_WORK`` (``lib/spec.py``); no table is kept here.
+
 ``ctx`` (a ``types.SimpleNamespace``) carries: ``spec``, ``sizes``,
 ``mix``, ``peak``; from the traced sub-window ``trace`` (xplane.Trace),
 ``trace_window_s``, ``program_events`` (the program's own host spans);
@@ -19,27 +23,6 @@ import os
 import re
 
 from . import harness, work, xplane
-
-
-def _flash_args(ctx):
-    s = ctx.sizes
-    return (ctx.mix["batch"], s["n_heads"], ctx.mix["seq"],
-            s["hidden"] // s["n_heads"])
-
-
-# work functions: (ctx, number of kernel events) -> (flops, bytes) in all
-WORK = {
-    "flash_forward": lambda ctx, n: tuple(
-        n * x for x in work.flash_forward(*_flash_args(ctx))),
-    "flash_backward": lambda ctx, n: tuple(
-        n * x for x in work.flash_backward(*_flash_args(ctx))),
-    "paged_decode": lambda ctx, n: (
-        work.paged_decode(ctx.values["traced_decode_contexts"],
-                          ctx.sizes["n_heads"],
-                          ctx.sizes["hidden"] // ctx.sizes["n_heads"],
-                          ctx.sizes["n_layers"])
-        if ctx.values.get("traced_decode_contexts") else None),
-}
 
 
 def driver_value(ctx, r):
@@ -58,11 +41,16 @@ def device_trace_kernel(ctx, r):
     take for the work, over the time its events took."""
     if ctx.trace is None:
         return None
+    table = ctx.spec.family.KERNEL_WORK
+    if r["work"] not in table:
+        raise SystemExit(f"kernel {r['pattern']}: the family "
+                         f"{ctx.spec.config['family']!r} counts no work "
+                         f"{r['work']!r} (it has {sorted(table)})")
     evs = xplane.kernel_events(ctx.trace, r["pattern"])
     seconds = sum(e.dur for e in evs) / 1e9
     if not evs or seconds <= 0:
         return None
-    got = WORK[r["work"]](ctx, len(evs))
+    got = table[r["work"]](ctx, len(evs))
     if got is None:
         return None
     least, bound = work.roofline_seconds(got[0], got[1], ctx.peak)

@@ -10,8 +10,7 @@ import time
 import jax
 import numpy as np
 
-from . import check, harness, program, reference, traffic, work
-from .weights import make_params
+from . import check, harness, program, traffic
 
 BEYOND_ANY = 1e12        # a percentile that falls on a failed request
 DRAIN_S = 60.0           # how long past the close an answer is awaited
@@ -186,9 +185,10 @@ def latencies(clients, t0):
     return ttft, tpot
 
 
-def model_flops(clients, sizes, t_lo, t_hi):
+def model_flops(clients, spec, t_lo, t_hi):
     """Model FLOPs of every prompt and output token processed between
     t_lo and t_hi: a prompt counts where its first token arrived."""
+    flops, sizes = spec.family.forward_flops_per_token, spec.config["sizes"]
     total = 0.0
     for c in clients:
         p = len(c.spec["prompt"])
@@ -196,10 +196,9 @@ def model_flops(clients, sizes, t_lo, t_hi):
             if not t_lo <= t <= t_hi:
                 continue
             if i == 0:
-                total += p * work.forward_flops_per_token(
-                    sizes, p, causal_mean=True)
+                total += p * flops(sizes, p, causal_mean=True)
             else:
-                total += work.forward_flops_per_token(sizes, p + i)
+                total += flops(sizes, p + i)
     return total
 
 
@@ -245,16 +244,16 @@ def malformed(clients, vocab):
     return bad
 
 
-def compare_served(sample, sizes, seed, pad_to, lowp=None):
+def compare_served(sample, spec, seed, pad_to, lowp=None):
     """Run the reference over each sampled prompt with its served tokens.
     Returns (widest gap, widest control gap, tokens compared)."""
-    params = make_params(sizes, seed)
+    family, sizes = spec.family, spec.config["sizes"]
+    params = family.make_params(sizes, seed)
     worst = worst_low = 0.0
     n = 0
     for c in sample:
-        gap, low = reference.served_gaps(
-            params, c.spec["prompt"], c.tokens, sizes["n_heads"], pad_to,
-            lowp)
+        gap, low = family.served_gaps(
+            params, c.spec["prompt"], c.tokens, sizes, pad_to, lowp)
         worst = max(worst, float(gap.max()))
         worst_low = max(worst_low, float(low.max()))
         n += len(gap)
@@ -272,8 +271,8 @@ def run(spec, args, env):
     sizes, mix, wl = spec.config["sizes"], spec.traffic, spec.workload
     engine = wl["engine"]
     cfg = program.build_config(spec.config)
-    eng = program.build_engine(cfg, make_params(sizes, args.seed), engine,
-                               args.seed)
+    eng = program.build_engine(cfg, spec.family.make_params(sizes, args.seed),
+                               engine, args.seed)
     env["stage"]("weights made and engine built")
     try:
         warm_up(eng, mix, engine, sizes, args.seed)
@@ -326,7 +325,7 @@ def run(spec, args, env):
     eng = None
     gc.collect()
     t_ref = time.perf_counter()
-    gap, _, n_cmp = compare_served(sample, sizes, args.seed,
+    gap, _, n_cmp = compare_served(sample, spec, args.seed,
                                    pad_length(mix, sizes["seq_len"]))
     longest = max((len(c.spec["prompt"]) + len(c.tokens) for c in sample),
                   default=0)
@@ -344,7 +343,7 @@ def run(spec, args, env):
             "kv_blocks_used_peak_share":
                 100.0 * used_peak / (engine["n_blocks"] - 1),
             "model_flops_per_s":
-                model_flops(clients, sizes, t0, t_close) / args.seconds,
+                model_flops(clients, spec, t0, t_close) / args.seconds,
             "traced_decode_contexts":
                 decode_contexts(clients, tw.t_start, tw.t_stop)})
     return {"attempted": len(clients), "failed": failed, "e2e": e2e,

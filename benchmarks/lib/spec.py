@@ -11,17 +11,75 @@ adding files:
                                           parameters, rate, limits
     benchmarks/metrics/<metric>.json      a per-layer metric's reader
     benchmarks/readers/<metric>.py        (optional) a reader of its own
+    benchmarks/families/<family>/         what the harness knows of a
+                                          model's insides
+
+A configuration file names its ``family`` (there is no default), and
+the package of that name gives, as ``FAMILY`` lists:
+
+    make_params(sizes, seed)              the tree the program's builder
+                                          takes, one jitted call
+    served_gaps(params, prompt, served, sizes, pad_to, lowp=None)
+    train_readings(params0, batches, sizes, hyper, rows, lowp=None,
+                   drop_half=False)
+    leaf_norms(tree)                      the plain reference's readings
+    forward_flops_per_token(sizes, context, causal_mean=False)
+    train_flops_per_token(sizes, seq)     the model's work, from shapes
+    KERNEL_WORK                           {name: f(ctx, n_events) ->
+                                          (flops, bytes) or None}: what a
+                                          metric file's "work" names
+    train_loss(cfg), param_specs(cfg)     for ``DistributedTrainStep``
+
+What a family reads of ``sizes``, and how it blocks its reference to fit
+the chip, is its own affair; the rest of ``benchmarks/lib`` reads of
+them only ``vocab_size`` and ``seq_len``.
 """
+import functools
+import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO_ROOT = os.path.dirname(BENCH_DIR)
+FAMILY = ("make_params", "served_gaps", "train_readings", "leaf_norms",
+          "forward_flops_per_token", "train_flops_per_token", "KERNEL_WORK",
+          "train_loss", "param_specs")
 
 
 def _load(path):
     with open(path) as f:
         return json.load(f)
+
+
+def load_family(name, overlay=None):
+    """The package ``families/<name>/`` (``overlay``'s first), loaded
+    once a process; refused where it lacks a name of ``FAMILY``."""
+    dirs = [os.path.join(d, "families", name)
+            for d in (overlay, BENCH_DIR) if d]
+    init = next((os.path.join(d, "__init__.py") for d in dirs
+                 if os.path.exists(os.path.join(d, "__init__.py"))), None)
+    if init is None:
+        raise SystemExit(f"run.py: no family {name!r}: none of {dirs} "
+                         "holds an __init__.py")
+    mod_name = "bench_family_" + name
+    mod = sys.modules.get(mod_name)
+    if mod is not None and mod.__file__ == init:
+        return mod
+    mod_spec = importlib.util.spec_from_file_location(
+        mod_name, init, submodule_search_locations=[os.path.dirname(init)])
+    mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[mod_name] = mod          # the package's own imports need it
+    try:
+        mod_spec.loader.exec_module(mod)
+        lacking = [n for n in FAMILY if not hasattr(mod, n)]
+        if lacking:
+            raise SystemExit(f"run.py: family {name!r} ({init}) lacks "
+                             f"{lacking}; a family gives {list(FAMILY)}")
+    except BaseException:
+        del sys.modules[mod_name]
+        raise
+    return mod
 
 
 class Spec:
@@ -43,6 +101,13 @@ class Spec:
         self.name = workload
         self.chips = int(self.cell["chips"])
         self.config = _load(self.path("configs", self.cell["config"]))
+        if "family" not in self.config:
+            raise SystemExit(
+                f"run.py: configuration {self.cell['config']!r} "
+                f"({self.path('configs', self.cell['config'])}) names no "
+                '"family": say which package of benchmarks/families/ makes '
+                "its weights, reference and work counts; there is no "
+                "default")
         self.traffic = _load(self.path("traffic", self.cell["traffic"]))
         self.workload = _load(self.path("workloads", workload))
         self.peaks = _load(os.path.join(self.bench_dir, "lib", "peaks.json"))
@@ -53,6 +118,10 @@ class Spec:
             if os.path.exists(p):
                 return p
         return os.path.join(self.bench_dir, kind, name + ext)
+
+    @functools.cached_property
+    def family(self):
+        return load_family(self.config["family"], self.overlay)
 
     def reports(self, metric):
         """Does this cell report ``metric`` (an entry of end_to_end or

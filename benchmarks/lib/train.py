@@ -7,14 +7,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import check, harness, program, reference, traffic, work
-from .weights import make_params
+from . import check, harness, program, traffic
 
 PIPELINE = 2          # steps in flight before the host waits for a loss
 
 
-def _leaf_norms_scaled(tree, scale):
-    return {k: v * scale for k, v in reference.leaf_norms(tree).items()}
+def _leaf_norms_scaled(family, tree, scale):
+    return {k: v * scale for k, v in family.leaf_norms(tree).items()}
 
 
 @jax.jit
@@ -23,41 +22,44 @@ def _diff(a, b):
         lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b)
 
 
-def first_steps(step, ring, sizes, seed, hyper, n=3):
+def first_steps(step, ring, spec, seed, hyper, n=3):
     """Drive the step object through its first n steps, by the window's
     own call and feed, and take the readings ``correct`` compares."""
+    family = spec.family
     losses, grad_norms = [], None
     for i in range(n):
         losses.append(float(step(ring[i % len(ring)])))
         if i == 0:
             # Adam's first moment after one step is (1 - beta1) * g
             grad_norms = _leaf_norms_scaled(
-                step.opt_state["m"], 1.0 / (1.0 - hyper["beta1"]))
-    start = make_params(sizes, seed)
-    change = reference.leaf_norms(_diff(step.params, start))
+                family, step.opt_state["m"], 1.0 / (1.0 - hyper["beta1"]))
+    start = family.make_params(spec.config["sizes"], seed)
+    change = family.leaf_norms(_diff(step.params, start))
     del start
     return {"losses": losses, "grad_norms": grad_norms,
             "change_norms": change}
 
 
-def reference_readings(sizes, seed, ring, hyper, rows, n=3, **kw):
-    params0 = make_params(sizes, seed)
-    return reference.train_readings(
+def reference_readings(spec, seed, ring, hyper, rows, n=3, **kw):
+    sizes = spec.config["sizes"]
+    params0 = spec.family.make_params(sizes, seed)
+    return spec.family.train_readings(
         params0, [ring[i % len(ring)] for i in range(n)],
-        sizes["n_heads"], hyper, rows, **kw)
+        sizes, hyper, rows, **kw)
 
 
 def run(spec, args, env):
     sizes, mix, wl = spec.config["sizes"], spec.traffic, spec.workload
+    family = spec.family
     hyper = dict(wl["step"]["opt"], lr=wl["step"]["lr"])
     cfg = program.build_config(spec.config)
     ring = traffic.train_batches(mix, args.seed, sizes["vocab_size"])
     tokens_per_step = mix["batch"] * mix["seq"]
 
-    step = program.build_train_step(cfg, make_params(sizes, args.seed),
-                                    wl["step"])
+    step = program.build_train_step(
+        cfg, family.make_params(sizes, args.seed), wl["step"], family)
     env["stage"]("weights made and step built")
-    got = first_steps(step, ring, sizes, args.seed, hyper)
+    got = first_steps(step, ring, spec, args.seed, hyper)
     env["stage"]("first three steps done")
     harness.say("first losses", got["losses"])
     for i in range(3, 3 + PIPELINE + 1):          # the window's rhythm
@@ -115,7 +117,7 @@ def run(spec, args, env):
     del step, inflight, loss
     gc.collect()
     t_ref = time.perf_counter()
-    ref = reference_readings(sizes, args.seed, ring, hyper,
+    ref = reference_readings(spec, args.seed, ring, hyper,
                              wl["check"]["reference_rows"])
     compared, notes = check.train(got, ref, wl["check"]["limits"])
     harness.say(f"reference took {time.perf_counter() - t_ref:.1f} s; "
@@ -134,7 +136,7 @@ def run(spec, args, env):
             "model_flops_per_s":
                 (n_steps - traced_steps) * tokens_per_step
                 / (elapsed - (tw.t_exit - tw.t_enter))
-                * work.train_flops_per_token(sizes, mix["seq"])})
+                * family.train_flops_per_token(sizes, mix["seq"])})
         harness.say(f"traced {traced_steps} steps in {tw.window_s:.3f} s")
     return {"attempted": n_steps, "failed": 0, "e2e": e2e,
             "compared": compared, "device": device, "ctx": ctx}

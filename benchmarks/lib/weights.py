@@ -1,16 +1,14 @@
-"""Seeded weights, made on the device in one jitted call.
+"""What every family's weights share: the key a seed gives and the
+types a configuration file may name.
 
 The benchmark makes the weights, hands them to the program, and makes
 them again for the plain reference: the reference takes nothing the
-program has made. The tree has the layout the program's GPT family takes
-(`wte`, `wpe`, `blocks` with a leading layer dimension, `lnf_s`,
-`lnf_b`); biases start at zero and layer-norm scales at one, as the
-published GPT-2 initialisation has them (normal, std 0.02, residual
-projections scaled by 1/sqrt(2L)).
+program has made. Which tree that is belongs to the model's family: its
+``make_params(sizes, seed)`` is in ``benchmarks/families/<family>/``
+(the GPT block's, which stood here until PR 29, is in
+``benchmarks/families/gpt/weights.py``), one jitted call on the device
+that draws from ``seed_key(seed)``.
 """
-import functools
-import math
-
 import jax
 import jax.numpy as jnp
 
@@ -21,38 +19,3 @@ def seed_key(seed):
     """A key from any whole number (seeds pass 2**31)."""
     seed = int(seed)
     return jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF), seed >> 31)
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6, 7))
-def _make(key, V, H, L, S, M, dtype, std):
-    ks = jax.random.split(key, 6)
-
-    def nrm(k, shape, scale=std):
-        return (scale * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
-
-    res = std / math.sqrt(2 * L)
-    ones = lambda *s: jnp.ones(s, dtype)      # noqa: E731
-    zeros = lambda *s: jnp.zeros(s, dtype)    # noqa: E731
-    blocks = {
-        "ln1_s": ones(L, H), "ln1_b": zeros(L, H),
-        "qkv_w": nrm(ks[0], (L, H, 3 * H)), "qkv_b": zeros(L, 3 * H),
-        "proj_w": nrm(ks[1], (L, H, H), res), "proj_b": zeros(L, H),
-        "ln2_s": ones(L, H), "ln2_b": zeros(L, H),
-        "fc_w": nrm(ks[2], (L, H, M)), "fc_b": zeros(L, M),
-        "out_w": nrm(ks[3], (L, M, H), res), "out_b": zeros(L, H),
-    }
-    return {"wte": nrm(ks[4], (V, H)), "wpe": nrm(ks[5], (S, H), std / 2),
-            "blocks": blocks, "lnf_s": ones(H), "lnf_b": zeros(H)}
-
-
-def make_params(sizes, seed):
-    """sizes: the configuration file's ``sizes``. Weights are drawn in
-    float32 and rounded once to the type they are held in. ``init_std``
-    (0.02, GPT-2's, unless the file says otherwise) is not a size of the
-    program's: a tiny test configuration raises it so that its few
-    narrow layers, not the token's own embedding, decide the logits."""
-    return _make(seed_key(seed), sizes["vocab_size"], sizes["hidden"],
-                 sizes["n_layers"], sizes["seq_len"],
-                 sizes["hidden"] * sizes["mlp_ratio"],
-                 DTYPES[sizes["param_dtype"]],
-                 float(sizes.get("init_std", 0.02)))

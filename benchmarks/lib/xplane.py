@@ -3,9 +3,11 @@
 Read with ``jax.profiler.ProfileData`` alone. On a TPU the device plane
 is ``/device:TPU:<n>``; its line ``XLA Ops`` holds one event per
 executed HLO instruction (the event's name is the instruction's text,
-``%name.N = ...``), ``XLA Modules`` one event per program run, and the
-host plane's ``python`` line the ``TraceAnnotation`` spans. All start
-times are nanoseconds on the session's clock.
+``%name.N = ...``), ``XLA Modules`` one event per program run. The host
+plane ``/host:CPU`` has one line a thread, named for the thread (the
+main thread's for the command: ``python3`` under the benchmark's own),
+and a ``TraceAnnotation`` span lies on the line of the thread that
+entered it. All start times are nanoseconds on the session's clock.
 """
 import collections
 import glob
@@ -36,9 +38,17 @@ def find_xplane(trace_dir):
     return files[-1]
 
 
+def host_annotations(plane):
+    """The benchmark's own ``bench.``-named spans, from every line of
+    the host plane: whatever thread entered them."""
+    return [Event(e.name, float(e.start_ns), float(e.duration_ns))
+            for line in plane.lines for e in line.events
+            if e.name.startswith("bench.")]
+
+
 class Trace:
     """ops / modules: {device ordinal: [Event]} sorted by start (ns);
-    annotations: [Event] from the host's python line."""
+    annotations: [Event], the benchmark's own spans on the host."""
 
     def __init__(self, path):
         from jax.profiler import ProfileData
@@ -56,13 +66,7 @@ class Trace:
                         (self.ops if line.name == "XLA Ops"
                          else self.modules)[int(m.group(1))] = evs
             elif plane.name == "/host:CPU":
-                for line in plane.lines:
-                    if line.name == "python":
-                        self.annotations += [
-                            Event(e.name, float(e.start_ns),
-                                  float(e.duration_ns))
-                            for e in line.events
-                            if e.name.startswith("bench.")]
+                self.annotations += host_annotations(plane)
 
     def sync_start(self):
         """Session-clock start (ns) of the clock-sync annotation."""
@@ -157,3 +161,28 @@ def idle_gaps(trace, host_spans, top=10, floor_ns=20e3):
         end = e.end if end is None else max(end, e.end)
     return [[k, v] for k, v in sorted(out.items(),
                                       key=lambda kv: -kv[1])[:top]]
+
+
+def idle_by_span(trace, host_spans, top=10, floor_ns=20e3):
+    """[[what the host was doing, seconds]] for the result's breakdown:
+    the same gaps as ``idle_gaps`` finds, each shared out by overlap,
+    every stretch to the innermost span over it (the last to begin, the
+    shorter of two that begin together) or to "unattributed". A span
+    that holds others (a scheduler's turn) keeps what its children
+    leave, as ``self_seconds`` does for ops. (The arithmetic of
+    ``readers/idle_named_share.serve.py``'s log, copied.)"""
+    out = collections.Counter()
+    end = None
+    for e in trace.modules.get(0, ()):
+        if end is not None and e.start - end > floor_ns:
+            over = [s for s in host_spans
+                    if s.start < e.start and s.end > end]
+            cuts = sorted({end, e.start}
+                          | {t for s in over for t in (s.start, s.end)
+                             if end < t < e.start})
+            for a, b in zip(cuts, cuts[1:]):
+                inner = max((s for s in over if s.start <= a and s.end >= b),
+                            key=lambda s: (s.start, -s.dur), default=None)
+                out[inner.name if inner else "unattributed"] += (b - a) / 1e9
+        end = e.end if end is None else max(end, e.end)
+    return [[k, v] for k, v in out.most_common(top)]

@@ -119,7 +119,7 @@ def main(argv=None, rehearsal=None):
         device["window_s"] = ctx.trace_window_s
         result["breakdown"] = {
             "device_ops": xplane.device_ops(ctx.trace),
-            "idle_gaps": xplane.idle_gaps(ctx.trace, ctx.host_spans)}
+            "idle_gaps": xplane.idle_by_span(ctx.trace, ctx.host_spans)}
         harness.say("device time by program",
                     xplane.module_seconds(ctx.trace))
         harness.say("end-to-end in this traced run (not the metric)",

@@ -10,7 +10,7 @@ import types
 import pytest
 
 from benchmarks import run
-from benchmarks.lib import readers, spec as spec_mod, xplane
+from benchmarks.lib import harness, readers, spec as spec_mod, xplane
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FX = os.path.join(HERE, "fixtures")
@@ -230,6 +230,67 @@ def test_with_no_sync_annotation_the_offset_is_fitted(capsys, first):
     bare = types.SimpleNamespace(trace=trace, host_spans=[],
                                  program_events=[])
     assert reader("idle_named_share.serve").read(bare) is None
+
+
+# -- the two clocks ----------------------------------------------------------
+
+def host_event(name, start_ns, dur_ns):
+    return types.SimpleNamespace(name=name, start_ns=start_ns,
+                                 duration_ns=dur_ns)
+
+
+def test_annotations_come_from_every_line_of_the_host_plane():
+    # a line is named for its thread: under ``python3 benchmarks/run.py``
+    # the main thread's is ``python3``, not ``python`` (PR 26's chip runs)
+    plane = types.SimpleNamespace(lines=[
+        types.SimpleNamespace(name="python3", events=[
+            host_event("bench.clock_sync", 5000, 10),
+            host_event("PjitFunction(step)", 6000, 50)]),
+        types.SimpleNamespace(name="load-generator/77", events=[
+            host_event("bench.train_step_dispatch", 7000, 20),
+            host_event("serving.turn", 7000, 20)])])
+    assert xplane.host_annotations(plane) == [
+        E("bench.clock_sync", 5000.0, 10.0),
+        E("bench.train_step_dispatch", 7000.0, 20.0)]
+    # the recorded trace's line is ``python``: it reads as it did
+    trace = xplane.Trace(os.path.join(HERE, "data", "small.xplane.pb"))
+    assert sorted(e.name for e in trace.annotations) \
+        == ["bench.paged"] * 3 + ["bench.step"] * 3
+    assert trace.sync_start() is None
+
+
+def test_program_spans_move_to_the_trace_clock_through_the_sync():
+    events = serve_events() + [
+        span("serving.queue_wait", 0, 600_000, rid=1, tick=0),
+        span("serving.admit_to_first", 0, 600_000, rid=1, tick=0),
+        {"name": "op_scopes", "ph": "M", "args": {}}]
+    found = types.SimpleNamespace(
+        annotations=[E("bench.clock_sync", 9e9, 10.0)],
+        sync_start=lambda: 9e9)
+    # the annotation was entered at 2 s on perf_counter and lies at 9 s
+    # on the session's clock: every span moves by 7 s
+    spans = harness.host_spans(found, events, 2e9)
+    assert spans[0].name == "bench.clock_sync"
+    moved = spans[1:]
+    assert len(moved) == 6 * len(TURNS)
+    assert moved[0] == E("serving.turn", 7e9, 95_000e3)
+    # a request's chain is not what the host was doing
+    assert not {"serving.queue_wait", "serving.admit_to_first",
+                "serving.request_done"} & {s.name for s in moved}
+    gaps = dict(xplane.idle_gaps(serve_trace(off_ns=7e9), spans))
+    # idle_gaps gives a whole gap to the span that covers most of it, the
+    # turn; the breakdown shares it out to the innermost, as the reader's
+    # log does
+    assert set(gaps) == {"serving.turn"}
+    trace, mod = serve_trace(off_ns=7e9), reader("idle_named_share.serve")
+    shared = xplane.idle_by_span(trace, spans)
+    assert dict(shared) == pytest.approx(dict(mod.shared_out(trace, spans)))
+    assert [k for k, _ in shared][:2] == ["unattributed", "serving.emit"]
+    assert sum(v for _, v in shared) == pytest.approx(sum(gaps.values()))
+    assert len(xplane.idle_by_span(trace, spans, top=2)) == 2
+    # no annotation on the trace: the program's spans have no clock
+    lost = types.SimpleNamespace(annotations=[], sync_start=lambda: None)
+    assert harness.host_spans(lost, events, 2e9) == []
 
 
 def hist(total, count):

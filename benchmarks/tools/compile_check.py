@@ -1,7 +1,10 @@
 #!/usr/bin/env python3
-"""Compile-only check, no chip: do the engine's paged decode and prefill
-chunk programs at the serve cells' sizes (32 slots, a pool of 2049
-blocks) and the BERT-base train step fit a described v5e device?
+"""Compile-only check, no chip, of the ``gpt`` family's cells (it names
+them, calls the family's model functions itself and writes its per-head
+pool's shape; another family brings a check of its own): do the engine's
+paged decode and prefill chunk programs at the serve cells' sizes (32
+slots, a pool of 2049 blocks) and the BERT-base train step fit a
+described v5e device?
 
     JAX_PLATFORMS=cpu python3 benchmarks/tools/compile_check.py [--train]
 
@@ -25,7 +28,7 @@ def main():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from benchmarks.lib import program, spec as spec_mod, weights
+    from benchmarks.lib import program, spec as spec_mod
     from paddle_tpu.models.gpt import (gpt_decode_step_paged, gpt_loss,
                                        gpt_prefill_chunk)
     from paddle_tpu.parallel.train_step import (pure_adamw_init,
@@ -52,7 +55,7 @@ def main():
     sp = spec_mod.Spec("serve.gpt_1p3b.chat")
     sizes, eng = sp.config["sizes"], sp.workload["engine"]
     cfg = program.build_config(sp.config)
-    params = sds(jax.eval_shape(lambda: weights.make_params(sizes, 0)))
+    params = sds(jax.eval_shape(lambda: sp.family.make_params(sizes, 0)))
     shape = (eng["n_blocks"], cfg.n_layers, cfg.n_heads, eng["block_size"],
              cfg.head_dim)
     pool = (jax.ShapeDtypeStruct(shape, cfg.dtype, sharding=one),) * 2
@@ -73,7 +76,7 @@ def main():
         sp = spec_mod.Spec("train.bert_base.b32")
         cfg = program.build_config(sp.config)
         mix = sp.traffic
-        p = jax.eval_shape(lambda: weights.make_params(
+        p = jax.eval_shape(lambda: sp.family.make_params(
             sp.config["sizes"], 0))
         st = jax.eval_shape(pure_adamw_init, p)
 

@@ -30,25 +30,25 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 
 def train_reading(spec, seed, mode):
     from benchmarks.lib import check, program, traffic, train
-    from benchmarks.lib.weights import make_params
 
     sizes, mix, wl = spec.config["sizes"], spec.traffic, spec.workload
     hyper = dict(wl["step"]["opt"], lr=wl["step"]["lr"])
     ring = traffic.train_batches(mix, seed, sizes["vocab_size"])
     rows = wl["check"]["reference_rows"]
     if mode == "half_batch":
-        got = train.reference_readings(sizes, seed, ring, hyper, rows,
+        got = train.reference_readings(spec, seed, ring, hyper, rows,
                                        drop_half=True)
     else:
         cfg = program.build_config(spec.config)
         if mode == "control":
             cfg = dataclasses.replace(cfg, fp8=True)
-        step = program.build_train_step(cfg, make_params(sizes, seed),
-                                        wl["step"])
-        got = train.first_steps(step, ring, sizes, seed, hyper)
+        step = program.build_train_step(
+            cfg, spec.family.make_params(sizes, seed), wl["step"],
+            spec.family)
+        got = train.first_steps(step, ring, spec, seed, hyper)
         del step
         gc.collect()
-    ref = train.reference_readings(sizes, seed, ring, hyper, rows)
+    ref = train.reference_readings(spec, seed, ring, hyper, rows)
     compared, notes = check.train(got, ref, wl["check"]["limits"])
     return {"values": {k: v["value"] for k, v in compared.items()},
             "notes": notes, "losses": got["losses"],
@@ -65,7 +65,7 @@ def serve_reading(spec, seed, mode, seconds, env):
     sample = serve.pick_sample(got["clients"], seed,
                                spec.workload["check"]["sample_requests"])
     _, fp8_gap, _ = serve.compare_served(
-        sample, spec.config["sizes"], seed,
+        sample, spec, seed,
         serve.pad_length(spec.traffic, spec.config["sizes"]["seq_len"]),
         lowp="fp8")
     return {"values": {k: v["value"] for k, v in got["compared"].items()},

@@ -110,7 +110,8 @@ def main():
             first = med if first is None else first
             sp = spread(vals) if len(vals) >= 2 else float("nan")
             print(f"{m} set {k}: median {med!r} spread {sp:.4%} "
-                  f"median against set 1 {med / first - 1:+.3%} "
+                  f"median against set 1 "
+                  f"{med / first - 1 if first else float('nan'):+.3%} "
                   f"values {vals}")
     return 0
 

@@ -33,7 +33,6 @@ def main():
 
     from benchmarks.lib import (harness, program, serve, spec as spec_mod,
                                 traffic)
-    from benchmarks.lib.weights import make_params
 
     if jax.devices()[0].platform != "tpu":
         sys.stderr.write("sweep.py: needs a TPU\n")
@@ -42,7 +41,7 @@ def main():
     spec = spec_mod.Spec(a.workload)
     sizes, mix, wl = spec.config["sizes"], spec.traffic, spec.workload
     cfg = program.build_config(spec.config)
-    eng = program.build_engine(cfg, make_params(sizes, a.seed),
+    eng = program.build_engine(cfg, spec.family.make_params(sizes, a.seed),
                                wl["engine"], a.seed)
     try:
         serve.warm_up(eng, mix, wl["engine"], sizes, a.seed)
