@@ -26,6 +26,17 @@ from .gpt import (
     gpt_nano,
     bert_base_config,
 )
+from .mla import (
+    MLAConfig,
+    mla_init,
+    mla_forward,
+    mla_prefill_chunk,
+    mla_decode_step_paged,
+    mla_param_specs,
+    mla_tiny,
+    sarvam_105b,
+)
+from .serving_api import ServingModel
 from .dlrm import (
     DLRMConfig,
     dlrm_init,
@@ -45,6 +56,9 @@ __all__ = [
     "gpt_decode_step", "gpt_decode_step_paged",
     "gpt_verify_step", "gpt_verify_step_paged", "gpt_truncate",
     "gpt_tiny", "gpt_small", "gpt_1p3b", "gpt_nano", "bert_base_config",
+    "MLAConfig", "mla_init", "mla_forward", "mla_prefill_chunk",
+    "mla_decode_step_paged", "mla_param_specs", "mla_tiny", "sarvam_105b",
+    "ServingModel",
     "DLRMConfig", "dlrm_init", "dlrm_forward", "dlrm_forward_from_emb",
     "dlrm_loss", "dlrm_loss_from_emb", "dlrm_param_specs", "dlrm_score_fn",
     "dlrm_tiny", "synthetic_ctr_batches",

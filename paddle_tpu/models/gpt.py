@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..core import native as _native
 from ..ops.flash_attention import NEG_INF, _attention_reference, _on_tpu
+from .serving_api import ServingModel
 
 __all__ = ["GPTConfig", "gpt_init", "gpt_forward", "gpt_loss",
            "gpt_param_specs", "gpt_tiny", "gpt_small", "gpt_1p3b",
@@ -120,6 +121,12 @@ class GPTConfig:
             return ()
         n = max(1, int(self.moe_every))
         return tuple(i for i in range(self.n_layers) if i % n == n - 1)
+
+    def serving_model(self):
+        """What the serving engine needs of this model
+        (``models/serving_api.py``): today's two-array per-head pool and
+        the ``gpt_*`` step functions."""
+        return _SERVING
 
 
 def gpt_tiny(**kw):
@@ -1316,3 +1323,19 @@ def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
     (x, kb, vb), _ = jax.lax.scan(
         step, (x, kb, vb), (params["blocks"], jnp.arange(L)))
     return _head(cfg, params, x), (kb, vb)
+
+
+def gpt_pool_spec(cfg: GPTConfig, n_blocks: int, block_size: int):
+    """The paged pool's two arrays, keys and values: (n_blocks, L, nh,
+    block_size, hd) each."""
+    shape = (n_blocks, cfg.n_layers, cfg.n_heads, block_size, cfg.head_dim)
+    return (jax.ShapeDtypeStruct(shape, cfg.dtype),) * 2
+
+
+_SERVING = ServingModel(
+    name="gpt", pool_spec=gpt_pool_spec, param_specs=gpt_param_specs,
+    forward=gpt_forward, prefill_chunk=gpt_prefill_chunk,
+    decode_step_paged=gpt_decode_step_paged, prefill=gpt_prefill,
+    decode_step=gpt_decode_step, verify_step=gpt_verify_step,
+    verify_step_paged=gpt_verify_step_paged,
+    prefill_prefix=gpt_prefill_prefix)

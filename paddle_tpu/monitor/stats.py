@@ -367,6 +367,10 @@ DEFAULT_STATS = (
     # mixture-of-experts serving stats (ISSUE 18)
     "moe_expert_load",        # gauge: busiest-expert share of routed tokens, ppm
     "moe_tokens_dropped",     # routed assignments dropped past expert capacity
+    # an expert layer that holds a share of its experts (models/mla.py)
+    "moe_assignments_routed",  # tokens x top-k x expert layers, over all experts
+    "moe_assignments_held",    # of those, rows computed by experts held here
+    "moe_expert_reads",        # (run, layer, held expert) with at least one row
     # cross-host serving fleet (ISSUE 19)
     "fleet_hosts",            # gauge: fleet hosts with a fresh heartbeat
     "fleet_replicas",         # gauge: remote replica proxies attached to the router
@@ -474,6 +478,9 @@ FUSED_KERNEL_FALLBACKS = _registry.get_stat("fused_kernel_fallbacks")
 FP8_MATMUL_CALLS = _registry.get_stat("fp8_matmul_calls")
 MOE_EXPERT_LOAD = _registry.get_stat("moe_expert_load")
 MOE_TOKENS_DROPPED = _registry.get_stat("moe_tokens_dropped")
+MOE_ASSIGNMENTS_ROUTED = _registry.get_stat("moe_assignments_routed")
+MOE_ASSIGNMENTS_HELD = _registry.get_stat("moe_assignments_held")
+MOE_EXPERT_READS = _registry.get_stat("moe_expert_reads")
 FLEET_HOSTS = _registry.get_stat("fleet_hosts")
 FLEET_REPLICAS = _registry.get_stat("fleet_replicas")
 FLEET_KV_TRANSFER_BYTES = _registry.get_stat("fleet_kv_transfer_bytes")

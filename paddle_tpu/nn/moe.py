@@ -38,7 +38,8 @@ import jax.numpy as jnp
 
 from ..ops.moe_dispatch import moe_combine_scatter, moe_dispatch_gather
 
-__all__ = ["moe_route", "moe_ffn", "moe_capacity", "MoELayer"]
+__all__ = ["moe_route", "moe_ffn", "moe_capacity", "MoELayer",
+           "moe_route_sigmoid", "moe_ffn_held"]
 
 
 def moe_capacity(n_tokens: int, n_experts: int, top_k: int,
@@ -182,6 +183,82 @@ def moe_ffn(p, x, *, top_k: int, capacity_factor: Optional[float] = None,
         out = _expert_ffn(p, expert_in, cd)
         y = moe_combine_scatter(out.reshape(E * C, -1), slots, gates)
     return y, aux, z, counts, dropped
+
+
+def moe_route_sigmoid(router_w, router_b, x, *, top_k: int, scale: float):
+    """Sigmoid routing with a selection-only bias (the auxiliary-loss-free
+    balancing of DeepSeek-V3, arXiv:2412.19437 eq. 12-16). x (T, H);
+    router_w (H, E); router_b (E,).
+
+    ``s = sigmoid(x W)`` in float32; the ``top_k`` largest of ``s + b``
+    are chosen (ties to the lower index) and ``b`` takes no further
+    part; ``g_e = scale * s_e / sum of the chosen s``. Returns
+    ``(gates (T, k) f32, idx (T, k) i32)`` over ALL E experts, whichever
+    of them are held here."""
+    s = jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32),
+                                  router_w.astype(jnp.float32),
+                                  precision="highest"))
+    _, idx = jax.lax.top_k(s + router_b.astype(jnp.float32), int(top_k))
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    gates = float(scale) * chosen / jnp.maximum(
+        jnp.sum(chosen, axis=-1, keepdims=True), 1e-20)
+    return gates, idx.astype(jnp.int32)
+
+
+def moe_ffn_held(w_gate, w_up, w_down, x, gates, idx, *, n_experts: int,
+                 expert_offset: int, n_held: int, group_base=0, live=None,
+                 out_dtype=None):
+    """The part of a routed gated-SiLU expert layer that the experts
+    HELD here give: ``sum over chosen e in [expert_offset, expert_offset
+    + n_held) of g_e E_e(x)``. What the other experts would add is left
+    out (they live on other chips). With ``n_held = n_experts`` this is
+    the whole routed layer.
+
+    x (T, H); gates / idx (T, k) from a router over all ``n_experts``;
+    w_gate / w_up (G, H, M) and w_down (G, M, H) with ``G >= n_held``:
+    the held experts are groups ``[group_base, group_base + n_held)`` of
+    the stack (several layers' experts in one array, so that a layer's
+    weights are addressed in place and never sliced out; ``group_base``
+    may be traced). ``live`` (T,) bool leaves tokens out (batch lanes
+    that hold no request). ``out_dtype``: the type of ``y`` and of the
+    down-projection's rows (x's if None).
+
+    Dropless by construction: every assignment that falls on a held
+    expert becomes one row; the rows are sorted by expert and multiplied
+    group by group (``jax.lax.ragged_dot``: on a TPU the compiler's own
+    grouped matmul, which visits only the row tiles of groups that have
+    rows, so an expert with no row is not read). Returns ``(y (T, H),
+    counts (n_experts,) i32 assignments per expert over live tokens,
+    held i32 rows computed, reads i32 held experts with a row)``."""
+    T, k = idx.shape
+    cd = x.dtype
+    G = w_gate.shape[0]
+    lo, hi = int(expert_offset), int(expert_offset) + int(n_held)
+    on = jnp.ones((T,), bool) if live is None else live
+    held = (idx >= lo) & (idx < hi) & on[:, None]
+    counts = jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=jnp.int32)
+                     * on[:, None, None].astype(jnp.int32), axis=(0, 1))
+    sizes = counts[lo:hi]
+    n_rows = jnp.sum(sizes)
+    # held assignments first, by expert; the rest (key n_held) after
+    key = jnp.where(held, idx - lo, n_held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    rows = x[order // k]                                       # (T*k, H)
+    group_sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((G,), jnp.int32), sizes, (group_base,))
+    valid = (jnp.arange(T * k) < n_rows)[:, None]
+    g = jax.lax.ragged_dot(rows, w_gate.astype(cd), group_sizes)
+    u = jax.lax.ragged_dot(rows, w_up.astype(cd), group_sizes)
+    # rows past the last group are not the kernel's to define
+    h = jnp.where(valid, jax.nn.silu(g) * u, 0).astype(cd)
+    od = cd if out_dtype is None else out_dtype
+    out = jnp.where(valid, jax.lax.ragged_dot(
+        h, w_down.astype(cd), group_sizes, preferred_element_type=od), 0)
+    out = out[jnp.argsort(order)].reshape(T, k, -1)            # unsorted
+    w = jnp.where(held, gates, 0.0).astype(od)
+    y = jnp.einsum("tkh,tk->th", out, w,
+                   preferred_element_type=jnp.float32).astype(od)
+    return y, counts, n_rows, jnp.sum((sizes > 0).astype(jnp.int32))
 
 
 class MoELayer:

@@ -194,14 +194,11 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import native
-from ..models.gpt import (gpt_decode_step, gpt_decode_step_paged,
-                          gpt_forward, gpt_param_specs, gpt_prefill,
-                          gpt_prefill_chunk, gpt_prefill_prefix,
-                          gpt_verify_step, gpt_verify_step_paged)
 from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              CONSTRAINED_REQUESTS, FAULTS_INJECTED,
-                             MOE_EXPERT_LOAD, MOE_EXPERT_SHARE_PCT,
-                             MOE_TOKENS_DROPPED,
+                             MOE_ASSIGNMENTS_HELD, MOE_ASSIGNMENTS_ROUTED,
+                             MOE_EXPERT_LOAD, MOE_EXPERT_READS,
+                             MOE_EXPERT_SHARE_PCT, MOE_TOKENS_DROPPED,
                              PREFIX_COW_COPIES, SERVING_DEADLINE_SHEDS,
                              SERVING_DECODE_MS, SERVING_DECODE_TICK_MS,
                              SERVING_EVICTIONS, SERVING_FIRST_TOKEN_MS,
@@ -592,7 +589,10 @@ class InferenceEngine:
             self._watchdog = None
         self._restarts = 0
         self._slow_ticks = 0
-        if getattr(cfg, "fused_mlp", None) is None:
+        # the model's pool layout, step functions and parameter specs are
+        # reached through its configuration object (models/serving_api.py)
+        self._model = cfg.serving_model()
+        if hasattr(cfg, "fused_mlp") and cfg.fused_mlp is None:
             # pin the fused-MLP choice NOW (graftlint GL002): prefill
             # programs compile lazily per prompt-length bucket, so a
             # FLAGS_fused_kernels flip mid-serving would otherwise split
@@ -602,6 +602,16 @@ class InferenceEngine:
             cfg = _dc.replace(cfg, fused_mlp=bool(native.fused_kernels[0]))
         self.cfg = cfg
         self._mesh = self._resolve_mesh(mesh)
+        self.paged = native.paged_kv[0] if paged is None else bool(paged)
+        use_prefix = native.prefix_cache[0] if prefix_cache is None \
+            else bool(prefix_cache)
+        for option, asked in (("unpaged", not self.paged),
+                              ("draft", draft is not None),
+                              ("prefix_cache", use_prefix),
+                              ("int8_weights", int8_weights),
+                              ("mesh", self._mesh is not None)):
+            if asked and option in self._model.refuses:
+                raise ValueError(self._model.refuses[option])
         self._shards = int(self._mesh.shape["data"]) \
             if self._mesh is not None else 1
         if self._mesh is not None:
@@ -617,6 +627,10 @@ class InferenceEngine:
                 raise ValueError(f"n_heads={cfg.n_heads} not divisible by "
                                  f"the model degree {model_deg}")
         self._moe = bool(getattr(cfg, "moe_layer_ids", ()))
+        # router stats ride LAST in a step program's outputs
+        self._routed = self._moe or self._model.routed
+        # chunk router stats not yet read: (span args, device stats)
+        self._moe_pending = []
         if self._moe:
             import dataclasses as _dc
 
@@ -651,7 +665,6 @@ class InferenceEngine:
             INT8_MATMUL_CALLS.add()
         else:
             self._decode_params = self._params
-        self.paged = native.paged_kv[0] if paged is None else bool(paged)
         # cache construction args, kept for the watchdog's restart path
         # (a restart rebuilds the device cache from scratch)
         self._cache_args = (max_len, n_blocks, block_size)
@@ -667,9 +680,14 @@ class InferenceEngine:
                     f"block_size={self.block_size} (chunks must start "
                     "block-aligned)")
             self.prefill_chunk = int(prefill_chunk)
+            # the pool's arrays ride as positional arguments 1..n, all
+            # donated: (params, kb, vb, ...) for the per-head pair
+            self._n_pool = len(self.cache.pool)
+            pool_args = tuple(range(1, 1 + self._n_pool))
             self._decode_paged_jit = jax.jit(self._decode_paged_fn,
-                                             donate_argnums=(1, 2))
-            self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=(1, 2))
+                                             donate_argnums=pool_args)
+            self._chunk_jit = jax.jit(self._chunk_fn,
+                                      donate_argnums=pool_args)
             if self._mesh is not None:
                 self.cache.kb = self._put_cache(self.cache.kb)
                 self.cache.vb = self._put_cache(self.cache.vb)
@@ -681,8 +699,6 @@ class InferenceEngine:
                 self.cache.k = self._put_cache(self.cache.k)
                 self.cache.v = self._put_cache(self.cache.v)
         self.n_slots = self.cache.n_slots
-        use_prefix = native.prefix_cache[0] if prefix_cache is None \
-            else bool(prefix_cache)
         if use_prefix and not self.paged:
             raise ValueError("prefix_cache requires the paged KV cache "
                              "(FLAGS_paged_kv=1 or paged=True) — sharing "
@@ -811,7 +827,8 @@ class InferenceEngine:
         if self._mesh is None:
             return jax.device_put(params)
         from ..parallel.sharding import shard_params
-        return shard_params(params, gpt_param_specs(cfg), self._mesh)
+        return shard_params(params, cfg.serving_model().param_specs(cfg),
+                            self._mesh)
 
     def _put_cache(self, buf):
         return jax.device_put(buf, NamedSharding(self._mesh, _CACHE_SPEC))
@@ -852,6 +869,7 @@ class InferenceEngine:
                     f"draft n_heads={draft_cfg.n_heads} not divisible by "
                     f"the model degree {model_deg}")
         self.draft_cfg = draft_cfg
+        self._draft_model = draft_cfg.serving_model()
         self._draft_params = self._put_params(draft_cfg, draft_params)
         self.draft = (draft_cfg, self._draft_params)
         self.spec_k = int(spec_k)
@@ -887,7 +905,8 @@ class InferenceEngine:
 
     def _decode_fn(self, params, k, v, positions, tokens, base_key, rids,
                    steps, temps, top_ks, top_ps, mask):
-        got = gpt_decode_step(self.cfg, params, (k, v), positions, tokens)
+        got = self._model.decode_step(self.cfg, params, (k, v), positions,
+                                      tokens)
         logits, (k, v) = got[0], got[1]
         toks = self._sample_args(logits, base_key, rids, steps, temps,
                                  top_ks, top_ps, mask)
@@ -907,7 +926,7 @@ class InferenceEngine:
                     top_k, top_p, mask):
         # tokens (1, S_pad) end-padded; causality keeps positions < true_len
         # exact, and the logits/cache rows past true_len are never read
-        logits, (ke, ve) = gpt_prefill(self.cfg, params, tokens)
+        logits, (ke, ve) = self._model.prefill(self.cfg, params, tokens)
         k, v = cache_insert(k, v, slot, ke[0], ve[0])
         with jax.named_scope("sampling"):
             last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
@@ -920,9 +939,10 @@ class InferenceEngine:
                          true_len, key, temp, top_k, top_p, mask):
         # target prefill + draft prefill in ONE program: both caches seed
         # the same slot so the first speculative tick can draft at once
-        logits, (ke, ve) = gpt_prefill(self.cfg, params, tokens)
+        logits, (ke, ve) = self._model.prefill(self.cfg, params, tokens)
         k, v = cache_insert(k, v, slot, ke[0], ve[0])
-        _, (dke, dve) = gpt_prefill(self.draft_cfg, dparams, tokens)
+        _, (dke, dve) = self._draft_model.prefill(self.draft_cfg, dparams,
+                                                  tokens)
         dk, dv = cache_insert(dk, dv, slot, dke[0], dve[0])
         with jax.named_scope("sampling"):
             last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
@@ -931,19 +951,22 @@ class InferenceEngine:
                                 top_p[None], mask=mask)[0]
         return tok, k, v, dk, dv
 
-    def _decode_paged_fn(self, params, kb, vb, tables, positions, tokens,
-                         base_key, rids, steps, temps, top_ks, top_ps,
-                         mask):
-        got = gpt_decode_step_paged(
-            self.cfg, params, (kb, vb), tables, positions, tokens)
-        logits, (kb, vb) = got[0], got[1]
+    def _decode_paged_fn(self, params, *args):
+        # args: the pool's arrays, then (tables, positions, tokens,
+        # base_key, rids, steps, temps, top_ks, top_ps, mask)
+        pool, (tables, positions, tokens, base_key, rids, steps, temps,
+               top_ks, top_ps, mask) = \
+            args[:self._n_pool], args[self._n_pool:]
+        got = self._model.decode_step_paged(
+            self.cfg, params, pool, tables, positions, tokens)
+        logits, pool = got[0], got[1]
         toks = self._sample_args(logits, base_key, rids, steps, temps,
                                  top_ks, top_ps, mask)
         out = (toks,)
         if self._watchdog is not None:
             out = out + (logits_finite(logits),)
-        out = out + (kb, vb)
-        if self._moe:
+        out = out + tuple(pool)
+        if self._routed:
             out = out + (got[2],)
         return out
 
@@ -951,7 +974,7 @@ class InferenceEngine:
         # prefix-cache tail chunk: continue a prefill from an UNALIGNED
         # cached length (the radix match ends wherever the shared prompt
         # diverges); only the final chunk's last live row is read
-        logits, (kb, vb) = gpt_prefill_prefix(
+        logits, (kb, vb) = self._model.prefill_prefix(
             self.cfg, params, (kb, vb), table_row, tokens, start)
         return logits, kb, vb
 
@@ -964,22 +987,26 @@ class InferenceEngine:
         vb = jax.lax.dynamic_update_slice_in_dim(vb, vr, dst, axis=0)
         return kb, vb
 
-    def _chunk_fn(self, params, kb, vb, table_row, tokens, start):
-        # one prefill chunk: writes the chunk's K/V into the pool, returns
-        # the chunk logits (only the final chunk's last live row is read)
-        logits, (kb, vb) = gpt_prefill_chunk(
-            self.cfg, params, (kb, vb), table_row, tokens, start)
-        return logits, kb, vb
+    def _chunk_fn(self, params, *args):
+        # one prefill chunk: writes the chunk's rows into the pool,
+        # returns the chunk logits (only the final chunk's last live row
+        # is read); args: the pool's arrays, then (table_row, tokens,
+        # start); a routed model's router stats ride last
+        pool, (table_row, tokens, start) = \
+            args[:self._n_pool], args[self._n_pool:]
+        got = self._model.prefill_chunk(
+            self.cfg, params, pool, table_row, tokens, start)
+        return (got[0],) + tuple(got[1]) + tuple(got[2:])
 
     def _chunk_spec_fn(self, params, dparams, kb, vb, dk, dv, table_row,
                        slot, tokens, start):
         # paged target chunk + the same chunk appended to the draft's
         # fixed cache row (gpt_verify_step doubles as a chunk append)
-        logits, (kb, vb) = gpt_prefill_chunk(
+        logits, (kb, vb) = self._model.prefill_chunk(
             self.cfg, params, (kb, vb), table_row, tokens, start)
         row_k = jax.lax.dynamic_slice_in_dim(dk, slot, 1, axis=0)
         row_v = jax.lax.dynamic_slice_in_dim(dv, slot, 1, axis=0)
-        _, (row_k, row_v) = gpt_verify_step(
+        _, (row_k, row_v) = self._draft_model.verify_step(
             self.draft_cfg, dparams, (row_k, row_v),
             jnp.reshape(start, (1,)), tokens)
         dk = jax.lax.dynamic_update_slice_in_dim(dk, row_k, slot, axis=0)
@@ -994,8 +1021,8 @@ class InferenceEngine:
         cur = tokens
         d_toks, d_logits = [], []
         for j in range(self.spec_k):
-            lg, (dk, dv) = gpt_decode_step(self.draft_cfg, dparams,
-                                           (dk, dv), positions + j, cur)
+            lg, (dk, dv) = self._draft_model.decode_step(
+                self.draft_cfg, dparams, (dk, dv), positions + j, cur)
             with jax.named_scope("sampling"):
                 keys = stream_keys(base_key, rids, steps + j)
                 dkeys = jax.vmap(
@@ -1013,7 +1040,7 @@ class InferenceEngine:
             dparams, dk, dv, positions, tokens, base_key, rids, steps,
             temps, top_ks, top_ps)
         vtokens = jnp.concatenate([tokens[:, None], d_toks], axis=1)
-        t_logits, (k, v) = gpt_verify_step(self.cfg, params, (k, v),
+        t_logits, (k, v) = self._model.verify_step(self.cfg, params, (k, v),
                                            positions, vtokens)
         with jax.named_scope("sampling"):
             keys = stream_keys(base_key, rids, steps)
@@ -1035,7 +1062,7 @@ class InferenceEngine:
             dparams, dk, dv, positions, tokens, base_key, rids, steps,
             temps, top_ks, top_ps)
         vtokens = jnp.concatenate([tokens[:, None], d_toks], axis=1)
-        t_logits, (kb, vb) = gpt_verify_step_paged(
+        t_logits, (kb, vb) = self._model.verify_step_paged(
             self.cfg, params, (kb, vb), tables, positions, vtokens)
         with jax.named_scope("sampling"):
             keys = stream_keys(base_key, rids, steps)
@@ -1250,7 +1277,7 @@ class InferenceEngine:
                 width = eng.cache.table_width if table_width is None \
                     else eng._width_bucket(int(table_width))
                 return eng._decode_paged_jit.lower(
-                    eng._decode_params, eng.cache.kb, eng.cache.vb,
+                    eng._decode_params, *eng.cache.pool,
                     np.zeros((n, width), np.int32), *tail)
             return eng._decode_jit.lower(
                 eng._decode_params, eng.cache.k, eng.cache.v, *tail)
@@ -1990,7 +2017,7 @@ class InferenceEngine:
                         np.int32(req.top_k), np.float32(req.top_p),
                         jnp.asarray(self._mask_row(req)))
             else:
-                logits = gpt_forward(self.cfg, self._params,
+                logits = self._model.forward(self.cfg, self._params,
                                      jnp.asarray(seq[None]))
                 tok = sample_tokens(
                     logits[:, -1], self._stream_key(req.rid, 0),
@@ -2109,10 +2136,16 @@ class InferenceEngine:
                     jnp.asarray(row), np.int32(slot), jnp.asarray(toks),
                     np.int32(st.length))
             else:
-                logits, self.cache.kb, self.cache.vb = self._chunk_jit(
-                    self._params, self.cache.kb, self.cache.vb,
-                    jnp.asarray(row), jnp.asarray(toks),
-                    np.int32(st.length))
+                got = self._chunk_jit(
+                    self._params, *self.cache.pool, jnp.asarray(row),
+                    jnp.asarray(toks), np.int32(st.length))
+                logits = got[0]
+                self.cache.pool = tuple(got[1:1 + self._n_pool])
+                if self._model.routed:
+                    # read once the device is known to be past the chunk
+                    # (the tick's wait, a first token): dispatch stays
+                    # asynchronous, and the span's args are filled then
+                    self._moe_pending.append((ck_args, got[-1]))
         ck_ms = (time.perf_counter() - t0) * 1e3
         self._note_ms(SERVING_PREFILL_MS, "_prefill_ms", ck_ms)
         SERVING_PREFILL_CHUNK_MS.observe(ck_ms)
@@ -2147,6 +2180,7 @@ class InferenceEngine:
                 mask=jnp.asarray(self._mask_row(st.req)))[0])
             st.last_token = tok
             st.generated = 1
+            self._note_moe_pending()
             self._push_first(st, tok)
             reason = self._finish_reason(st, tok)
             if reason is not None:
@@ -2336,17 +2370,17 @@ class InferenceEngine:
                         max(len(self.cache.block_tables[s])
                             for s in active))]
                     got = self._decode_paged_jit(
-                        self._decode_params, self.cache.kb,
-                        self.cache.vb, tables, positions, tokens,
-                        self._base_key, rids, steps, temps, top_ks,
-                        top_ps, mask_arg)
+                        self._decode_params, *self.cache.pool, tables,
+                        positions, tokens, self._base_key, rids, steps,
+                        temps, top_ks, top_ps, mask_arg)
                     moe_stats = None
-                    if self._moe:
+                    if self._routed:
                         *got, moe_stats = got
                     if self._watchdog is not None:
-                        out, health, self.cache.kb, self.cache.vb = got
+                        out, health, *pool = got
                     else:
-                        out, self.cache.kb, self.cache.vb = got
+                        out, *pool = got
+                    self.cache.pool = tuple(pool)
                 else:
                     got = self._decode_jit(
                         self._decode_params, self.cache.k, self.cache.v,
@@ -2365,6 +2399,7 @@ class InferenceEngine:
                 n_emit = None
                 if moe_stats is not None:
                     self._note_moe(moe_stats, span_args)
+                self._note_moe_pending()
             else:
                 # reference decode: full recompute per sequence, no cache
                 out = np.zeros(self.n_slots, np.int32)
@@ -2374,7 +2409,7 @@ class InferenceEngine:
                     st = self._slots[s]
                     seq = np.concatenate(
                         [st.req.prompt, np.asarray(st.req.tokens, np.int32)])
-                    logits = gpt_forward(self.cfg, self._params,
+                    logits = self._model.forward(self.cfg, self._params,
                                          jnp.asarray(seq[None]))
                     if health is not None:
                         health[s] = bool(np.all(np.isfinite(
@@ -2635,9 +2670,25 @@ class InferenceEngine:
         and the cumulative dropped-assignment counter. Decode is
         dropless (C=T), so dropped stays 0 there; the counter exists
         for parity with training capacity accounting."""
-        counts, dropped = moe_stats
+        if self._model.routed:
+            # a model that holds a share of its experts: (assignments per
+            # expert over all of them, rows computed here, experts read);
+            # dropless by construction
+            counts, held, reads = moe_stats
+            dropped = 0
+        else:
+            (counts, dropped), held = moe_stats, None
         counts = np.asarray(counts, np.int64)
         total = int(counts.sum())
+        if held is not None:
+            held, reads = int(np.asarray(held)), int(np.asarray(reads))
+            MOE_ASSIGNMENTS_ROUTED.add(total)
+            MOE_ASSIGNMENTS_HELD.add(held)
+            MOE_EXPERT_READS.add(reads)
+            if span_args is not None:
+                span_args.update(moe_assignments_routed=total,
+                                 moe_assignments_held=held,
+                                 moe_expert_reads=reads)
         if total > 0:
             shares = counts / total
             MOE_EXPERT_LOAD.set(int(float(shares.max()) * 1e6))
@@ -2650,6 +2701,16 @@ class InferenceEngine:
             span_args["moe_busiest_pct"] = round(
                 float(counts.max()) / total * 100.0, 2)
             span_args["moe_dropped"] = nd
+
+    def _note_moe_pending(self) -> None:
+        """Router stats of chunks dispatched earlier, now that the
+        device has run them: counters, and the args of each chunk's
+        ``serving.prefill_chunk`` span (the event holds the dict)."""
+        if not self._moe_pending:
+            return
+        pending, self._moe_pending = self._moe_pending, []
+        for args, stats in pending:
+            self._note_moe(stats, args)
 
     def _note_ms(self, gauge, attr: str, ms: float) -> None:
         old = getattr(self, attr)

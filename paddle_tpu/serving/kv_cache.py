@@ -108,9 +108,17 @@ class PagedKVCache:
     plus per-slot block tables — vLLM-style PagedAttention memory, TPU
     shaped.
 
-    Device side: ONE pair of donated pool buffers
+    Device side: the donated pool buffers ``pool``, a tuple whose layout
+    the MODEL gives (``cfg.serving_model().pool_spec``, models/
+    serving_api.py): every array is ``(n_blocks, n_layers, ...)``. The
+    GPT family's is one pair
 
         kb, vb : (n_blocks, n_layers, n_heads, block_size, head_dim)
+
+    (``kb`` / ``vb`` name ``pool[0]`` / ``pool[1]`` of such a pair); a
+    latent-attention model's is one array ``(n_blocks, n_layers,
+    block_size, row)``. The allocator, the tables and the gauges below
+    know nothing of it.
 
     Block-major: one (block, layer) pair is one contiguous
     ``(n_heads, block_size, head_dim)`` run, which is what the paged
@@ -201,10 +209,10 @@ class PagedKVCache:
                 "blocks (the first block of each shard range is its "
                 "reserved garbage sink)")
         self.dtype = cfg.dtype if dtype is None else dtype
-        shape = (self.n_blocks, cfg.n_layers, cfg.n_heads, self.block_size,
-                 cfg.head_dim)
-        self.kb = jnp.zeros(shape, self.dtype)
-        self.vb = jnp.zeros(shape, self.dtype)
+        self.pool = tuple(
+            jnp.zeros(a.shape, a.dtype if dtype is None else dtype)
+            for a in cfg.serving_model().pool_spec(
+                cfg, self.n_blocks, self.block_size))
         self.lengths = np.zeros(self.n_slots, np.int32)
         self.block_tables: List[List[int]] = [[] for _ in range(self.n_slots)]
         # per-shard free lists; the first block of each range is the sink
@@ -447,9 +455,33 @@ class PagedKVCache:
 
     update_gauges = _update_gauges
 
+    # -- the per-head pair's two names ---------------------------------------
+    def _pair(self):
+        if len(self.pool) != 2:
+            raise AttributeError(
+                f"this pool is {len(self.pool)} array(s), not a key/value "
+                "pair: address it as .pool")
+        return self.pool
+
+    @property
+    def kb(self):
+        return self._pair()[0]
+
+    @kb.setter
+    def kb(self, value):
+        self.pool = (value, self._pair()[1])
+
+    @property
+    def vb(self):
+        return self._pair()[1]
+
+    @vb.setter
+    def vb(self, value):
+        self.pool = (self._pair()[0], value)
+
     @property
     def nbytes(self) -> int:
-        return int(self.kb.nbytes) + int(self.vb.nbytes)
+        return sum(int(a.nbytes) for a in self.pool)
 
     def __repr__(self):
         return (f"PagedKVCache(slots={self.n_slots}, "
