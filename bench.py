@@ -2013,74 +2013,6 @@ def bench_gpt_tiny_fp8(on_accel):
             }
 
 
-def bench_ragged_decode(on_accel):
-    """ISSUE 17: ragged paged-attention decode A/B — live-length-clamped
-    K/V index map (FLAGS_ragged_decode) vs the dense map that DMAs every
-    table slot. Batch of decode queries whose live lengths are ragged
-    (1..max); the win is DMA elision, so only an accelerator shows it —
-    the CPU row is the interpret-mode parity smoke at a tiny pool."""
-    import math as _math
-
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.paged_attention import paged_attention_arrays
-
-    rng = np.random.default_rng(0)
-    if on_accel:
-        B, nh, hd, bs, W = 32, 8, 128, 16, 64
-        dtype = jnp.bfloat16
-        iters = 50
-    else:
-        B, nh, hd, bs, W = 4, 8, 128, 8, 4
-        dtype = jnp.float32
-        iters = 5
-    n_blocks = B * W + 1
-    q = jnp.asarray(rng.standard_normal((B, nh, hd)), dtype)
-    kb = jnp.asarray(rng.standard_normal((n_blocks, nh, bs, hd)), dtype)
-    vb = jnp.asarray(rng.standard_normal((n_blocks, nh, bs, hd)), dtype)
-    tables = jnp.asarray(1 + np.arange(B * W, dtype=np.int32).reshape(B, W))
-    # ragged live lengths: 1..W*bs, mean ~half the pool
-    lengths = jnp.asarray(rng.integers(1, W * bs + 1, (B,)), jnp.int32)
-    scale = 1.0 / _math.sqrt(hd)
-    interp = not on_accel
-
-    def one_leg(ragged):
-        fn = jax.jit(lambda qq: paged_attention_arrays(
-            qq, kb, vb, tables, lengths, scale=scale,
-            interpret=interp, ragged=ragged))
-        jax.block_until_ready(fn(q))
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = fn(q)
-            jax.block_until_ready(out)
-            best = min(best, (time.perf_counter() - t0) / iters)
-        return best, fn(q)
-
-    dense_s, out_d = one_leg(False)
-    ragged_s, out_r = one_leg(True)
-    identical = bool(jnp.array_equal(out_d, out_r))
-    live = int(jnp.sum(lengths))
-    return {"value": round(B / ragged_s, 1), "unit": "decode_tokens/sec",
-            "mfu": None,
-            "vs_baseline": round(dense_s / ragged_s, 4),
-            "dense_ms": round(dense_s * 1e3, 3),
-            "ragged_ms": round(ragged_s * 1e3, 3),
-            "bit_identical": identical,
-            "live_frac": round(live / (B * W * bs), 3),
-            "baseline": "the dense K/V index map (every pool slot "
-                        "DMA'd) — vs_baseline is dense_ms/ragged_ms; "
-                        "expected ~1/live_frac on TPU, ~1.0 under "
-                        "interpret (no DMA cost model)",
-            "note": ("Pallas decode kernel, ragged lengths 1..%d, "
-                     "batch %d" % (W * bs, B) if on_accel else
-                     "cpu: interpret-mode smoke — pins bit-identical "
-                     "outputs; interpret has no DMA cost so the A/B "
-                     "delta only shows on TPU")}
-
-
 def bench_gpt_moe(on_accel):
     """ISSUE 18: FLOPs-matched dense vs MoE A/B on the 8-device mesh.
 
@@ -2607,7 +2539,6 @@ def main():
                      ("gpt_tiny_fused", bench_gpt_tiny_fused),
                      ("flash_s2048", bench_flash_s2048),
                      ("gpt_tiny_fp8", bench_gpt_tiny_fp8),
-                     ("ragged_decode", bench_ragged_decode),
                      ("gpt_moe", bench_gpt_moe),
                      ("overlap_zero2", bench_overlap_zero2),
                      ("gpt_tiny_serving", bench_gpt_tiny_serving),

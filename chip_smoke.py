@@ -9,7 +9,9 @@ and data made from a seed (no network, no files):
                through ``parallel.DistributedTrainStep`` with AdamW.
 - ``serve``:   ``serving.InferenceEngine`` over ``gpt_1p3b`` (hidden 2048,
                16 heads of 128, 24 layers, bf16 weights), paged KV cache,
-               8 slots, mixed greedy and sampled traffic.
+               8 slots, mixed greedy and sampled traffic; and the paged
+               decode step of one chip's share of ``sarvam_105b``,
+               compiled from shapes alone.
 - ``kernels``: every Pallas family in ``paddle_tpu/ops`` compiled by
                Mosaic at a preset's shape and compared with its composed
                reference.
@@ -286,6 +288,35 @@ def pool_slab_moves(hlo, pool_shape):
     return found
 
 
+def latent_decode_program(clock, cfg=None, n_slots=32, n_blocks=6801,
+                          block=64, width=128):
+    """Compiled from shapes alone (no weights are made): the paged decode
+    step of the latent-attention expert model, at one chip's share of
+    ``sarvam_105b``, holds the Mosaic kernel and moves no slab of its
+    latent pool."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import mla_decode_step_paged, mla_init, sarvam_105b
+
+    cfg = cfg or sarvam_105b(n_layers=6, experts_held=32, vocab_size=65536,
+                             seq_len=16384, dtype=jnp.bfloat16,
+                             param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: mla_init(cfg, seed=0))
+    pool = cfg.serving_model().pool_spec(cfg, n_blocks, block)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    with clock.compiling():
+        hlo = jax.jit(functools.partial(mla_decode_step_paged, cfg),
+                      donate_argnums=(1,)).lower(
+            params, pool, i32(n_slots, width), i32(n_slots),
+            i32(n_slots)).compile().as_text()
+    check(MOSAIC in hlo, "serve: the latent decode program holds no Mosaic "
+                         "custom call")
+    moves = pool_slab_moves(hlo, tuple(pool[0].shape))
+    check(not moves, "serve: the latent decode program moves a layer's slab "
+                     f"of the latent pool, or the pool whole: {moves[:4]}")
+
+
 def phase_serve(cfg=None, n_slots=8):
     import jax.numpy as jnp
 
@@ -330,6 +361,7 @@ def phase_serve(cfg=None, n_slots=8):
               f"serve: greedy request {i} (prompt {PROMPT_LENS[i]}) is not "
               "token-identical when repeated")
     check(fallbacks() == fb0, "serve: a kernel entry fell back to jnp")
+    latent_decode_program(clock)
     n_tok = len(PROMPT_LENS) * NEW_TOKENS
     return clock, {"layers": cfg.n_layers, "hidden": cfg.hidden,
                    "heads": cfg.n_heads, "depth_cut": False,
@@ -340,7 +372,8 @@ def phase_serve(cfg=None, n_slots=8):
                    "warm_tokens": n_tok,
                    "greedy_repeat_identical": len(greedy),
                    "decode_logits_rel_err": round(logits_err, 5),
-                   "tol": TOL_LOGITS, "mosaic_in_hlo": True}
+                   "tol": TOL_LOGITS, "mosaic_in_hlo": True,
+                   "latent_decode_program": "compiled, no slab moved"}
 
 
 # --------------------------------------------------------------------------
@@ -386,6 +419,7 @@ def phase_kernels():
     fk = importlib.import_module("paddle_tpu.ops.fused_kernels")
     fo = importlib.import_module("paddle_tpu.ops.fused_optimizer")
     i8 = importlib.import_module("paddle_tpu.ops.int8_matmul")
+    ma = importlib.import_module("paddle_tpu.ops.mla_attention")
     md = importlib.import_module("paddle_tpu.ops.moe_dispatch")
     pa = importlib.import_module("paddle_tpu.ops.paged_attention")
     rf = importlib.import_module("paddle_tpu.parallel.ring_flash")
@@ -455,12 +489,48 @@ def phase_kernels():
             return pa._paged_attention_reference(
                 *up((q, kb, vb)), tables, lengths, q.shape[-1] ** -0.5)
 
-    for tag, ragged, dims in (("dense.h16.d128", False, (8, 16, 128, 64)),
-                              ("ragged.h16.d128", True, (8, 16, 128, 64)),
-                              ("ragged.h12.d64", True, (8, 12, 64, 32))):
-        run("paged_attention." + tag,
-            fn=functools.partial(pa.paged_attention_arrays, ragged=ragged),
+    for tag, dims in (("h16.d128", (8, 16, 128, 64)),
+                      ("h12.d64", (8, 12, 64, 32))):
+        run("paged_attention." + tag, fn=pa.paged_attention_arrays,
             ref_fn=paged_ref, args=paged_case(*dims), tol=TOL_BF16)
+
+    # the serve cells' shapes: 32 slots, most of them holding no request
+    # (length 0: no grid step, a row of zeros) between live ones of
+    # mixed lengths, tables 128 wide, a layer of the whole pool
+    def live_case(bs, W, n_blocks):
+        lengths = np.zeros(32, np.int32)
+        lengths[[2, 3, 11, 20, 30]] = (1, 20 * bs, W * bs, 93 * bs + 7,
+                                       16 * bs)
+        tables = rng.integers(1, n_blocks, (32, W)).astype(np.int32)
+        live = np.arange(W)[None, :] * bs < lengths[:, None]
+        return np.where(live, tables, 0).astype(np.int32), lengths
+
+    def live_rows(out, lengths):
+        return jnp.where((lengths > 0)[:, None, None], out, 0)
+
+    tables, lengths = live_case(BLOCK, 128, 1025)
+    run("paged_attention.cells.dead_lanes",
+        fn=lambda q, kb, vb, t, n, li: pa.paged_attention_arrays(
+            q, kb, vb, t, n, layer=li),
+        ref_fn=lambda q, kb, vb, t, n, li: live_rows(
+            paged_ref(q, kb[:, 1], vb[:, 1], t, n), n),
+        args=(normal((32, 16, 128)), normal((1025, 2, 16, BLOCK, 128)),
+              normal((1025, 2, 16, BLOCK, 128)), tables, lengths,
+              jnp.int32(1)), tol=TOL_BF16)
+
+    def mla_ref(ql, qr, pool, t, n, li):
+        with jax.default_matmul_precision("highest"):
+            return live_rows(ma._mla_decode_reference(
+                *up((ql, qr, pool)), t, n, 0.07, li), n)
+
+    tables, lengths = live_case(64, 128, 513)
+    run("mla_latent_decode.cells.dead_lanes",
+        fn=lambda ql, qr, pool, t, n, li: ma.mla_decode_arrays(
+            ql, qr, pool, t, n, 0.07, li),
+        ref_fn=mla_ref,
+        args=(normal((32, 64, 512)), normal((32, 64, 64)),
+              normal((513, 2, 64, 640)), tables, lengths, jnp.int32(1)),
+        tol=TOL_BF16)
 
     # -- fused LN+MLP and add+LN (bert_base block shapes) -------------------
     H, M = 768, 3072

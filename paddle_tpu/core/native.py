@@ -278,17 +278,6 @@ autotune_watchers: list = []
 fp8_matmul = [_truthy(os.environ.get("FLAGS_fp8_matmul", "0"))]
 fp8_matmul_watchers: list = []
 
-# FLAGS_ragged_decode (ISSUE 17): ragged paged-attention decode — the
-# paged kernel's K/V index map clamps dead table iterations (past the
-# slot's live length) to the last live block, so consecutive grid steps
-# re-reference the same block and the DMA is elided; decode cost tracks
-# live tokens instead of padded table width. Compute is already guarded
-# per-iteration, so ON is bit-identical to OFF by construction; default
-# OFF keeps the PR-7 index map verbatim. Mirrored via watchers
-# (ragged_decode_watchers) — the decode wrapper is jit-reachable.
-ragged_decode = [_truthy(os.environ.get("FLAGS_ragged_decode", "0"))]
-ragged_decode_watchers: list = []
-
 # FLAGS_overlap_zero2 (ISSUE 17): extend FLAGS_overlap_grads' in-backward
 # gradient collective from pmean to the ZeRO-2 reduce-scatter — sharded
 # grad buckets issue psum_scatter INSIDE the backward so the scatter of
@@ -343,10 +332,6 @@ def set_flag(name: str, value) -> None:
         fp8_matmul[0] = _truthy(value)
         for watcher in fp8_matmul_watchers:
             watcher(fp8_matmul[0])
-    elif name.endswith("ragged_decode"):
-        ragged_decode[0] = _truthy(value)
-        for watcher in ragged_decode_watchers:
-            watcher(ragged_decode[0])
     elif name.endswith("overlap_zero2"):
         overlap_zero2[0] = _truthy(value)
     if _lib is not None:

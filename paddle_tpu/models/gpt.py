@@ -1017,9 +1017,12 @@ def _pool_gather(kb, vb, li, tables):
 
 
 @jax.named_scope("attn")
-def _dec_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions):
+def _dec_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions,
+                    lengths, walk):
     """Attention half of the paged one-token block step at layer ``li``
-    (pool write + paged attention + proj residual). Returns (x, kb, vb)."""
+    (pool write + paged attention + proj residual); ``lengths`` (B,) are
+    the tokens each slot attends over, ``walk`` the tick's
+    ``ops.paged_attention.decode_walk`` of them. Returns (x, kb, vb)."""
     B = x.shape[0]
     nh, hd = cfg.n_heads, cfg.head_dim
     bs = kb.shape[3]
@@ -1035,22 +1038,27 @@ def _dec_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions):
     kb, vb = _pool_write_rows(kb, vb, li, blk, positions % bs, k, v)
 
     from ..ops.paged_attention import paged_attention_arrays
-    o = paged_attention_arrays(q, kb, vb, tables, positions + 1,
-                               scale=1.0 / math.sqrt(hd), layer=li)
+    o = paged_attention_arrays(q, kb, vb, tables, lengths,
+                               scale=1.0 / math.sqrt(hd), layer=li,
+                               walk=walk)
     o = o.reshape(B, 1, nh * hd)
 
     x = x + _dec_mm(o, p["proj_w"], cd) + p["proj_b"].astype(cd)
     return x, kb, vb
 
 
-def _block_decode_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions):
+def _block_decode_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions,
+                        lengths, walk):
     """One-token block step at layer ``li`` of the block pool.
 
     x (B, 1, H); kb/vb the whole pool (n_blocks, L, nh, block_size, hd);
     tables (B, W) int32; positions (B,) int32 — where each slot's
-    incoming token lands. Attention routes through ops.paged_attention
-    (Pallas kernel on TPU, identical composed gather elsewhere)."""
-    x, kb, vb = _dec_attn_paged(cfg, p, x, kb, vb, li, tables, positions)
+    incoming token lands; ``lengths`` (B,) the tokens it attends over
+    and ``walk`` the tick's list of live blocks.
+    Attention routes through ops.paged_attention (Pallas kernel on TPU,
+    identical composed gather elsewhere)."""
+    x, kb, vb = _dec_attn_paged(cfg, p, x, kb, vb, li, tables, positions,
+                                lengths, walk)
     return _dec_mlp(cfg, p, x), kb, vb
 
 
@@ -1060,15 +1068,25 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
 
     pool = (kb, vb), each (n_blocks, L, nh, block_size, hd); tables
     (B, W) int32 per-slot block tables (padding/stale rows point at
-    reserved block 0); positions/tokens (B,) int32. Returns
+    reserved block 0: a row that starts with it holds no request, costs
+    the attention kernel nothing and yields logits nobody reads);
+    positions/tokens (B,) int32. Returns
     (logits (B, V) fp32, new pool) with the new tokens' K/V written at
     block ``tables[b, positions[b] // block_size]``, offset
     ``positions[b] % block_size``. Numerics match gpt_decode_step over
     the same live positions; MoE configs return the same third
     ``(counts, dropped)`` element gpt_decode_step does."""
+    from ..ops.paged_attention import decode_walk
+
     kb, vb = pool
     cd = cfg.dtype
     L = kb.shape[1]
+    # a lane whose table row is the sink (block 0) holds no request:
+    # length 0, which costs the kernel no step and reads as zeros. The
+    # kernel's list of live blocks is the same at every layer, so it is
+    # built here, once a tick, and not inside the layer loop
+    lengths = jnp.where(tables[:, 0] > 0, positions + 1, 0)
+    walk = decode_walk(lengths, tables.shape[1], kb.shape[3])
     with jax.named_scope("embed"):
         x = (params["wte"].astype(cd)[tokens]
              + params["wpe"].astype(cd)[positions])[:, None, :]  # (B, 1, H)
@@ -1082,7 +1100,7 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
         for i in range(cfg.n_layers):
             pa = _layer_params(blocks, i, _ATTN_KEYS)
             x, kb, vb = _dec_attn_paged(cfg, pa, x, kb, vb, i, tables,
-                                        positions)
+                                        positions, lengths, walk)
             if i in moe_ids:
                 pm = _layer_params(params["moe"], mi, _MOE_KEYS)
                 mi += 1
@@ -1098,7 +1116,7 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
         x, kb, vb = carry
         layer_p, li = inp
         x, kb, vb = _block_decode_paged(cfg, layer_p, x, kb, vb, li,
-                                        tables, positions)
+                                        tables, positions, lengths, walk)
         return (x, kb, vb), None
 
     (x, kb, vb), _ = jax.lax.scan(

@@ -53,7 +53,8 @@ import numpy as np
 
 from ..nn.moe import moe_ffn_held, moe_route_sigmoid
 from ..ops.flash_attention import NEG_INF
-from ..ops.mla_attention import gather_rows, mla_decode_arrays
+from ..ops.mla_attention import (decode_walk, gather_rows,
+                                 mla_decode_arrays)
 from .serving_api import ServingModel
 
 __all__ = ["MLAConfig", "sarvam_105b", "mla_tiny", "mla_init",
@@ -546,6 +547,10 @@ def mla_decode_step_paged(cfg: MLAConfig, params, pool, tables, positions,
     blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
     off = positions % bs
     live = tables[:, 0] > 0
+    # such a lane has length 0: no step of the kernel, and zeros. The
+    # kernel's list of live blocks is the same at every layer
+    lengths = jnp.where(live, positions + 1, 0)
+    walk = decode_walk(lengths, tables.shape[1], bs)
 
     @jax.named_scope("attn")
     def attn(p, x, lat, li):
@@ -557,8 +562,8 @@ def mla_decode_step_paged(cfg: MLAConfig, params, pool, tables, positions,
                 lat = _pool_put(lat, row[n], (blk[n], li, off[n], 0))
         w = _wkvb(cfg, p)
         q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w[..., :dn])
-        o_lat = mla_decode_arrays(q_lat, q_rope, lat, tables, positions + 1,
-                                  cfg.softmax_scale, li)
+        o_lat = mla_decode_arrays(q_lat, q_rope, lat, tables, lengths,
+                                  cfg.softmax_scale, li, walk=walk)
         o = jnp.einsum("bhr,rhd->bhd", o_lat, w[..., dn:]).reshape(B, -1)
         return x + _into_residual(o, p["wo"]), lat
 

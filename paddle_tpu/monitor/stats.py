@@ -296,6 +296,8 @@ DEFAULT_STATS = (
     "serving_tokens_per_s",    # gauge: recent generation rate (tokens/s)
     "serving_evictions",       # sequences evicted from slots (eos/len/deadline/cancel)
     "serving_prefill_chunks",  # prefill work quanta dispatched (paged: chunks)
+    "serving_decode_blocks_live",    # active slots' table entries, paged ticks
+    "serving_decode_blocks_tabled",  # n_slots x table width, the same ticks
     # paged KV cache (ISSUE 7)
     "kv_blocks_free",          # gauge: pool blocks on the free list
     "kv_blocks_used",          # gauge: pool blocks owned by live slots
@@ -419,6 +421,9 @@ SERVING_DECODE_MS = _registry.get_stat("serving_decode_ms")
 SERVING_TOKENS_PER_S = _registry.get_stat("serving_tokens_per_s")
 SERVING_EVICTIONS = _registry.get_stat("serving_evictions")
 SERVING_PREFILL_CHUNKS = _registry.get_stat("serving_prefill_chunks")
+SERVING_DECODE_BLOCKS_LIVE = _registry.get_stat("serving_decode_blocks_live")
+SERVING_DECODE_BLOCKS_TABLED = _registry.get_stat(
+    "serving_decode_blocks_tabled")
 KV_BLOCKS_FREE = _registry.get_stat("kv_blocks_free")
 KV_BLOCKS_USED = _registry.get_stat("kv_blocks_used")
 KV_FRAGMENTATION = _registry.get_stat("kv_fragmentation")

@@ -17,27 +17,27 @@ layer — this module provides it:
   interpret-mode parity tests (tests/test_paged_attention.py,
   ``-m kernels``).
 
-Kernel design (mirrors the flash forward):
-- grid ``(batch, max_blocks_per_slot)``, kv-block innermost so the VMEM
-  scratch (m, l, acc) carries across one slot's block sweep;
-- the block table, per-slot lengths and the layer ride as SCALAR
-  PREFETCH (pltpu.PrefetchScalarGridSpec): the K/V BlockSpec index_map
-  reads ``(tables[b, i], layer)`` to DMA that block of that layer — one
-  contiguous ``(nh, bs, hd)`` run of the pool — directly: no gather and
-  no slab materialization, HBM traffic is exactly the live blocks;
-- blocks past a slot's length are skipped with ``pl.when`` (their table
-  entries point at reserved garbage block 0, so the dead DMA is safe);
-- scores/softmax statistics in f32, accumulator f32, output cast back.
-
-Ragged decode (ISSUE 17, ``FLAGS_ragged_decode``): the compute guard
-skips dead blocks, but the K/V DMAs still sweep the PADDED table width —
-a slot with 1 live block in a W=64 table pays 64 block fetches. With the
-flag on, the K/V index map clamps dead iterations to the slot's LAST
-live block (``tbl[b, min(i, max((len-1)//bs, 0))]``); consecutive grid
-steps that name the same block elide the DMA on TPU, so HBM traffic
-tracks live tokens instead of table width. Output is bit-identical: the
-clamp only changes which block dead (compute-guarded) iterations would
-have fetched, never what is computed.
+Kernel design:
+- the grid is a flat list of LIVE work, ``ops/block_walk.live_steps``:
+  one step for each group of ``G`` live blocks of a slot, no step for a
+  table's padding and none for a slot of length 0. Its length is the
+  list's ``count``, a dynamic grid bound: a tick with two short
+  requests in 32 slots runs a handful of steps a layer, not ``32 x
+  table width``. The list is the same at every layer, so the model
+  builds it once a tick (:func:`decode_walk`) and hands it in;
+- tables, lengths, the layer and the list ride as SCALAR PREFETCH
+  (pltpu.PrefetchScalarGridSpec). The pool is handed to the call ``G``
+  times for keys and ``G`` times for values; input ``j``'s index map
+  reads ``(tables[slot[n], col[n] + j], layer)``, clamped to the slot's
+  last live block (a repeated block index elides the DMA): ``G``
+  contiguous ``(nh, bs, hd)`` runs of the pool a step, read in place
+  (no gather, no slab of a layer), and worked on as ONE ``(nh, G * bs,
+  hd)`` tile with one softmax update;
+- the VMEM scratch (m, l, acc) is reset on a slot's first step and
+  carries across its steps; the output row is written on its last;
+- scores/softmax statistics in f32, accumulator f32, output cast back;
+- a slot of length 0 has no step: its output row is zeros, set outside
+  the kernel.
 """
 from __future__ import annotations
 
@@ -48,17 +48,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from ..core import native as _native
 from . import autotune as _autotune
+from .block_walk import LiveSteps, check_walk, live_steps, step_block
 from .flash_attention import NEG_INF, _on_tpu
 
-__all__ = ["paged_attention_arrays", "gather_blocks"]
+__all__ = ["paged_attention_arrays", "gather_blocks", "decode_walk"]
 
-# Module-local mirror of FLAGS_ragged_decode (no core.native subscript in
-# jit-reachable code); set_flags syncs it through the watcher list.
-_ragged = [bool(_native.ragged_decode[0])]
-_native.ragged_decode_watchers.append(
-    lambda v: _ragged.__setitem__(0, bool(v)))
+# Blocks a grid step, before ``gcd`` with the table width. Chosen on the
+# chip from {4, 8, 16} (PERF.md section 6, PR 31): 16 reads 79-83% of the
+# HBM roofline with every slot full (8: 72-75%, 4: 58-60%) and within
+# 0.06 ms a tick of 8 with one or two slots live.
+BLOCKS_PER_STEP = 16
+
+
+def decode_walk(lengths, width: int, block_size: int) -> LiveSteps:
+    """The kernel's work-list for slots of ``lengths`` (B,) live tokens
+    and tables ``width`` wide. The same for every layer: build it once a
+    tick, outside the layer loop, and pass it as ``walk=``."""
+    return live_steps(lengths, width, block_size,
+                      math.gcd(width, BLOCKS_PER_STEP))
 
 
 def _whole_pool(kb, vb, layer):
@@ -106,54 +114,55 @@ def _paged_attention_reference(q, kb, vb, tables, lengths, scale,
     return jnp.einsum("bhk,bhkd->bhd", w, v.astype(q.dtype))
 
 
-def _decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_s, l_s, acc_s, *, block_size, n_blocks, scale):
+def _decode_kernel(tables_ref, lengths_ref, layer_ref, slot_ref, col_ref,
+                   first_ref, last_ref, q_ref, *refs, block_size, group,
+                   scale):
     from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-    i = pl.program_id(1)
+    k_refs, v_refs = refs[:group], refs[group:2 * group]
+    o_ref, m_s, l_s, acc_s = refs[2 * group:]
+    n = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(first_ref[n] == 1)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    ln = lengths_ref[b]
+    def tile(blocks):
+        # the step's G blocks as ONE (nh, G * bs, hd) tile (a block past
+        # the slot's last live one holds that block again: masked)
+        return blocks[0][...] if group == 1 else jnp.concatenate(
+            [r[...] for r in blocks], axis=1)
 
-    @pl.when(i * block_size < ln)
-    def _compute():
-        # heads ride the leading (untiled) dim and the single query is a
-        # one-row matrix: Mosaic takes a batched matmul only with rank-3
-        # operands, and this way no head count or block size is refused
-        q = q_ref[0]                                   # (nh, 1, hd)
-        k = k_ref[...]                                 # (nh, bs, hd)
-        v = v_ref[...]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale
-        pos = i * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < ln, s, NEG_INF)            # (nh, 1, bs) f32
-        m_prev = m_s[...]                              # (nh, 1, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_s[...] = alpha * l_s[...] + jnp.sum(p, -1, keepdims=True)
-        m_s[...] = m_new
-        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+    # heads ride the leading (untiled) dim and the single query is a
+    # one-row matrix: Mosaic takes a batched matmul only with rank-3
+    # operands, and this way no head count or block size is refused
+    q = q_ref[0]                                       # (nh, 1, hd)
+    k, v = tile(k_refs), tile(v_refs)
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32) * scale
+    pos = col_ref[n] * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 2)
+    s = jnp.where(pos < lengths_ref[slot_ref[n]], s, NEG_INF)
+    m_prev = m_s[...]                                  # (nh, 1, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                             # (nh, 1, G * bs) f32
+    l_s[...] = alpha * l_s[...] + jnp.sum(p, -1, keepdims=True)
+    m_s[...] = m_new
+    acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
 
-    @pl.when(i == n_blocks - 1)
+    @pl.when(last_ref[n] == 1)
     def _finalize():
-        l = l_s[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "ragged"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "group"))
 def _paged_decode(q, kb, vb, tables, lengths, scale, interpret=False,
-                  ragged=False, layer=None):
+                  layer=None, walk=None, group=BLOCKS_PER_STEP):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -162,28 +171,27 @@ def _paged_decode(q, kb, vb, tables, lengths, scale, interpret=False,
     layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     bs = kb.shape[3]
     W = tables.shape[1]
-    if ragged:
-        # Clamp dead sweep iterations to the slot's last LIVE block: the
-        # index map then repeats that block index for every i past the
-        # live range, and repeated consecutive indices elide the DMA —
-        # decode HBM traffic tracks live tokens, not padded table width.
-        # Compute stays guarded by pl.when(i*bs < len), so which block a
-        # dead iteration names never affects the output.
-        def _kv_idx(b, i, tbl, ln, lay):
-            last = jnp.maximum((ln[b] - 1) // bs, 0)
-            return (tbl[b, jnp.minimum(i, last)], lay[0], 0, 0, 0)
-    else:
-        def _kv_idx(b, i, tbl, ln, lay):
-            return (tbl[b, i], lay[0], 0, 0, 0)
-    q_spec = pl.BlockSpec((1, nh, 1, hd),
-                          lambda b, i, tbl, ln, lay: (b, 0, 0, 0))
-    # block and layer are squeezed: the kernel sees the same rank-3
-    # (nh, bs, hd) operand, one contiguous run of the pool, per DMA
-    kv_spec = pl.BlockSpec((None, None, nh, bs, hd), _kv_idx)
+    group = math.gcd(W, group)
+    if walk is None:
+        walk = live_steps(lengths, W, bs, group)
+    check_walk(walk, B, W, group)
+
+    def kv_idx(j):
+        def idx(n, tbl, ln, lay, slot, col, first, last):
+            return (step_block(n, j, tbl, ln, slot, col, bs), lay[0], 0, 0, 0)
+        return idx
+
+    q_spec = pl.BlockSpec(
+        (1, nh, 1, hd),
+        lambda n, tbl, ln, lay, slot, col, first, last: (slot[n], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, W),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        num_scalar_prefetch=7,
+        grid=(walk.count[0],),
+        # block and layer are squeezed: each DMA is one contiguous
+        # (nh, bs, hd) run of the pool
+        in_specs=[q_spec] + 2 * [
+            pl.BlockSpec((None, None, nh, bs, hd), kv_idx(j))
+            for j in range(group)],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((nh, 1, 1), jnp.float32),    # running max
@@ -191,7 +199,7 @@ def _paged_decode(q, kb, vb, tables, lengths, scale, interpret=False,
             pltpu.VMEM((nh, 1, hd), jnp.float32),   # output accumulator
         ],
     )
-    kernel = functools.partial(_decode_kernel, block_size=bs, n_blocks=W,
+    kernel = functools.partial(_decode_kernel, block_size=bs, group=group,
                                scale=scale)
     out = pl.pallas_call(
         kernel,
@@ -201,12 +209,14 @@ def _paged_decode(q, kb, vb, tables, lengths, scale, interpret=False,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="pallas_paged_decode",
-    )(tables, lengths, layer, q.reshape(B, nh, 1, hd), kb, vb)
-    return out.reshape(B, nh, hd)
+    )(tables, lengths, layer, walk.slot, walk.col, walk.first, walk.last,
+      q.reshape(B, nh, 1, hd), *([kb] * group), *([vb] * group))
+    # a slot of length 0 had no step: its row of the output is undefined
+    return jnp.where((lengths > 0)[:, None, None], out.reshape(B, nh, hd), 0)
 
 
 def paged_attention_arrays(q, kb, vb, tables, lengths, scale=None,
-                           interpret=None, ragged=None, layer=None):
+                           interpret=None, layer=None, walk=None):
     """Single-token paged attention over a block pool (routed entry).
 
     q (B, nh, hd) — one query per slot; kb/vb — the WHOLE pool
@@ -217,9 +227,11 @@ def paged_attention_arrays(q, kb, vb, tables, lengths, scale=None,
     past a slot's live blocks must point at a safe block, the engine
     reserves pool block 0); lengths (B,) int32 live tokens.
 
-    ``ragged=None`` follows ``FLAGS_ragged_decode``; True/False forces
-    the live-length-clamped (resp. full-width) K/V sweep. Either way the
-    result is bit-identical — ragged only changes DMA traffic.
+    ``walk`` is :func:`decode_walk` of these lengths and this table
+    width, built once where the call is made at every layer of a model;
+    left out, the kernel builds it. A slot of length 0 costs no grid
+    step and its output row is zeros (the composed path returns the
+    mean of its table's values there; no caller reads such a row).
 
     Off-TPU (unless ``interpret=True`` is forced) this returns the
     identical composed jnp math, so callers never branch. On a TPU every
@@ -228,8 +240,6 @@ def paged_attention_arrays(q, kb, vb, tables, lengths, scale=None,
     hd = q.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
-    if ragged is None:
-        ragged = _ragged[0]
     if interpret is None:
         interpret = False
         if not _on_tpu():
@@ -237,8 +247,7 @@ def paged_attention_arrays(q, kb, vb, tables, lengths, scale=None,
                                               scale, layer=layer)
     return _paged_decode(q, kb, vb, jnp.asarray(tables, jnp.int32),
                          jnp.asarray(lengths, jnp.int32), float(scale),
-                         interpret=bool(interpret), ragged=bool(ragged),
-                         layer=layer)
+                         interpret=bool(interpret), layer=layer, walk=walk)
 
 
 # -- autotune family (ISSUE 17) ---------------------------------------------
@@ -265,8 +274,7 @@ def _paged_bench(shape, dtype, config):
         1 + np.arange(B * W, dtype=np.int32).reshape(B, W))
     lengths = jnp.full((B,), W * bs, jnp.int32)
     out = _paged_decode(q, kb, vb, tables, lengths,
-                        1.0 / math.sqrt(hd), interpret=not _on_tpu(),
-                        ragged=bool(_ragged[0]))
+                        1.0 / math.sqrt(hd), interpret=not _on_tpu())
     jax.block_until_ready(out)
 
 

@@ -175,7 +175,10 @@ a front-end TraceContext, leaves one chain keyed by ``rid``:
 ``serving.queue_wait`` (submit → admit), ``serving.admit_to_first``
 (admit → first token, with its ``chunks``), ``serving.request_done``.
 Span args are built only under ``recording()``; ``serving_prefill_chunks``
-counts prefill work quanta, always. The jitted programs carry
+counts prefill work quanta, always. A paged ``serving.decode_step``
+carries ``decode_blocks_live`` (the active slots' table entries) and
+``decode_blocks_tabled`` (``n_slots`` x the tick's table width), which
+``serving_decode_blocks_live`` / ``_tabled`` sum. The jitted programs carry
 ``jax.named_scope``s (``kv_pool``, ``sampling``, and the model's
 ``embed`` / ``ln`` / ``attn`` / ``mlp`` / ``head``) for xprof; the engine
 registers no ``on_stop`` table: it keeps serving while another thread
@@ -200,6 +203,8 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              MOE_EXPERT_LOAD, MOE_EXPERT_READS,
                              MOE_EXPERT_SHARE_PCT, MOE_TOKENS_DROPPED,
                              PREFIX_COW_COPIES, SERVING_DEADLINE_SHEDS,
+                             SERVING_DECODE_BLOCKS_LIVE,
+                             SERVING_DECODE_BLOCKS_TABLED,
                              SERVING_DECODE_MS, SERVING_DECODE_TICK_MS,
                              SERVING_EVICTIONS, SERVING_FIRST_TOKEN_MS,
                              SERVING_PER_TOKEN_MS, SERVING_PREEMPTIONS,
@@ -2366,9 +2371,15 @@ class InferenceEngine:
                     # worst-case table — one compile per width bucket,
                     # log2(table_width) programs total
                     tables = self.cache.tables_array(active)
-                    tables = tables[:, :self._width_bucket(
-                        max(len(self.cache.block_tables[s])
-                            for s in active))]
+                    held = [len(self.cache.block_tables[s]) for s in active]
+                    tables = tables[:, :self._width_bucket(max(held))]
+                    # how much of the tabled width is live: the decode
+                    # kernel walks the live blocks only
+                    live, tabled = sum(held), tables.size
+                    span_args["decode_blocks_live"] = live
+                    span_args["decode_blocks_tabled"] = tabled
+                    SERVING_DECODE_BLOCKS_LIVE.add(live)
+                    SERVING_DECODE_BLOCKS_TABLED.add(tabled)
                     got = self._decode_paged_jit(
                         self._decode_params, *self.cache.pool, tables,
                         positions, tokens, self._base_key, rids, steps,

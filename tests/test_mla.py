@@ -127,23 +127,59 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(tiny):
 
 
 @pytest.mark.kernels
-@pytest.mark.parametrize("group", [1, 4])
-def test_latent_decode_kernel_matches_its_composed_math(group):
-    """Interpret mode: the Pallas kernel's mathematics (block sweep,
-    online softmax, dead blocks clamped and skipped, a padded row)."""
+@pytest.mark.parametrize("W,group", [(8, 1), (8, 4), (2, 4), (8, 8),
+                                     (32, 16)])
+def test_latent_decode_kernel_matches_its_composed_math(W, group):
+    """Interpret mode: the Pallas kernel's mathematics on the live walk
+    (G blocks a step as one tile, online softmax across a slot's steps,
+    blocks past the last live one clamped and masked, a padded row, a
+    traced layer), with dead slots between live ones and lengths on a
+    block's and a step's edge."""
     rng = np.random.default_rng(2)
+    B, nh, R, Dr, bs, L = 6, 4, 32, 8, 8, 2
+    pool = jnp.asarray(rng.standard_normal((B * W + 1, L, bs, 128)),
+                       jnp.float32)
+    ql = jnp.asarray(rng.standard_normal((B, nh, R)), jnp.float32)
+    qr = jnp.asarray(rng.standard_normal((B, nh, Dr)), jnp.float32)
+    full = W * bs
+    lens = np.asarray([0, 1, min(group * bs, full), 0, max(full - 3, 1), full])
+    live = np.arange(W)[None, :] * bs < lens[:, None]
+    tables = jnp.asarray(np.where(
+        live, 1 + rng.permutation(B * W).reshape(B, W), 0), jnp.int32)
+    lengths = jnp.asarray(lens, jnp.int32)
+    want = mla_attention._mla_decode_reference(ql, qr, pool, tables, lengths,
+                                               0.2, 1)
+    got = jax.jit(lambda li: mla_attention._mla_decode(
+        ql, qr, pool, tables, lengths, li, scale=0.2, interpret=True,
+        group=group))(jnp.int32(1))
+    np.testing.assert_allclose(np.asarray(got)[lens > 0],
+                               np.asarray(want)[lens > 0], atol=2e-5)
+    # a slot with no token costs no step: its row is zeros
+    assert not np.asarray(got)[lens == 0].any()
+
+
+@pytest.mark.kernels
+def test_latent_decode_takes_the_walk_its_model_builds():
+    """``decode_walk`` handed in is the walk built inside; one of another
+    table width is refused."""
+    rng = np.random.default_rng(4)
     B, nh, R, Dr, bs, L, W = 3, 4, 32, 8, 8, 2, 8
     pool = jnp.asarray(rng.standard_normal((B * W + 1, L, bs, 128)),
                        jnp.float32)
     ql = jnp.asarray(rng.standard_normal((B, nh, R)), jnp.float32)
     qr = jnp.asarray(rng.standard_normal((B, nh, Dr)), jnp.float32)
     tables = jnp.asarray(1 + np.arange(B * W).reshape(B, W), jnp.int32)
-    lengths = jnp.asarray([1, 37, W * bs], jnp.int32)
-    want = mla_attention._mla_decode_reference(ql, qr, pool, tables, lengths,
-                                               0.2, 1)
-    got = mla_attention._mla_decode(ql, qr, pool, tables, lengths, 1,
-                                    scale=0.2, interpret=True, group=group)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    lengths = jnp.asarray([0, 37, W * bs], jnp.int32)
+    inside = mla_attention.mla_decode_arrays(ql, qr, pool, tables, lengths,
+                                             0.2, 1, interpret=True)
+    handed = mla_attention.mla_decode_arrays(
+        ql, qr, pool, tables, lengths, 0.2, 1, interpret=True,
+        walk=mla_attention.decode_walk(lengths, W, bs))
+    np.testing.assert_array_equal(np.asarray(inside), np.asarray(handed))
+    with pytest.raises(ValueError, match="decode_walk"):
+        mla_attention.mla_decode_arrays(
+            ql, qr, pool, tables, lengths, 0.2, 1, interpret=True,
+            walk=mla_attention.decode_walk(lengths, 4 * W, bs))
 
 
 # -- the expert layer ----------------------------------------------------------
@@ -425,3 +461,49 @@ def test_the_programs_carry_the_router_and_experts_scopes(tiny):
             assert "forward/" + scope in labels, (scope, labels)
     finally:
         eng.shutdown()
+
+
+@pytest.mark.kernels
+def test_the_decode_step_walks_live_lanes_only_and_builds_the_walk_once(
+        tiny, monkeypatch):
+    """``mla_decode_step_paged`` through the kernel (interpret mode)
+    against the composed path, dead lanes between live ones; the walk's
+    running sum over the lanes stands once in the program, outside the
+    scan over the expert layers."""
+    import functools
+
+    from paddle_tpu.models import mla as mla_model
+
+    cfg, sizes, params = tiny
+    rng = np.random.default_rng(7)
+    cache = PagedKVCache(cfg, n_slots=5, n_blocks=12, block_size=8)
+    assert cache.grow(1, 22) and cache.grow(3, 9)
+    pool = tuple(jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+                 for a in cache.pool)
+    tables = jnp.asarray(cache.tables_array([1, 3])[:, :4])
+    pos = jnp.asarray([0, 21, 0, 8, 0], jnp.int32)
+    toks = jnp.asarray([0, 7, 0, 11, 0], jnp.int32)
+    want = mla_decode_step_paged(cfg, params, pool, tables, pos, toks)
+    monkeypatch.setattr(mla_model, "mla_decode_arrays", functools.partial(
+        mla_attention.mla_decode_arrays, interpret=True))
+    step = functools.partial(mla_decode_step_paged, cfg)
+    got = jax.jit(step)(params, pool, tables, pos, toks)
+    live = np.asarray([1, 3])
+    np.testing.assert_allclose(np.asarray(got[0])[live],
+                               np.asarray(want[0])[live], atol=5e-4)
+    assert np.isfinite(np.asarray(got[0])).all()
+    np.testing.assert_allclose(np.asarray(got[1][0])[1:],
+                               np.asarray(want[1][0])[1:], atol=1e-5)
+
+    def builds(jaxpr, in_scan=False):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "cumsum" \
+                    and eqn.outvars[0].aval.shape == (5,) \
+                    and eqn.outvars[0].aval.dtype == jnp.int32:
+                yield in_scan
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from builds(sub, in_scan
+                                  or eqn.primitive.name == "scan")
+
+    jaxpr = jax.make_jaxpr(step)(params, pool, tables, pos, toks)
+    assert list(builds(jaxpr.jaxpr)) == [False]

@@ -4,6 +4,8 @@ contiguous attention, garbage-sink/zero-length safety, fallback routing,
 and model-level agreement between the paged and contiguous decode steps.
 Registered under the ``-m kernels`` marker with the other Pallas parity
 suites."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -115,64 +117,124 @@ class TestKernelParity:
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-class TestRaggedDecode:
-    """FLAGS_ragged_decode (ISSUE 17): the live-length-clamped K/V index
-    map only changes WHICH blocks are DMA'd (dead iterations re-address
-    the last live block, whose copy the pipeline elides) — the masked
-    compute is untouched, so the output must be bit-identical."""
+def _loop_steps(lengths, W, bs, G):
+    """The work-list by a plain loop: (slot, first column, first?, last?)
+    for every group of G live blocks, slot by slot in table order."""
+    steps = []
+    for b, ln in enumerate(lengths):
+        blocks = min(max(-(-int(ln) // bs), 0), W)
+        for c in range(0, blocks, G):
+            steps.append((b, c, int(c == 0), int(c + G >= blocks)))
+    return steps
 
-    def test_ragged_bit_identical_across_lengths(self):
-        nh, hd, bs, W, nb, B = 8, 64, 16, 4, 20, 4
-        kb, vb = _pool(nb, nh, bs, hd)
+
+class TestLiveWalk:
+    """ops/block_walk.live_steps, the grid of both table-walking decode
+    kernels: live blocks only, G a step."""
+
+    BS = 4
+
+    @staticmethod
+    def _lengths(W, G, bs):
+        """Slots that are empty, hold one token, end on a block's or a
+        step's edge, fall one short of or one past it, and fill the
+        table; dead slots between live ones."""
+        full = W * bs
+        mix = [0, 1, bs, 0, min(G * bs, full), min(G * bs + 1, full),
+               full, max(full - 1, 0), 1, min(2 * G * bs, full), 0]
+        return [mix, [0] * 5, [full] * 3, [0, 0, 3, 0]]
+
+    @pytest.mark.parametrize("G", [1, 4, 8])
+    @pytest.mark.parametrize("W", [1, 2, 8, 32])
+    def test_matches_the_loop(self, W, G):
+        from paddle_tpu.ops.block_walk import live_steps
+
+        bs = self.BS
+        for lengths in self._lengths(W, G, bs):
+            want = _loop_steps(lengths, W, bs, G)
+            walk = jax.jit(live_steps, static_argnums=(1, 2, 3))(
+                jnp.asarray(lengths, jnp.int32), W, bs, G)
+            slot, col, first, last = (np.asarray(a) for a in walk[:4])
+            count = int(walk.count[0])
+            N = len(lengths) * -(-W // G)
+            assert slot.shape == col.shape == first.shape == last.shape \
+                == (N,) and slot.dtype == np.int32
+            assert count == len(want)
+            got = list(zip(slot[:count], col[:count], first[:count],
+                           last[:count]))
+            assert got == want
+            # every live block is named exactly once, in table order,
+            # and no step names a table's padding
+            named = [(b, c + j) for b, c, _, _ in got for j in range(G)
+                     if (c + j) * bs < lengths[b] and c + j < W]
+            assert named == [(b, i) for b, ln in enumerate(lengths)
+                             for i in range(min(-(-ln // bs), W))]
+            # the tail names the last live step again and marks nothing
+            if count:
+                assert (slot[count:] == slot[count - 1]).all()
+                assert (col[count:] == col[count - 1]).all()
+            assert not first[count:].any() and not last[count:].any()
+
+    def test_a_length_past_the_table_is_the_full_table(self):
+        from paddle_tpu.ops.block_walk import live_steps
+
+        walk = live_steps(jnp.asarray([10 ** 6, -3]), 8, 4, 4)
+        assert int(walk.count[0]) == 2
+        assert np.asarray(walk.col)[:2].tolist() == [0, 4]
+
+
+class TestLiveWalkKernel:
+    """The kernel on the live walk (interpret mode) against the composed
+    reference: dead slots between live ones, lengths on every edge, a
+    traced layer, G from 1 to past the table width."""
+
+    @pytest.mark.parametrize("W,G", [(1, 1), (2, 4), (8, 4), (8, 8),
+                                     (32, 8)])
+    def test_matches_reference(self, W, G):
+        L, nh, hd, bs, B = 3, 4, 64, 8, 6
+        nb = B * W + 1
+        kb = jnp.asarray(RNG.normal(size=(nb, L, nh, bs, hd)), jnp.float32)
+        vb = jnp.asarray(RNG.normal(size=(nb, L, nh, bs, hd)), jnp.float32)
         q = jnp.asarray(RNG.normal(size=(B, nh, hd)), jnp.float32)
-        tables = _tables([[5, 2, 9, 14], [1, 7, 3, 11], [4, 8, 6, 13],
-                          [10, 15, 17, 19]], W)
-        # the boundary lengths: 1 token, one-short-of-a-block, exactly
-        # one block, and the full table
-        lengths = jnp.asarray([1, bs - 1, bs, W * bs], jnp.int32)
-        base = _paged_decode(q, kb, vb, tables, lengths, 0.125,
-                             interpret=True, ragged=False)
-        ragged = _paged_decode(q, kb, vb, tables, lengths, 0.125,
-                               interpret=True, ragged=True)
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(ragged))
-        want = _paged_attention_reference(q, kb, vb, tables, lengths,
-                                          0.125)
-        np.testing.assert_allclose(np.asarray(ragged), np.asarray(want),
+        full = W * bs
+        lens = [0, min(G * bs, full), 1, 0, full, max(full - 3, 1)]
+        live = np.arange(W)[None, :] * bs < np.asarray(lens)[:, None]
+        tables = jnp.asarray(np.where(
+            live, 1 + RNG.permutation(B * W).reshape(B, W), 0), jnp.int32)
+        lengths = jnp.asarray(lens, jnp.int32)
+        want = _paged_attention_reference(q, kb[:, 1], vb[:, 1], tables,
+                                          lengths, 0.125)
+        got = jax.jit(lambda li: _paged_decode(
+            q, kb, vb, tables, lengths, 0.125, interpret=True, layer=li,
+            group=G))(jnp.int32(1))
+        rows = np.asarray(lens) > 0
+        np.testing.assert_allclose(np.asarray(got)[rows],
+                                   np.asarray(want)[rows],
                                    rtol=2e-6, atol=2e-6)
+        # a slot with no token costs no step: its row is zeros
+        assert not np.asarray(got)[~rows].any()
 
-    def test_zero_length_ragged_is_finite(self):
-        nh, hd, bs = 8, 64, 16
-        kb, vb = _pool(4, nh, bs, hd)
-        q = jnp.asarray(RNG.normal(size=(2, nh, hd)), jnp.float32)
-        tables = _tables([[], [1, 2]], 2)
-        lengths = jnp.asarray([0, 20], jnp.int32)
-        base = _paged_decode(q, kb, vb, tables, lengths, 0.125,
-                             interpret=True, ragged=False)
-        ragged = _paged_decode(q, kb, vb, tables, lengths, 0.125,
-                               interpret=True, ragged=True)
-        assert np.isfinite(np.asarray(ragged)).all()
-        np.testing.assert_array_equal(np.asarray(base), np.asarray(ragged))
+    def test_a_walk_handed_in_is_the_walk_built_inside(self):
+        """The model builds ``decode_walk`` once a tick and hands it to
+        every layer's call; a walk of another shape is refused."""
+        from paddle_tpu.ops.paged_attention import decode_walk
 
-    def test_flag_routes_and_stays_identical(self):
-        import paddle_tpu as paddle
-        from paddle_tpu.ops import paged_attention as pa
-
-        nh, hd, bs = 8, 64, 16
-        kb, vb = _pool(6, nh, bs, hd)
-        q = jnp.asarray(RNG.normal(size=(1, nh, hd)), jnp.float32)
-        tables = _tables([[1, 4]], 3)
-        lengths = jnp.asarray([19], jnp.int32)
-        off = paged_attention_arrays(q, kb, vb, tables, lengths,
-                                     interpret=True)
-        paddle.set_flags({"FLAGS_ragged_decode": 1})
-        try:
-            assert pa._ragged[0]
-            on = paged_attention_arrays(q, kb, vb, tables, lengths,
+        nh, hd, bs, W, B = 4, 64, 8, 8, 3
+        kb, vb = _pool(B * W + 1, nh, bs, hd)
+        q = jnp.asarray(RNG.normal(size=(B, nh, hd)), jnp.float32)
+        tables = jnp.asarray(1 + np.arange(B * W).reshape(B, W), jnp.int32)
+        lengths = jnp.asarray([0, 37, 64], jnp.int32)
+        inside = paged_attention_arrays(q, kb, vb, tables, lengths,
                                         interpret=True)
-        finally:
-            paddle.set_flags({"FLAGS_ragged_decode": 0})
-        assert not pa._ragged[0]
-        np.testing.assert_array_equal(np.asarray(off), np.asarray(on))
+        handed = paged_attention_arrays(
+            q, kb, vb, tables, lengths, interpret=True,
+            walk=decode_walk(lengths, W, bs))
+        np.testing.assert_array_equal(np.asarray(inside),
+                                      np.asarray(handed))
+        with pytest.raises(ValueError, match="decode_walk"):
+            paged_attention_arrays(q, kb, vb, tables, lengths,
+                                   interpret=True,
+                                   walk=decode_walk(lengths, 4 * W, bs))
 
 
 class TestPagedDecodeStep:
@@ -227,11 +289,10 @@ class TestPoolInPlace:
     back."""
 
     @pytest.mark.parametrize("layer", [0, 2, 4])
-    @pytest.mark.parametrize("ragged", [False, True])
     @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
                                            (jnp.bfloat16, 2e-2)])
     def test_layer_entry_matches_reference_on_the_slice(self, dtype, tol,
-                                                        ragged, layer):
+                                                        layer):
         """The 5-D ``layer=`` entry (interpret mode) against the composed
         reference on that layer's slice, traced layer index as the
         model's scan passes it."""
@@ -245,7 +306,7 @@ class TestPoolInPlace:
                                           tables, lengths, 0.125)
         got = jax.jit(lambda li: paged_attention_arrays(
             q, kb, vb, tables, lengths, scale=0.125, interpret=True,
-            ragged=ragged, layer=li))(jnp.int32(layer))
+            layer=li))(jnp.int32(layer))
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=tol, atol=tol)
@@ -373,3 +434,71 @@ class TestPoolInPlace:
         np.testing.assert_allclose(
             np.asarray(got[1][0][row[1], :, :, 1]),
             np.asarray(want[1][0][0, :, :, S]), rtol=1e-5, atol=1e-5)
+
+
+def walk_builds(jaxpr, n_slots):
+    """(outside, inside) the layer scan: how many equations build a
+    work-list, told by its running sum over an int32 (n_slots,)."""
+    found = [0, 0]
+    for eqn, in_scan in TestPoolInPlace._walk(jaxpr.jaxpr):
+        if eqn.primitive.name == "cumsum" \
+                and eqn.outvars[0].aval.shape == (n_slots,) \
+                and eqn.outvars[0].aval.dtype == jnp.int32:
+            found[in_scan] += 1
+    return tuple(found)
+
+
+class TestWalkInTheModel:
+    """``gpt_decode_step_paged`` builds the live walk once a tick, hands
+    it to the kernel at every layer, and gives a lane with no request
+    length 0."""
+
+    @staticmethod
+    def _tick(moe=False):
+        from paddle_tpu.models import gpt_init, gpt_tiny
+        from paddle_tpu.serving import PagedKVCache
+
+        kw = dict(moe_experts=4, moe_top_k=2, moe_every=2) if moe else {}
+        cfg = gpt_tiny(dtype=jnp.float32, seq_len=64, **kw)
+        params = gpt_init(cfg, seed=5)
+        cache = PagedKVCache(cfg, n_slots=5, block_size=8)
+        # lanes 1 and 3 hold requests of 21 and 8 cached tokens; 0, 2, 4
+        # hold none (table rows of the sink, position 0)
+        assert cache.grow(1, 22) and cache.grow(3, 9)
+        pool = tuple(jnp.asarray(RNG.normal(size=a.shape), a.dtype)
+                     for a in (cache.kb, cache.vb))
+        tables = jnp.asarray(cache.tables_array([1, 3])[:, :4])
+        pos = jnp.asarray([0, 21, 0, 8, 0], jnp.int32)
+        toks = jnp.asarray([0, 7, 0, 11, 0], jnp.int32)
+        return cfg, params, pool, tables, pos, toks
+
+    @pytest.mark.parametrize("moe", [False, True])
+    def test_step_through_the_kernel_matches_the_composed_path(
+            self, monkeypatch, moe):
+        from paddle_tpu.models import gpt_decode_step_paged
+        from paddle_tpu.ops import paged_attention as pa
+
+        cfg, params, pool, tables, pos, toks = self._tick(moe)
+        want = gpt_decode_step_paged(cfg, params, pool, tables, pos, toks)
+        monkeypatch.setattr(pa, "paged_attention_arrays", functools.partial(
+            pa.paged_attention_arrays, interpret=True))
+        got = jax.jit(functools.partial(gpt_decode_step_paged, cfg))(
+            params, pool, tables, pos, toks)
+        live = np.asarray([1, 3])
+        np.testing.assert_allclose(np.asarray(got[0])[live],
+                                   np.asarray(want[0])[live],
+                                   rtol=2e-4, atol=2e-4)
+        assert np.isfinite(np.asarray(got[0])).all()
+        for a, b in zip(got[1], want[1]):
+            # rows of live lanes; dead lanes write the sink block only
+            np.testing.assert_allclose(np.asarray(a)[1:], np.asarray(b)[1:],
+                                       rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("moe", [False, True])
+    def test_the_walk_is_built_once_a_tick(self, moe):
+        from paddle_tpu.models import gpt_decode_step_paged
+
+        cfg, params, pool, tables, pos, toks = self._tick(moe)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            gpt_decode_step_paged, cfg))(params, pool, tables, pos, toks)
+        assert walk_builds(jaxpr, 5) == (1, 0)

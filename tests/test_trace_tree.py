@@ -11,8 +11,9 @@
   CPU-compiled step; a traced ``DistributedTrainStep`` emits the table
   once at ``stop_tracing()``; a failing ``on_stop`` callback never raises;
 - ``span()`` puts a ``jax.profiler.TraceAnnotation`` beside its event;
-- ``serving_prefill_chunks`` is registered and incremented, and graftlint
-  GL005/GL006 stay clean.
+- ``serving_prefill_chunks`` is registered and incremented, a paged decode
+  tick counts its live and its tabled blocks, and graftlint GL005/GL006
+  stay clean.
 """
 import collections
 import importlib.util
@@ -223,6 +224,47 @@ class TestChunkCounter:
         engine(paged=False).submit(
             _prompt(12, 2), max_new_tokens=2).result(timeout=120)
         assert monitor.stat_get("serving_prefill_chunks") - before == 4
+
+    def test_decode_blocks_counted_on_the_span_and_in_the_registry(
+            self, engine):
+        """Each paged decode tick says how many of its tabled blocks are
+        live: the span's two arguments, the two counters by the same
+        amounts, and the serving report's share."""
+        names = ("serving_decode_blocks_live",
+                 "serving_decode_blocks_tabled")
+        assert set(names) <= set(monitor.DEFAULT_STATS)
+        before = [monitor.stat_get(n) for n in names]
+        eng = engine(n_slots=4)
+        events, _, _ = _traced_run(eng, lengths=(40, 9), new=4)
+        ticks = [e["args"] for e in events
+                 if e["name"] == "serving.decode_step"]
+        assert ticks
+        for a in ticks:
+            # block 8: a slot of n tokens holds ceil(n / 8) blocks; the
+            # tabled width is a power of two times all four slots
+            assert 1 <= a["decode_blocks_live"] <= a["batch"] * 6
+            width = a["decode_blocks_tabled"] // 4
+            assert a["decode_blocks_tabled"] == 4 * width
+            assert width & (width - 1) == 0
+            assert a["decode_blocks_live"] <= a["batch"] * width
+        after = [monitor.stat_get(n) for n in names]
+        assert after[0] - before[0] == sum(
+            a["decode_blocks_live"] for a in ticks)
+        assert after[1] - before[1] == sum(
+            a["decode_blocks_tabled"] for a in ticks)
+        spec = importlib.util.spec_from_file_location(
+            "trace_report", os.path.join(_ROOT, "tools", "trace_report.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        with open(os.devnull, "w") as sink:
+            out = mod.serving_report(mod.aggregate(events), file=sink,
+                                     events=events)
+        assert out["decode_blocks_live_share"] == pytest.approx(
+            (after[0] - before[0]) / (after[1] - before[1]))
+        # the fixed-slot engine tables no blocks
+        events, _, _ = _traced_run(engine(paged=False), lengths=(12,))
+        assert all("decode_blocks_live" not in e["args"] for e in events
+                   if e["name"] == "serving.decode_step")
 
     def test_graftlint_gauges_clean(self):
         from paddle_tpu.analysis import run_lint

@@ -377,7 +377,9 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
     slow serving trace: is admission or steady-state decode eating the
     time budget? When raw ``events`` are passed, paged runs also get a
     PREFILL STARVATION verdict — the max consecutive ticks any open
-    stream waited behind chunked prefill work."""
+    stream waited behind chunked prefill work — and the share of the
+    decode ticks' tabled blocks that were live (``decode_blocks_live``
+    over ``decode_blocks_tabled`` of the ``serving.decode_step`` spans)."""
     pre = [r for r in rows if r["name"] == "serving.prefill"]
     chk = [r for r in rows if r["name"] == "serving.prefill_chunk"]
     dec = [r for r in rows if r["name"] == "serving.decode_step"]
@@ -402,6 +404,15 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
             "throughput scales with slot occupancy; raise n_slots or "
             "batch more traffic")
     if events is not None:
+        # paged decode ticks say how much of their tabled width is live
+        # (what the decode kernel's live-block walk has to read)
+        ticks = [e.get("args") or {} for e in events
+                 if e.get("name") == "serving.decode_step"]
+        tabled = sum(int(a.get("decode_blocks_tabled", 0)) for a in ticks)
+        if tabled:
+            live = sum(int(a.get("decode_blocks_live", 0)) for a in ticks)
+            out.update(decode_blocks_live=live, decode_blocks_tabled=tabled,
+                       decode_blocks_live_share=live / tabled)
         starve = _prefill_starvation(events)
         if starve:
             out.update(starve)
