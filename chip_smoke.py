@@ -331,8 +331,8 @@ def phase_serve(cfg=None, n_slots=8):
     logits_err = decode_logits_check(cfg, params, clock)
     gc.collect()
 
-    eng = InferenceEngine(cfg, params, n_slots=n_slots, paged=True,
-                          block_size=BLOCK, prefill_chunk=128, seed=0)
+    eng = InferenceEngine(cfg, params, n_slots=n_slots, block_size=BLOCK,
+                          prefill_chunk=128, seed=0)
     try:
         with clock.compiling():
             # the width bucket the shortest prompt decodes in

@@ -144,13 +144,6 @@ use_shared_memory = [_truthy(os.environ.get("FLAGS_use_shared_memory", "1"))]
 # writeback + per-step host scalar paths.
 fast_step = [_truthy(os.environ.get("FLAGS_fast_step", "1"))]
 
-# Fast-path mirror of FLAGS_serving_jit (ISSUE 4): the serving engine's
-# jit-compiled KV-cache prefill/decode programs. Default ON;
-# `paddle.set_flags({"FLAGS_serving_jit": 0})` drops the engine to an
-# un-jitted full-recompute reference decode (same scheduler, same
-# sampling) — the numerics escape hatch for debugging cache bugs.
-serving_jit = [_truthy(os.environ.get("FLAGS_serving_jit", "1"))]
-
 # Fast-path mirror of FLAGS_fused_optimizer (ISSUE 6 — the reference's
 # operators/fused/ fused Adam/LAMB kernels): flatten the param/moment/grad
 # pytrees into a few contiguous dtype-homogeneous buffers and run the
@@ -179,15 +172,6 @@ fused_kernels = [_truthy(os.environ.get("FLAGS_fused_kernels", "0"))]
 # data/sharding mesh (model/pipe degree 1) and replicated params — other
 # topologies keep the GSPMD path.
 overlap_grads = [_truthy(os.environ.get("FLAGS_overlap_grads", "0"))]
-
-# Fast-path mirror of FLAGS_paged_kv (ISSUE 7): the serving engine's
-# paged KV cache — a block pool (n_blocks, layers, heads, block_size,
-# head_dim) with per-slot block tables instead of one contiguous
-# max_len buffer per slot, chunked prefill interleaved with decode
-# ticks, and the Pallas paged-attention decode kernel
-# (ops/paged_attention.py) on TPU. Default OFF; the PR-4 fixed-slot
-# path is pinned bit-for-bit while unset.
-paged_kv = [_truthy(os.environ.get("FLAGS_paged_kv", "0"))]
 
 # FLAGS_fault_inject (ISSUE 5): deterministic fault-injection spec string
 # (e.g. "nan_grad@step=50:repeat=3,crash@step=120"); empty = no faults.
@@ -254,9 +238,9 @@ serving_mesh = [_int_or_zero(os.environ.get("FLAGS_serving_mesh", "0"))]
 # cached prompt prefixes, splices matched (refcounted, copy-on-write)
 # blocks into the new slot's table and only prefills the uncached tail,
 # so a shared system prompt prefills ONCE and fans out across streams.
-# Requires FLAGS_paged_kv=1 (or InferenceEngine(paged=True)). Default
-# OFF; the cache-cold engine is pinned token-identical while unset, and
-# greedy output with the cache ON is pinned token-identical to cold.
+# Default OFF; the cache-cold engine is pinned token-identical while
+# unset, and greedy output with the cache ON is pinned token-identical
+# to cold.
 prefix_cache = [_truthy(os.environ.get("FLAGS_prefix_cache", "0"))]
 
 
@@ -299,16 +283,12 @@ def set_flag(name: str, value) -> None:
         use_shared_memory[0] = _truthy(value)
     elif name.endswith("fast_step"):
         fast_step[0] = _truthy(value)
-    elif name.endswith("serving_jit"):
-        serving_jit[0] = _truthy(value)
     elif name.endswith("fused_optimizer"):
         fused_optimizer[0] = _truthy(value)
     elif name.endswith("fused_kernels"):
         fused_kernels[0] = _truthy(value)
     elif name.endswith("overlap_grads"):
         overlap_grads[0] = _truthy(value)
-    elif name.endswith("paged_kv"):
-        paged_kv[0] = _truthy(value)
     elif name.endswith("fault_inject"):
         fault_inject[0] = str(value)
         for watcher in fault_inject_watchers:

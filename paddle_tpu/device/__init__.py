@@ -149,8 +149,8 @@ def enable_compile_cache() -> str:
     nothing is touched. Otherwise the cache goes to ``.jax_cache`` beside
     the package (the checkout root; gitignored). The path is part of the
     cache key's surroundings, so it is fixed: never a temp name, a pid or
-    a time. Entry points call this once (chip_smoke.py, bench.py); the
-    package itself sets no cache."""
+    a time. Entry points call this once (chip_smoke.py, benchmarks/run.py);
+    the package itself sets no cache."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

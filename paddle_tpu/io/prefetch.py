@@ -6,8 +6,7 @@ with step N-1's compute. :class:`DevicePrefetcher` wraps any batch
 iterator and keeps ``size`` batches (default 2 — double buffering)
 ``jax.device_put`` ahead of the consumer, so the copy of batch N+1
 overlaps step N: this is the framework-level version of the reference's
-C++ BufferedReader async H2D stage, and of the device loop bench.py used
-to carry privately.
+C++ BufferedReader async H2D stage.
 
 When a parallel mesh is active (parallel.create_mesh) each array leaf is
 placed with the mesh's batch sharding (leading dim over

@@ -325,8 +325,8 @@ class TrainStep:
                 new_buffers = _sentinel.gate(trip, new_buffers, buffers)
             return new_params, new_slots, new_buffers, loss, sent_state
 
-        # pure step exposed for K-steps-in-one-jit timing (bench.py) and
-        # custom outer loops — keeps the historical 5-arg/4-output
+        # pure step exposed for K-steps-in-one-jit timing and custom
+        # outer loops — keeps the historical 5-arg/4-output
         # contract (no sentinel state); _compiled is the per-call dispatch
         # path, _compiled_fast additionally donates the buffer tree
         # (FLAGS_fast_step)

@@ -182,7 +182,8 @@ def gpt_truncate(cfg: GPTConfig, params, n_layers: int):
 
 
 def bert_base_config(**kw):
-    # BERT-base shapes (used by bench.py config 3 as an encoder-sized LM)
+    # BERT-base shapes (the benchmark's `bert_base` configuration: an
+    # encoder-sized LM)
     d = dict(vocab_size=30592, hidden=768, n_layers=12, n_heads=12,
              seq_len=512)
     d.update(kw)
@@ -313,10 +314,10 @@ def _attention(cfg: GPTConfig, q, k, v):
         return ring_flash_attention_sharded(q, k, v, causal=True, scale=scale,
                                       seq_axis=cfg.seq_axis,
                                       batch_axis="data", head_axis="model")
-    # auto: measured on v5e — flash wins at seq >= 1024 always, and at 512
-    # whenever remat is off (278 vs 260 sps BERT-base; the 512 loss only
-    # appears under remat, which recomputes the fused kernel in the
-    # backward); see bench.py flash_ab + tools/exp_bert.py
+    # auto: on the v5e flash won at seq >= 1024 always, and at 512
+    # whenever remat is off (the 512 loss only appears under remat, which
+    # recomputes the fused kernel in the backward). Read before the
+    # benchmark existed; no ledger line re-measures the choice (ROADMAP D4)
     use_flash = (cfg.use_flash if cfg.use_flash is not None
                  else (_on_tpu() and (q.shape[2] >= 1024
                                       or (q.shape[2] >= 512
@@ -1352,8 +1353,8 @@ def gpt_pool_spec(cfg: GPTConfig, n_blocks: int, block_size: int):
 
 _SERVING = ServingModel(
     name="gpt", pool_spec=gpt_pool_spec, param_specs=gpt_param_specs,
-    forward=gpt_forward, prefill_chunk=gpt_prefill_chunk,
-    decode_step_paged=gpt_decode_step_paged, prefill=gpt_prefill,
-    decode_step=gpt_decode_step, verify_step=gpt_verify_step,
+    prefill_chunk=gpt_prefill_chunk,
+    decode_step_paged=gpt_decode_step_paged,
     verify_step_paged=gpt_verify_step_paged,
-    prefill_prefix=gpt_prefill_prefix)
+    prefill_prefix=gpt_prefill_prefix,
+    decode_step=gpt_decode_step, verify_step=gpt_verify_step)

@@ -572,24 +572,15 @@ def mla_decode_step_paged(cfg: MLAConfig, params, pool, tables, positions,
     return _head(cfg, params, x), (lat,), stats
 
 
-def _forward_logits(cfg, params, tokens):
-    return mla_forward(cfg, params, tokens)[0]
-
-
 _CANNOT = ("latent-attention models (MLAConfig) cannot {what} yet: {why}")
 _SERVING = ServingModel(
     name="mla",
     pool_spec=mla_pool_spec,
     param_specs=mla_param_specs,
-    forward=_forward_logits,
     prefill_chunk=mla_prefill_chunk,
     decode_step_paged=mla_decode_step_paged,
     routed=True,
     refuses={
-        "unpaged": _CANNOT.format(
-            what="serve from the fixed-slot KVCache",
-            why="it has no per-head K/V strip, only the paged latent pool "
-                "(pass paged=True)"),
         "draft": _CANNOT.format(
             what="take draft=",
             why="there is no latent verify step for speculative decoding"),

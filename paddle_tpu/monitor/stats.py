@@ -295,8 +295,8 @@ DEFAULT_STATS = (
     "serving_decode_ms",       # cumulative batched decode-tick wall time (ms)
     "serving_tokens_per_s",    # gauge: recent generation rate (tokens/s)
     "serving_evictions",       # sequences evicted from slots (eos/len/deadline/cancel)
-    "serving_prefill_chunks",  # prefill work quanta dispatched (paged: chunks)
-    "serving_decode_blocks_live",    # active slots' table entries, paged ticks
+    "serving_prefill_chunks",  # prefill chunks dispatched
+    "serving_decode_blocks_live",    # active slots' table entries, a tick
     "serving_decode_blocks_tabled",  # n_slots x table width, the same ticks
     # paged KV cache (ISSUE 7)
     "kv_blocks_free",          # gauge: pool blocks on the free list
@@ -508,8 +508,8 @@ FLIGHT_COLLECTS = _registry.get_stat("flight_collects")
 # -- pre-registered latency histograms (ISSUE 15) ---------------------------
 #
 # Recorded AT THE SOURCE (engine scheduler / frontend dispatcher), so the
-# p50/p99 numbers bench.py used to hand-collect are live, scrapeable
-# series under GET /metrics. All share DEFAULT_BUCKETS_MS.
+# p50/p99 numbers are live, scrapeable series under GET /metrics. All
+# share DEFAULT_BUCKETS_MS.
 
 DEFAULT_HISTOGRAMS = (
     ("serving_first_token_ms",
@@ -522,13 +522,12 @@ DEFAULT_HISTOGRAMS = (
      "admission wait (ms)"),
     ("serving_decode_tick_ms",
      "batched decode tick wall latency, dispatch to tokens on the host; "
-     "in paged mode it includes the device time of any prefill chunk "
-     "queued ahead of the tick (ms)"),
+     "it includes the device time of any prefill chunk queued ahead of "
+     "the tick (ms)"),
     ("serving_prefill_chunk_ms",
-     "prefill work quantum host latency: one whole-prompt prefill, "
-     "awaited (fixed), or the asynchronous DISPATCH of one chunk "
-     "(paged: about a millisecond whatever the chunk costs the device, "
-     "which shows in the next tick or first-token wait) (ms)"),
+     "host latency of the asynchronous DISPATCH of one prefill chunk "
+     "(about a millisecond whatever the chunk costs the device, which "
+     "shows in the next tick or first-token wait) (ms)"),
     ("moe_expert_share_pct",
      "per-expert share of routed assignments per decode tick (%) — "
      "one observation per expert per tick, so the spread IS the "

@@ -94,9 +94,9 @@ class MultiHeadAttention(Layer):
 
     def core_attention(self, q, k, v, attn_mask=None):
         # length-based auto-dispatch: the Pallas flash kernel beats XLA's
-        # fused attention on v5e from seq 512 up (bench.py flash_ab: 278
-        # vs 260 sps at 512, 41.4 vs 24.8 at 2048 — measured without
-        # remat, which is the eager-layer case); flash cannot produce the
+        # fused attention on v5e from seq 512 up (read without remat, which
+        # is the eager-layer case, before the benchmark existed: no ledger
+        # line re-measures it); flash cannot produce the
         # weights matrix or apply an arbitrary additive mask, so those
         # paths keep the dense softmax.
         if (attn_mask is None and not self.need_weights and not self.dropout

@@ -10,9 +10,8 @@ of ISSUE 11, speaks HTTP to real multi-tenant traffic.
 
 Layers, bottom up:
 
-- :mod:`kv_cache` — two cache shapes. :class:`KVCache`: fixed-slot
-  donated device buffers ``(slots, layers, heads, max_len, head_dim)``.
-  :class:`PagedKVCache` (``FLAGS_paged_kv=1``): a shared block pool
+- :mod:`kv_cache` — :class:`PagedKVCache`, the cache the engine serves
+  the target model from: a shared block pool
   ``(n_blocks, layers, heads, block_size, head_dim)`` + per-slot block
   tables, host-side free lists and PER-BLOCK REFCOUNTS — slot memory
   proportional to LIVE tokens, admission gated on free blocks,
@@ -21,7 +20,9 @@ Layers, bottom up:
   prefix cache's contract), with ``kv_blocks_free`` / ``kv_blocks_used``
   / ``kv_fragmentation`` gauges and loud ``AssertionError`` on
   refcount/free-list corruption. ``shards=D`` (multi-chip) partitions
-  the pool into per-shard block ranges;
+  the pool into per-shard block ranges. :class:`KVCache`, fixed-slot
+  donated buffers ``(slots, layers, heads, max_len, head_dim)``, is the
+  speculative DRAFT model's private cache and nothing else;
 - :mod:`prefix_cache` — :class:`~prefix_cache.RadixPrefixCache`
   (``FLAGS_prefix_cache=1``): a host-side radix tree keyed by token-id
   block chunks over that pool. Admission walks it, bumps refcounts on
@@ -32,10 +33,12 @@ Layers, bottom up:
   duplicated first, and eviction is LRU-by-leaf over refcount-0 nodes —
   composing with, not replacing, pool-exhaustion preemption. Greedy
   output is pinned token-identical to the cache-cold engine;
-- :func:`paddle_tpu.models.gpt_prefill` / ``gpt_decode_step`` /
-  ``gpt_prefill_chunk`` / ``gpt_prefill_prefix`` /
-  ``gpt_decode_step_paged`` / ``gpt_verify_step`` (+``_paged``) — the
-  cache-aware forward variants (they live with the model);
+- :func:`paddle_tpu.models.gpt_prefill_chunk` /
+  ``gpt_decode_step_paged`` / ``gpt_verify_step_paged`` /
+  ``gpt_prefill_prefix`` (the target's steps over the pool) and
+  ``gpt_decode_step`` / ``gpt_verify_step`` (the draft's, over its
+  fixed cache) — the cache-aware forward variants (they live with the
+  model, reached through ``cfg.serving_model()``);
 - :mod:`sampling` — fused greedy/temperature/top-k/top-p with per-slot
   parameters, per-REQUEST RNG streams, the speculative accept/resample
   rule, and per-row token MASKS (``mask=``) so constrained rows ride
@@ -50,8 +53,8 @@ Layers, bottom up:
   :class:`ByteTokenizer` (byte floor + optional merge vocab file) and
   :class:`StreamDetokenizer` for utf-8-safe live text streaming;
 - :mod:`engine` — the scheduler: bounded queue with backpressure,
-  prefill-and-insert admission (paged: CHUNKED prefill interleaved with
-  decode; prefix-cache splicing; LRU tree reclaim, then youngest-first
+  block-capacity admission, CHUNKED prefill interleaved with decode
+  (prefix-cache splicing; LRU tree reclaim, then youngest-first
   preemption), one batched decode step per tick, speculative decoding
   (``draft=``), multi-chip decode (``mesh=``/``FLAGS_serving_mesh``),
   eviction without draining, deadlines/cancellation, graceful shutdown,
@@ -128,13 +131,10 @@ Layers, bottom up:
   ``tools/trace_report.py frontend_report`` / ``overload_report`` turn
   its spans into per-tenant SLO and brownout/replica verdicts.
 
-Escape hatches: ``paddle.set_flags({"FLAGS_serving_jit": 0})`` swaps the
-jitted cache path for an un-jitted full-recompute reference decode;
-``FLAGS_paged_kv=0`` (default) keeps the fixed-slot cache;
-``FLAGS_prefix_cache=0`` (default) keeps every prefill cache-cold;
-``FLAGS_serving_mesh=0`` + ``draft=None`` (defaults) pin the
+Defaults: ``FLAGS_prefix_cache=0`` keeps every prefill cache-cold;
+``FLAGS_serving_mesh=0`` + ``draft=None`` pin the
 single-chip non-speculative engine; ``overload=None`` + no router
-(defaults) pin the PR-11 front end bit-identical.
+pin the PR-11 front end bit-identical.
 """
 from .constrained import (ConstraintCursor, TokenConstraint,
                           compile_constraint, compile_regex,

@@ -1,56 +1,49 @@
-"""Continuous-batching inference engine (Orca-style) over the KV cache.
+"""Continuous-batching inference engine (Orca-style) over the paged KV cache.
 
 Request lifecycle::
 
-    submit() ──► bounded queue ──► [admit: prefill into a free slot]
-                                        │
+    submit() ──► bounded queue ──► [admit: a free slot + blocks for the prompt]
+                                        │   chunked prefill, a chunk a turn
         stream()/result() ◄── tokens ◄──┤  one jitted decode step per tick,
                                         │  batched over ALL occupied slots
                   [evict: eos / max_tokens / deadline / cancel / capacity]
 
 A single scheduler thread owns the device state (params, cache buffers,
 jit calls); ``submit`` may be called from any thread and only touches the
-queue. Each tick the scheduler (1) admits waiting requests into free
-slots — prefill-and-insert, one sequence at a time, streaming the first
-token — and (2) runs ONE compiled decode step over the whole slot batch,
-so a late arrival starts generating next tick without draining anyone
-(the reference's AnalysisPredictor has no such path; batching there is
+queue. Each turn the scheduler (1) admits waiting requests into free
+slots, (2) advances every admitted-but-unprefilled slot by at most
+``prefill_chunk`` tokens (``serving.prefill_chunk`` spans), streaming the
+first token when a prompt's last chunk lands, and (3) runs ONE compiled
+decode step over the whole slot batch, so a late arrival starts
+generating next tick without draining anyone and a long prompt delays
+open streams by one chunk's work a turn, not its full length (the
+reference's AnalysisPredictor has no such path; batching there is
 caller-side). Finished sequences release their slot between ticks; the
 batch never stalls on the longest request.
 
-Jit surface: exactly two programs in steady state — a decode step at the
-fixed (n_slots,) batch shape, and a prefill per prompt-length bucket
-(prompts are end-padded to the next power of two, which causality makes
-exact). Cache buffers are donated through both, so serving allocates
-nothing per token. ``FLAGS_serving_jit=0`` swaps in an un-jitted
-full-recompute reference decode (same scheduler, same sampling) as the
-numerics escape hatch.
-
-Paged mode (``FLAGS_paged_kv=1`` or ``InferenceEngine(paged=True)``,
-ISSUE 7) replaces the fixed per-slot buffers with a
+One cache, one set of programs. The target model is served from a
 :class:`~paddle_tpu.serving.kv_cache.PagedKVCache` block pool and
-changes the tick loop in two ways:
+reached through ``cfg.serving_model()`` (models/serving_api.py). Jit
+surface in steady state: a decode step at the fixed ``(n_slots,)`` batch
+shape, one per block-table width bucket (``_decode_paged_fn``), and a
+prefill chunk per block-padded chunk length and table width
+(``_chunk_fn``). The pool's arrays are donated through both, so serving
+allocates nothing per token.
 
-- **chunked prefill**: admission no longer runs the whole prompt in one
-  stalling pass — each tick advances every admitted-but-unprefilled
-  slot by at most ``prefill_chunk`` tokens (``serving.prefill_chunk``
-  spans), THEN runs the batched decode step, so a long prompt delays
-  open streams by one chunk's work per tick instead of its full length;
-- **block-capacity admission**: the ``prompt >= max_len`` hard reject
-  is gone — a prompt up to ``cfg.seq_len - 1`` tokens is admitted
-  whenever enough free blocks exist, and otherwise waits at the head of
-  the queue until evictions free blocks (queue-until-available
-  backpressure). If generation outruns the pool, the youngest slot is
-  preempted back to the queue (``serving_preemptions`` gauge) and later
-  resumes by re-prefilling its prompt + generated prefix — output
-  streams are unaffected.
+Admission is by block capacity: a prompt up to ``cfg.seq_len - 1``
+tokens is admitted whenever enough free blocks exist, and otherwise
+waits at the head of the queue until evictions free blocks
+(queue-until-available backpressure). If generation outruns the pool,
+the youngest slot is preempted back to the queue
+(``serving_preemptions`` gauge) and later resumes by re-prefilling its
+prompt + generated prefix — output streams are unaffected.
 
 Speculative decoding (ISSUE 10, ``InferenceEngine(draft=(draft_cfg,
-draft_params), spec_k=k)``): a small draft model (its OWN fixed-slot KV
-cache, prefilled alongside the target's) proposes k tokens per slot per
-tick, and the target model scores all k+1 positions in ONE batched
-verify pass (:func:`~paddle_tpu.models.gpt_verify_step` /
-``gpt_verify_step_paged``). Acceptance follows the standard
+draft_params), spec_k=k)``): a small draft model (its OWN fixed-slot
+:class:`~paddle_tpu.serving.kv_cache.KVCache`, filled chunk by chunk
+alongside the target's pool) proposes k tokens per slot per tick, and
+the target model scores all k+1 positions in ONE batched verify pass
+(``gpt_verify_step_paged``). Acceptance follows the standard
 rejection-sampling rule (serving.sampling.spec_accept), so
 temperature/top-k/top-p sampling keeps the target distribution exactly
 and greedy output is token-identical to ``draft=None`` — the whole
@@ -59,8 +52,8 @@ to k+1 tokens per stream for one dispatch. Draft contract: same
 vocabulary, gpt_init-layout params (``models.gpt_truncate`` builds a
 layer-truncated draft from the target for free). Rejected positions
 leave stale K/V past the accepted length, which the position masks hide
-until the next step overwrites them; in paged mode the accepted length
-drives the same block accounting as the plain path, with tables grown
+until the next step overwrites them; the accepted length drives the
+same block accounting as the plain path, with tables grown
 (non-preemptively) to k+1 tokens of headroom — when a slot cannot get
 spec headroom the tick falls back to the plain one-token program.
 
@@ -69,15 +62,15 @@ Multi-chip decode (ISSUE 10, ``FLAGS_serving_mesh=D`` or
 axis and weights shard Megatron-style over "model"
 (models.gpt_param_specs transfers directly — the decode step is a pure
 function over the param pytree), so one jitted tick runs over the whole
-mesh with GSPMD deriving the collectives. The fixed cache shards its
-slot dim, the paged pool partitions its blocks into per-shard ranges
-(per-shard free lists + garbage sinks; see PagedKVCache(shards=D)), and
-admission places each request in the shard with the most free blocks.
+mesh with GSPMD deriving the collectives. The pool partitions its
+blocks into per-shard ranges (per-shard free lists + garbage sinks; see
+PagedKVCache(shards=D)), the draft's fixed cache shards its slot dim,
+and admission places each request in the shard with the most free blocks.
 ``FLAGS_serving_mesh=0`` (default) with no explicit mesh keeps the
 single-chip engine unchanged.
 
 Prefix sharing (ISSUE 11, ``FLAGS_prefix_cache=1`` or
-``InferenceEngine(prefix_cache=True)``, paged mode only): admission
+``InferenceEngine(prefix_cache=True)``): admission
 walks a host-side radix tree of cached prompt prefixes
 (serving.prefix_cache.RadixPrefixCache). A hit splices the matched
 (refcounted) pool blocks straight into the new slot's block table and
@@ -134,7 +127,7 @@ window over the last N ticks) / serving_evictions /
 serving_preemptions, kv_blocks_free / kv_blocks_used /
 kv_fragmentation from the block pool, spec_proposed / spec_accepted /
 spec_acceptance_rate from the speculative path and serving_shards for
-the mesh, plus ``serving.prefill`` / ``serving.prefill_chunk`` /
+the mesh, plus ``serving.prefill_chunk`` /
 ``serving.decode_step`` trace spans (decode spans carry
 proposed/accepted and per-shard load args) that ``tools/trace_report.py``
 turns into prefill-vs-decode, prefill-starvation, speculation and
@@ -143,7 +136,7 @@ shard-balance verdicts.
 Observability v2 (ISSUE 15): latency HISTOGRAMS recorded at the source
 (serving_first_token_ms / serving_per_token_ms / serving_queue_wait_ms
 / serving_decode_tick_ms / serving_prefill_chunk_ms — live under the
-front end's Prometheus ``GET /metrics``). In paged mode
+front end's Prometheus ``GET /metrics``).
 serving_prefill_chunk_ms times only the asynchronous DISPATCH of a chunk
 (about a millisecond whatever the chunk costs the device: nothing in the
 span waits for it), and serving_decode_tick_ms runs from the tick's
@@ -152,7 +145,7 @@ chunk queued ahead of the tick; the device's own times are on the
 profiler's trace (the benchmark's ``decode_tick_ms.serve`` /
 ``prefill_chunk_ms.serve``). CAUSAL TRACING — a request
 submitted with ``trace=TraceContext`` stamps every span it touches
-(prefill, each chunk, each decode tick via per-request
+(each prefill chunk, each decode tick via per-request
 ``serving.decode_tick`` events, the ``serving.failover_hop`` of an
 adoption, ``serving.request_done``) with its trace id + flow events,
 so one request renders as one connected chrome-trace timeline across
@@ -175,7 +168,7 @@ a front-end TraceContext, leaves one chain keyed by ``rid``:
 ``serving.queue_wait`` (submit → admit), ``serving.admit_to_first``
 (admit → first token, with its ``chunks``), ``serving.request_done``.
 Span args are built only under ``recording()``; ``serving_prefill_chunks``
-counts prefill work quanta, always. A paged ``serving.decode_step``
+counts prefill chunks, always. ``serving.decode_step``
 carries ``decode_blocks_live`` (the active slots' table entries) and
 ``decode_blocks_tabled`` (``n_slots`` x the tick's table width), which
 ``serving_decode_blocks_live`` / ``_tabled`` sum. The jitted programs carry
@@ -221,7 +214,7 @@ from ..resilience import faults as _faults
 from ..resilience.sentinel import logits_finite
 from ..monitor.flight import arm_flight_recorder, dump_flight
 from ..monitor.trace import emit_complete, emit_flow, recording, span
-from .kv_cache import KVCache, PagedKVCache, cache_insert
+from .kv_cache import KVCache, PagedKVCache
 from .prefix_cache import RadixPrefixCache
 from .sampling import (DRAFT_SALT, sample_tokens, sample_tokens_streams,
                        spec_accept, stream_keys)
@@ -300,8 +293,8 @@ class GenerationRequest:
         self._cancelled = False
         self._t_first = None              # monotonic time of the first token
         self._tokenizer = None            # set by engines with a text front end
-        # paged-mode preemption: (cached-prefix tokens, last token) to
-        # re-prefill from when the request is re-admitted
+        # preemption: (cached-prefix tokens, last token) to re-prefill
+        # from when the request is re-admitted
         self._resume = None
         # EngineRouter failover hook: called (req, err) when the OWNING
         # replica dies; True = a survivor adopted this request and the
@@ -343,7 +336,7 @@ class GenerationRequest:
             return
         if self._t_first is not None and len(self.tokens) >= 2:
             # the steady-state inter-token rate the client saw, stalls
-            # and failover hops included (bench's hand-collected twin)
+            # and failover hops included
             SERVING_PER_TOKEN_MS.observe(
                 (time.monotonic() - self._t_first) * 1e3
                 / (len(self.tokens) - 1))
@@ -459,18 +452,18 @@ class _Slot:
                  "resume_last", "admit_order", "tail_mode", "t_admit",
                  "chunks")
 
-    def __init__(self, req: GenerationRequest, length: int, last_token: int):
+    def __init__(self, req: GenerationRequest):
         self.req = req
-        self.length = length          # tokens whose K/V are in the cache
-        self.last_token = last_token  # input of the next decode step
-        self.generated = 1            # prefill already streamed one token
-        self.pending = None           # paged: prompt tokens not yet prefilled
-        self.resume_last = None       # paged: last token of a preempted run
-        self.admit_order = 0          # paged: preemption picks the youngest
+        self.length = 0               # tokens whose K/V are in the cache
+        self.last_token = -1          # input of the next decode step
+        self.generated = len(req.tokens)  # nonzero on resume
+        self.pending = None           # prompt tokens not yet prefilled
+        self.resume_last = None       # last token of a preempted run
+        self.admit_order = 0          # preemption picks the youngest
         self.tail_mode = False        # prefix hit: chunks continue from an
         #                               unaligned cached length (_tail_jit)
         self.t_admit = time.perf_counter()  # serving.admit_to_first starts
-        self.chunks = 0               # prefill work quanta run so far
+        self.chunks = 0               # prefill chunks run so far
 
 
 class InferenceEngine:
@@ -490,29 +483,27 @@ class InferenceEngine:
     per-channel (models.gpt.quantize_gpt_weights) for the DECODE step —
     the steady-state batched tick runs through the Pallas fused int8
     matmul (ops/int8_matmul.py; dequant in the kernel epilogue, int8 at
-    2x the bf16 MXU rate on v5e). Prefill and the FLAGS_serving_jit=0
-    reference decode keep the fp weights, so admission numerics are
-    unchanged; decode tokens are near-greedy-identical but not pinned
-    bit-for-bit (weight rounding). Default off.
+    2x the bf16 MXU rate on v5e). Prefill chunks keep the fp weights, so
+    admission numerics are unchanged; decode tokens are
+    near-greedy-identical but not pinned bit-for-bit (weight rounding).
+    Default off.
 
-    ``paged`` (None = follow FLAGS_paged_kv) swaps the fixed-slot cache
-    for a PagedKVCache block pool: per-slot memory proportional to live
-    tokens, admission gated on free BLOCKS instead of ``max_len``
-    (``max_len`` is ignored; the per-slot ceiling is ``cfg.seq_len``),
-    prompt prefill chunked at ``prefill_chunk`` tokens per tick and
-    interleaved with decode, and the Pallas paged-attention kernel on
-    TPU. ``block_size`` tokens per pool block; ``n_blocks`` defaults to
-    worst-case (every slot at seq_len) — size it smaller to actually
-    overcommit. Greedy output is token-identical to paged=False.
+    The cache is a PagedKVCache block pool: per-slot memory proportional
+    to live tokens, admission gated on free BLOCKS (the per-slot ceiling
+    is ``cfg.seq_len``, kept as ``eng.max_len``), prompt prefill chunked
+    at ``prefill_chunk`` tokens per tick and interleaved with decode,
+    and the Pallas paged-attention kernel on TPU. ``block_size`` tokens
+    per pool block; ``n_blocks`` defaults to worst-case (every slot at
+    seq_len) — size it smaller to actually overcommit. ``paged`` selects
+    nothing: ``None`` and ``True`` build this engine, ``False`` raises.
 
     ``draft=(draft_cfg, draft_params)`` enables speculative decoding:
     ``spec_k`` proposals per slot per tick from the draft, one target
     verify pass, rejection-sampling acceptance — greedy token-identical
     to ``draft=None``, sampled output keeps the target distribution.
     The draft must share the vocabulary and its positional table must
-    cover the engine's ``max_len``. Requires FLAGS_serving_jit=1 (the
-    reference escape hatch decodes one token at a time and must not be
-    flipped mid-run on an engine holding a draft cache).
+    cover the engine's ``max_len``. It keeps a private fixed-slot
+    KVCache, filled by the same chunks as the target's pool.
 
     ``mesh`` (None = follow FLAGS_serving_mesh) runs the decode over a
     multi-chip mesh: slots shard over "data", weights over "model";
@@ -524,8 +515,8 @@ class InferenceEngine:
     same encode/decode/stream_detokenizer surface) enables the text
     front end: ``submit(text=...)`` and request ``stream_text()``.
 
-    ``prefix_cache`` (None = follow FLAGS_prefix_cache; needs paged
-    mode, not combinable with ``draft``) turns on radix-tree prefix
+    ``prefix_cache`` (None = follow FLAGS_prefix_cache; not combinable
+    with ``draft``) turns on radix-tree prefix
     sharing: prompts that repeat a cached prefix splice its refcounted
     blocks instead of re-prefilling, with copy-on-write on a
     partially-used last block and LRU-by-leaf reclaim ahead of
@@ -543,7 +534,7 @@ class InferenceEngine:
     are rebuilt from scratch. Composes with ``draft=`` (ISSUE 14): the
     speculative verify program computes the same per-slot verdict over
     its k+1 verify positions, and a restart rebuilds the draft's KV
-    cache alongside the target's (the prefill paths re-seed both).
+    cache alongside the target's (the prefill chunks re-seed both).
     Options: ``latency_budget_ms`` (None disables the latency rung)
     with ``latency_trips`` consecutive slow ticks per stall verdict,
     and ``max_restarts`` before the engine fails open requests loudly.
@@ -566,8 +557,7 @@ class InferenceEngine:
     changes when it is armed.
     """
 
-    def __init__(self, cfg, params, n_slots: int = 4,
-                 max_len: Optional[int] = None, queue_size: int = 64,
+    def __init__(self, cfg, params, n_slots: int = 4, queue_size: int = 64,
                  eos_id: Optional[int] = None, seed: int = 0,
                  int8_weights: bool = False, paged: Optional[bool] = None,
                  block_size: int = 16, n_blocks: Optional[int] = None,
@@ -577,6 +567,14 @@ class InferenceEngine:
                  overload=None, replica_id: Optional[int] = None,
                  flight_dir: Optional[str] = None,
                  embedding_tables=None):
+        # ``paged`` is accepted because the serve workload files under
+        # benchmarks/ pass ``"paged": true`` through build_engine(**engine);
+        # the `benchmark` PR that drops that key drops this argument
+        if paged is not None and not paged:
+            raise ValueError(
+                "paged=False: the fixed-slot target path is gone; the "
+                "engine serves from the PagedKVCache only (drop the "
+                "argument)")
         # per-tick NaN/latency sentinel + auto-restart (off by default;
         # when off the engine's compiled programs are bit-identical to a
         # build without it — the health output is gated at trace time)
@@ -598,20 +596,18 @@ class InferenceEngine:
         # reached through its configuration object (models/serving_api.py)
         self._model = cfg.serving_model()
         if hasattr(cfg, "fused_mlp") and cfg.fused_mlp is None:
-            # pin the fused-MLP choice NOW (graftlint GL002): prefill
-            # programs compile lazily per prompt-length bucket, so a
+            # pin the fused-MLP choice NOW (graftlint GL002): chunk
+            # programs compile lazily per padded chunk length, so a
             # FLAGS_fused_kernels flip mid-serving would otherwise split
-            # the engine across fused and unfused programs per bucket
+            # the engine across fused and unfused programs per length
             import dataclasses as _dc
 
             cfg = _dc.replace(cfg, fused_mlp=bool(native.fused_kernels[0]))
         self.cfg = cfg
         self._mesh = self._resolve_mesh(mesh)
-        self.paged = native.paged_kv[0] if paged is None else bool(paged)
         use_prefix = native.prefix_cache[0] if prefix_cache is None \
             else bool(prefix_cache)
-        for option, asked in (("unpaged", not self.paged),
-                              ("draft", draft is not None),
+        for option, asked in (("draft", draft is not None),
                               ("prefix_cache", use_prefix),
                               ("int8_weights", int8_weights),
                               ("mesh", self._mesh is not None)):
@@ -672,42 +668,24 @@ class InferenceEngine:
             self._decode_params = self._params
         # cache construction args, kept for the watchdog's restart path
         # (a restart rebuilds the device cache from scratch)
-        self._cache_args = (max_len, n_blocks, block_size)
-        if self.paged:
-            self.cache = PagedKVCache(cfg, n_slots, n_blocks=n_blocks,
-                                      block_size=block_size,
-                                      shards=self._shards)
-            self.block_size = self.cache.block_size
-            self.max_len = cfg.seq_len   # positional table = per-slot cap
-            if prefill_chunk % self.block_size != 0:
-                raise ValueError(
-                    f"prefill_chunk={prefill_chunk} must be a multiple of "
-                    f"block_size={self.block_size} (chunks must start "
-                    "block-aligned)")
-            self.prefill_chunk = int(prefill_chunk)
-            # the pool's arrays ride as positional arguments 1..n, all
-            # donated: (params, kb, vb, ...) for the per-head pair
-            self._n_pool = len(self.cache.pool)
-            pool_args = tuple(range(1, 1 + self._n_pool))
-            self._decode_paged_jit = jax.jit(self._decode_paged_fn,
-                                             donate_argnums=pool_args)
-            self._chunk_jit = jax.jit(self._chunk_fn,
-                                      donate_argnums=pool_args)
-            if self._mesh is not None:
-                self.cache.kb = self._put_cache(self.cache.kb)
-                self.cache.vb = self._put_cache(self.cache.vb)
-        else:
-            self.cache = KVCache(cfg, n_slots, max_len)
-            self.max_len = self.cache.max_len
-            self.prefill_chunk = None
-            if self._mesh is not None:
-                self.cache.k = self._put_cache(self.cache.k)
-                self.cache.v = self._put_cache(self.cache.v)
+        self._cache_args = (n_slots, n_blocks, block_size)
+        self.cache = self._build_cache()
+        self.block_size = self.cache.block_size
+        self.max_len = cfg.seq_len   # positional table = per-slot cap
+        if prefill_chunk % self.block_size != 0:
+            raise ValueError(
+                f"prefill_chunk={prefill_chunk} must be a multiple of "
+                f"block_size={self.block_size} (chunks must start "
+                "block-aligned)")
+        self.prefill_chunk = int(prefill_chunk)
+        # the pool's arrays ride as positional arguments 1..n, all
+        # donated: (params, kb, vb, ...) for the per-head pair
+        self._n_pool = len(self.cache.pool)
+        pool_args = tuple(range(1, 1 + self._n_pool))
+        self._decode_paged_jit = jax.jit(self._decode_paged_fn,
+                                         donate_argnums=pool_args)
+        self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=pool_args)
         self.n_slots = self.cache.n_slots
-        if use_prefix and not self.paged:
-            raise ValueError("prefix_cache requires the paged KV cache "
-                             "(FLAGS_paged_kv=1 or paged=True) — sharing "
-                             "needs block-table indirection")
         if use_prefix and draft is not None:
             raise ValueError("prefix_cache and draft= are not combinable: "
                              "the draft's fixed cache holds no K/V for a "
@@ -763,8 +741,6 @@ class InferenceEngine:
         # into the engine's lifetime
         self._window: collections.deque = collections.deque(
             maxlen=max(2, int(tps_window_ticks)))  # (t, n_tokens)
-        self._decode_jit = jax.jit(self._decode_fn, donate_argnums=(1, 2))
-        self._prefill_jit = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
         SERVING_SHARDS.set(self._shards)
         # overload-hardening surface (ISSUE 13): the brownout controller
         # (None = every schedule decision bit-identical to a build
@@ -853,10 +829,9 @@ class InferenceEngine:
                 f"draft vocab {draft_cfg.vocab_size} != target vocab "
                 f"{self.cfg.vocab_size} (the acceptance rule compares "
                 "distributions over one vocabulary)")
-        # paged chunks are block-padded, so the draft cache (and its
+        # chunks are block-padded, so the draft cache (and its
         # positional table) must cover max_len rounded up to a block
-        draft_len = self.max_len if not self.paged else \
-            -(-self.max_len // self.block_size) * self.block_size
+        draft_len = -(-self.max_len // self.block_size) * self.block_size
         if draft_cfg.seq_len < draft_len:
             raise ValueError(
                 f"draft seq_len {draft_cfg.seq_len} < engine cache span "
@@ -879,16 +854,21 @@ class InferenceEngine:
         self.draft = (draft_cfg, self._draft_params)
         self.spec_k = int(spec_k)
         self._draft_len = draft_len
-        self._prefill_spec_jit = jax.jit(self._prefill_spec_fn,
-                                         donate_argnums=(2, 3, 4, 5))
-        if self.paged:
-            self._spec_paged_jit = jax.jit(self._spec_paged_fn,
-                                           donate_argnums=(2, 3, 4, 5))
-            self._chunk_spec_jit = jax.jit(self._chunk_spec_fn,
-                                           donate_argnums=(2, 3, 4, 5))
-        else:
-            self._spec_jit = jax.jit(self._spec_fn,
-                                     donate_argnums=(2, 3, 4, 5))
+        self._spec_paged_jit = jax.jit(self._spec_paged_fn,
+                                       donate_argnums=(2, 3, 4, 5))
+        self._chunk_spec_jit = jax.jit(self._chunk_spec_fn,
+                                       donate_argnums=(2, 3, 4, 5))
+
+    def _build_cache(self):
+        """Fresh zeroed block pool + accounting (construction and the
+        watchdog restart both route here)."""
+        n_slots, n_blocks, block_size = self._cache_args
+        cache = PagedKVCache(self.cfg, n_slots, n_blocks=n_blocks,
+                             block_size=block_size, shards=self._shards)
+        if self._mesh is not None:
+            cache.kb = self._put_cache(cache.kb)
+            cache.vb = self._put_cache(cache.vb)
+        return cache
 
     def _build_draft_cache(self):
         """Fresh zeroed draft KV cache (construction and the watchdog
@@ -907,54 +887,6 @@ class InferenceEngine:
             keys = stream_keys(base_key, rids, steps)
             return sample_tokens_streams(logits, keys, temps, top_ks,
                                          top_ps, mask=mask)
-
-    def _decode_fn(self, params, k, v, positions, tokens, base_key, rids,
-                   steps, temps, top_ks, top_ps, mask):
-        got = self._model.decode_step(self.cfg, params, (k, v), positions,
-                                      tokens)
-        logits, (k, v) = got[0], got[1]
-        toks = self._sample_args(logits, base_key, rids, steps, temps,
-                                 top_ks, top_ps, mask)
-        out = (toks,)
-        if self._watchdog is not None:
-            # per-slot finite verdict — gated at TRACE time, so a
-            # watchdog-off engine compiles the exact historical program
-            out = out + (logits_finite(logits),)
-        out = out + (k, v)
-        if self._moe:
-            # (counts (E,), dropped) router stats — always LAST so the
-            # tick's unpack can peel them off uniformly
-            out = out + (got[2],)
-        return out
-
-    def _prefill_fn(self, params, k, v, tokens, slot, true_len, key, temp,
-                    top_k, top_p, mask):
-        # tokens (1, S_pad) end-padded; causality keeps positions < true_len
-        # exact, and the logits/cache rows past true_len are never read
-        logits, (ke, ve) = self._model.prefill(self.cfg, params, tokens)
-        k, v = cache_insert(k, v, slot, ke[0], ve[0])
-        with jax.named_scope("sampling"):
-            last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
-                                                keepdims=False)
-            tok = sample_tokens(last[None], key, temp[None], top_k[None],
-                                top_p[None], mask=mask)[0]
-        return tok, k, v
-
-    def _prefill_spec_fn(self, params, dparams, k, v, dk, dv, tokens, slot,
-                         true_len, key, temp, top_k, top_p, mask):
-        # target prefill + draft prefill in ONE program: both caches seed
-        # the same slot so the first speculative tick can draft at once
-        logits, (ke, ve) = self._model.prefill(self.cfg, params, tokens)
-        k, v = cache_insert(k, v, slot, ke[0], ve[0])
-        _, (dke, dve) = self._draft_model.prefill(self.draft_cfg, dparams,
-                                                  tokens)
-        dk, dv = cache_insert(dk, dv, slot, dke[0], dve[0])
-        with jax.named_scope("sampling"):
-            last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
-                                                keepdims=False)
-            tok = sample_tokens(last[None], key, temp[None], top_k[None],
-                                top_p[None], mask=mask)[0]
-        return tok, k, v, dk, dv
 
     def _decode_paged_fn(self, params, *args):
         # args: the pool's arrays, then (tables, positions, tokens,
@@ -1039,27 +971,6 @@ class InferenceEngine:
         return (jnp.stack(d_toks, axis=1), jnp.stack(d_logits, axis=1),
                 dk, dv)
 
-    def _spec_fn(self, params, dparams, k, v, dk, dv, positions, tokens,
-                 base_key, rids, steps, temps, top_ks, top_ps):
-        d_toks, d_logits, dk, dv = self._draft_propose(
-            dparams, dk, dv, positions, tokens, base_key, rids, steps,
-            temps, top_ks, top_ps)
-        vtokens = jnp.concatenate([tokens[:, None], d_toks], axis=1)
-        t_logits, (k, v) = self._model.verify_step(self.cfg, params, (k, v),
-                                           positions, vtokens)
-        with jax.named_scope("sampling"):
-            keys = stream_keys(base_key, rids, steps)
-            out, n_emit = spec_accept(t_logits, d_logits, d_toks, keys,
-                                      temps, top_ks, top_ps)
-        if self._watchdog is not None:
-            # per-slot finite verdict over ALL k+1 verify positions —
-            # trace-time gated like the plain tick, so watchdog=off spec
-            # programs compile bit-identical to a watchdog-free build
-            health = logits_finite(
-                jnp.reshape(t_logits, (t_logits.shape[0], -1)))
-            return out, n_emit, health, k, v, dk, dv
-        return out, n_emit, k, v, dk, dv
-
     def _spec_paged_fn(self, params, dparams, kb, vb, dk, dv, tables,
                        positions, tokens, base_key, rids, steps, temps,
                        top_ks, top_ps):
@@ -1125,14 +1036,11 @@ class InferenceEngine:
         if prompt.size < 1:
             raise ValueError("prompt must hold at least one token")
         if prompt.size >= self.max_len:
-            # paged mode lifts this to the positional table (cfg.seq_len):
             # block capacity is checked at admission, not here
             raise ValueError(
                 f"prompt length {prompt.size} leaves no room to generate "
-                + (f"(positional table seq_len={self.max_len})" if self.paged
-                   else f"(cache max_len={self.max_len})"))
-        if self.paged and \
-                self.cache.blocks_for(prompt.size + 1) > \
+                f"(positional table seq_len={self.max_len})")
+        if self.cache.blocks_for(prompt.size + 1) > \
                 self.cache.max_slot_blocks:
             raise ValueError(
                 f"prompt length {prompt.size} can never fit one shard of "
@@ -1223,8 +1131,8 @@ class InferenceEngine:
     # -- replica lifecycle (serving/lifecycle.py, ISSUE 14) ------------------
     def warm_prefix(self, prompt) -> GenerationRequest:
         """Queue a prefill-only background request — the radix re-warm
-        primitive. The prompt is prefilled (and, in paged+prefix mode,
-        inserted into the radix tree) and exactly one token is generated
+        primitive. The prompt is prefilled (and, with the prefix cache
+        on, inserted into the radix tree) and exactly one token is generated
         and discarded by the caller. The request id comes from a
         DEDICATED space above ``2**30``, so warm replay neither collides
         with nor shifts the numbering of live request ids — a rejoined
@@ -1269,23 +1177,20 @@ class InferenceEngine:
         """Lower the batched one-token decode program over this engine's
         own weights and cache — assert-on-HLO testing, the serving twin
         of ``DistributedTrainStep.lower``. ``table_width`` picks the
-        paged program's block-table width bucket (default: the widest);
-        the fixed-slot program has one shape. Nothing runs and nothing is
-        donated; ``.compile().as_text()`` is the HLO the tick executes."""
+        program's block-table width bucket (default: the widest). Nothing
+        runs and nothing is donated; ``.compile().as_text()`` is the HLO
+        the tick executes."""
         n = self.n_slots
         i32 = np.zeros(n, np.int32)
         tail = (i32, i32, self._base_key, i32, i32, np.zeros(n, np.float32),
                 i32, np.ones(n, np.float32), self._mask_dev)
 
         def lower(eng):
-            if eng.paged:
-                width = eng.cache.table_width if table_width is None \
-                    else eng._width_bucket(int(table_width))
-                return eng._decode_paged_jit.lower(
-                    eng._decode_params, *eng.cache.pool,
-                    np.zeros((n, width), np.int32), *tail)
-            return eng._decode_jit.lower(
-                eng._decode_params, eng.cache.k, eng.cache.v, *tail)
+            width = eng.cache.table_width if table_width is None \
+                else eng._width_bucket(int(table_width))
+            return eng._decode_paged_jit.lower(
+                eng._decode_params, *eng.cache.pool,
+                np.zeros((n, width), np.int32), *tail)
 
         # on the scheduler thread: between ticks no donated buffer is
         # mid-flight
@@ -1564,12 +1469,10 @@ class InferenceEngine:
             return time.monotonic() - self._last_tick_t
 
     def pool_headroom(self) -> float:
-        """Free fraction of the KV capacity (blocks when paged, slots
-        otherwise) — the /readyz admission-headroom signal."""
-        if self.paged:
-            total = self.cache.n_blocks - self.cache.shards
-            return self.cache.free_blocks_count / max(1, total)
-        return self.cache.free_count / max(1, self.n_slots)
+        """Free fraction of the pool's blocks — the /readyz
+        admission-headroom signal."""
+        total = self.cache.n_blocks - self.cache.shards
+        return self.cache.free_blocks_count / max(1, total)
 
     def generate(self, prompt: Sequence[int] = None, **kw) -> List[int]:
         """Blocking convenience wrapper: submit + result."""
@@ -1672,8 +1575,7 @@ class InferenceEngine:
                     with span("serving.admit", cat="serving",
                               args=self._tick_args()):
                         self._admit()
-                    if self.paged and native.serving_jit[0]:
-                        self._prefill_chunk_tick()
+                    self._prefill_chunk_tick()
                     if any(s is not None for s in self._slots):
                         self._decode_tick()
         except BaseException as e:  # noqa: BLE001 — fail every request, not silently
@@ -1782,29 +1684,23 @@ class InferenceEngine:
                 req._finish(DEADLINE)
 
     def _admit(self) -> None:
-        """Move queued requests into free slots. Fixed mode: prefill-and-
-        insert on the spot. Paged mode: capacity-check the head of the
-        queue against the free-block pool of a shard that also has a
-        free slot (queue-until-available — a too-long prompt waits for
-        evictions instead of being rejected; multi-chip admission lands
-        in the shard with the most free blocks), then park the prompt on
-        the slot for the chunked-prefill tick."""
+        """Move queued requests into free slots: capacity-check the head
+        of the queue against the free-block pool of a shard that also
+        has a free slot (queue-until-available — a too-long prompt waits
+        for evictions instead of being rejected; multi-chip admission
+        lands in the shard with the most free blocks), then park the
+        prompt on the slot for the chunked-prefill tick."""
         self._shed_expired()
-        paged = self.paged and native.serving_jit[0]
         while self.cache.free_count > 0:
-            shard = None
-            place = None
             with self._cv:
                 if not self._queue:
                     break
-                if paged:
-                    head = self._queue[0]
-                    seq = head._resume[0] if head._resume is not None \
-                        else head.prompt
-                    place = self._admit_place(seq)
-                    if place is None:
-                        break   # head-of-line waits for blocks to free up
-                    shard = place[0]
+                head = self._queue[0]
+                seq = head._resume[0] if head._resume is not None \
+                    else head.prompt
+                place = self._admit_place(seq)
+                if place is None:
+                    break   # head-of-line waits for blocks to free up
                 req = self._queue.popleft()
                 SERVING_QUEUE_DEPTH.set(len(self._queue))
                 self._cv.notify_all()   # wake submitters blocked on full
@@ -1829,39 +1725,23 @@ class InferenceEngine:
                         cat="serving",
                         args=self._chain_args(
                             req, resumed=req._resume is not None))
-            slot = self.cache.alloc(prefer_shard=shard) if paged \
-                else self.cache.alloc()
-            if paged:
-                st = _Slot(req, length=0, last_token=-1)
-                st.generated = len(req.tokens)   # nonzero on resume
-                self._admit_seq += 1
-                st.admit_order = self._admit_seq
-                if req._resume is not None:
-                    seq, st.resume_last = req._resume
-                    req._resume = None
-                else:
-                    seq = req.prompt
-                _, m_len, m_blocks = place
-                if self._prefix is not None:
-                    m_len = self._splice_prefix(slot, m_len, m_blocks)
-                    self._prefix.note_lookup(m_len, seq.size)
-                if m_len > 0:
-                    st.length = m_len
-                    st.tail_mode = True
-                    self.cache.lengths[slot] = m_len
-                st.pending = seq[m_len:]
-                self._slots[slot] = st
-                continue
-            try:
-                self._prefill(req, slot)
-            except BaseException as e:  # noqa: BLE001
-                # mid-admission crash: the request is in neither the
-                # queue nor a slot, so _abort would miss it — fail it
-                # here before the scheduler unwinds
-                if self._slots[slot] is None:
-                    self.cache.release(slot)
-                req._finish(ERROR, e)
-                raise
+            shard, m_len, m_blocks = place
+            slot = self.cache.alloc(prefer_shard=shard)
+            st = _Slot(req)
+            self._admit_seq += 1
+            st.admit_order = self._admit_seq
+            if req._resume is not None:
+                st.resume_last = req._resume[1]
+                req._resume = None
+            if self._prefix is not None:
+                m_len = self._splice_prefix(slot, m_len, m_blocks)
+                self._prefix.note_lookup(m_len, seq.size)
+            if m_len > 0:
+                st.length = m_len
+                st.tail_mode = True
+                self.cache.lengths[slot] = m_len
+            st.pending = seq[m_len:]
+            self._slots[slot] = st
         SERVING_SLOT_OCCUPANCY.set(self.cache.occupancy)
 
     def _admit_place(self, seq):
@@ -1946,12 +1826,6 @@ class InferenceEngine:
             return True
         return self._prefix.evict(shard, missing) >= missing
 
-    def _bucket(self, n: int) -> int:
-        b = 16
-        while b < n:
-            b *= 2
-        return min(b, self.max_len)
-
     def _width_bucket(self, n_blocks: int) -> int:
         b = 1
         while b < n_blocks:
@@ -1959,9 +1833,8 @@ class InferenceEngine:
         return min(b, self.cache.table_width)
 
     def _stream_key(self, rid: int, draw: int):
-        """Host-side stream key for single-row programs (prefill): the
-        same (seed, request, draw) fold the batched steps compute
-        in-jit."""
+        """Host-side stream key for a prompt's first token: the same
+        (seed, request, draw) fold the batched steps compute in-jit."""
         return jax.random.fold_in(
             jax.random.fold_in(self._base_key, rid % (2**31 - 1)), draw)
 
@@ -1978,78 +1851,6 @@ class InferenceEngine:
         out[0, :m.shape[0]] = m
         return out
 
-    def _prefill(self, req: GenerationRequest, slot: int) -> None:
-        # a watchdog restart requeues fixed-mode streams with a resume
-        # record: re-prefill prompt+generated[:-1] and rebuild decode
-        # state without re-emitting — the paged preemption-resume
-        # contract on the fixed cache
-        resume = req._resume
-        req._resume = None
-        seq = resume[0] if resume is not None else req.prompt
-        S = int(seq.size)
-        if resume is not None and S + 1 > self.max_len:
-            self.cache.release(slot)
-            req._finish(LENGTH)
-            return
-        t0 = time.perf_counter()
-        pf_args = {"slot": slot, "prompt_len": S}
-        flow = None
-        if req.trace is not None and recording():
-            pf_args.update(req.trace.args(rid=req.rid))
-            flow = req.trace.trace_id
-        with span("serving.prefill", cat="serving", args=pf_args,
-                  flow=flow):
-            if native.serving_jit[0]:
-                s_pad = self._bucket(S)
-                toks = np.zeros((1, s_pad), np.int32)
-                toks[0, :S] = seq
-                key = self._stream_key(req.rid, 0)
-                if self.draft is not None:
-                    (tok, self.cache.k, self.cache.v, self.draft_cache.k,
-                     self.draft_cache.v) = self._prefill_spec_jit(
-                        self._params, self._draft_params, self.cache.k,
-                        self.cache.v, self.draft_cache.k,
-                        self.draft_cache.v, jnp.asarray(toks),
-                        np.int32(slot), np.int32(S), key,
-                        np.float32(req.temperature), np.int32(req.top_k),
-                        np.float32(req.top_p),
-                        jnp.asarray(self._mask_row(req)))
-                else:
-                    tok, self.cache.k, self.cache.v = self._prefill_jit(
-                        self._params, self.cache.k, self.cache.v,
-                        jnp.asarray(toks), np.int32(slot), np.int32(S),
-                        key, np.float32(req.temperature),
-                        np.int32(req.top_k), np.float32(req.top_p),
-                        jnp.asarray(self._mask_row(req)))
-            else:
-                logits = self._model.forward(self.cfg, self._params,
-                                     jnp.asarray(seq[None]))
-                tok = sample_tokens(
-                    logits[:, -1], self._stream_key(req.rid, 0),
-                    jnp.float32(req.temperature)[None],
-                    jnp.int32(req.top_k)[None],
-                    jnp.float32(req.top_p)[None],
-                    mask=jnp.asarray(self._mask_row(req)))[0]
-            tok = int(tok)
-        pf_ms = (time.perf_counter() - t0) * 1e3
-        self._note_ms(SERVING_PREFILL_MS, "_prefill_ms", pf_ms)
-        SERVING_PREFILL_CHUNK_MS.observe(pf_ms)
-        SERVING_PREFILL_CHUNKS.add(1)
-        st = _Slot(req, length=S, last_token=tok)
-        st.t_admit, st.chunks = t0, 1
-        self._slots[slot] = st
-        self.cache.lengths[slot] = S
-        if resume is not None:
-            # tokens through resume[1] were already streamed before the
-            # restart — rebuild decode state, emit nothing
-            st.last_token = resume[1]
-            st.generated = len(req.tokens)
-            return
-        self._push_first(st, tok)
-        reason = self._finish_reason(st, tok)
-        if reason is not None:
-            self._evict(slot, reason)
-
     def _push_first(self, st: _Slot, tok: int) -> None:
         """Stream a request's first token and close the middle link of
         its chain: ``serving.admit_to_first`` (admit -> first token)."""
@@ -2061,7 +1862,7 @@ class InferenceEngine:
                 time.perf_counter() - st.t_admit, cat="serving",
                 args=self._chain_args(st.req, chunks=st.chunks))
 
-    # -- paged mode: chunked prefill + preemption ----------------------------
+    # -- chunked prefill + preemption ----------------------------------------
     def _open_decode_streams(self) -> int:
         return sum(1 for st in self._slots
                    if st is not None and st.pending is None)
@@ -2238,8 +2039,7 @@ class InferenceEngine:
                 victim = self._youngest_slot(exclude=s)
                 if victim is None:
                     # alone and the pool is spent: nothing will ever free
-                    # a block — cache capacity reached, same terminal
-                    # condition as the fixed engine's full slot
+                    # a block — cache capacity reached
                     self._evict(s, LENGTH)
                     break
                 if self._slots[victim].admit_order <= st.admit_order:
@@ -2250,7 +2050,7 @@ class InferenceEngine:
         return [s for s in ready if self._slots[s] is not None]
 
     def _try_spec_grow(self, active: List[int]) -> bool:
-        """Paged spec headroom: grow every active table to cover the k
+        """Spec headroom: grow every active table to cover the k
         proposals + bonus WITHOUT preempting anyone (speculation is an
         optimization, never worth evicting work for). False → this tick
         falls back to the plain one-token program."""
@@ -2293,7 +2093,7 @@ class InferenceEngine:
             # so speculating through an automaton would emit illegal tokens.
             constrained = [s for s in active
                            if self._slots[s].req.constraint is not None]
-            use_spec = (self.draft is not None and native.serving_jit[0]
+            use_spec = (self.draft is not None
                         and (self.overload is None
                              or self.overload.spec_allowed())
                         and all(self._slots[s].length + self.spec_k + 1
@@ -2301,13 +2101,12 @@ class InferenceEngine:
             if use_spec and constrained:
                 use_spec = False
                 CONSTRAINED_FALLBACK_TICKS.add(1)
-            if self.paged and native.serving_jit[0]:
-                if use_spec:
-                    use_spec = self._try_spec_grow(active)
-                if not use_spec:
-                    active = self._grow_for_decode(active)
-                    if not active:
-                        return
+            if use_spec:
+                use_spec = self._try_spec_grow(active)
+            if not use_spec:
+                active = self._grow_for_decode(active)
+                if not active:
+                    return
 
             if _faults.ENABLED[0]:
                 # serving_nan fault (FLAGS_fault_inject, keyed by REQUEST id):
@@ -2364,46 +2163,33 @@ class InferenceEngine:
                 out, n_emit, health = self._spec_dispatch(
                     active, positions, tokens, rids, steps, temps,
                     top_ks, top_ps)
-            elif native.serving_jit[0]:
-                if self.paged:
-                    # table width bucketed to the live maximum (next pow2):
-                    # attention/gather work tracks LIVE tokens, not the
-                    # worst-case table — one compile per width bucket,
-                    # log2(table_width) programs total
-                    tables = self.cache.tables_array(active)
-                    held = [len(self.cache.block_tables[s]) for s in active]
-                    tables = tables[:, :self._width_bucket(max(held))]
-                    # how much of the tabled width is live: the decode
-                    # kernel walks the live blocks only
-                    live, tabled = sum(held), tables.size
-                    span_args["decode_blocks_live"] = live
-                    span_args["decode_blocks_tabled"] = tabled
-                    SERVING_DECODE_BLOCKS_LIVE.add(live)
-                    SERVING_DECODE_BLOCKS_TABLED.add(tabled)
-                    got = self._decode_paged_jit(
-                        self._decode_params, *self.cache.pool, tables,
-                        positions, tokens, self._base_key, rids, steps,
-                        temps, top_ks, top_ps, mask_arg)
-                    moe_stats = None
-                    if self._routed:
-                        *got, moe_stats = got
-                    if self._watchdog is not None:
-                        out, health, *pool = got
-                    else:
-                        out, *pool = got
-                    self.cache.pool = tuple(pool)
+            else:
+                # table width bucketed to the live maximum (next pow2):
+                # attention/gather work tracks LIVE tokens, not the
+                # worst-case table — one compile per width bucket,
+                # log2(table_width) programs total
+                tables = self.cache.tables_array(active)
+                held = [len(self.cache.block_tables[s]) for s in active]
+                tables = tables[:, :self._width_bucket(max(held))]
+                # how much of the tabled width is live: the decode
+                # kernel walks the live blocks only
+                live, tabled = sum(held), tables.size
+                span_args["decode_blocks_live"] = live
+                span_args["decode_blocks_tabled"] = tabled
+                SERVING_DECODE_BLOCKS_LIVE.add(live)
+                SERVING_DECODE_BLOCKS_TABLED.add(tabled)
+                got = self._decode_paged_jit(
+                    self._decode_params, *self.cache.pool, tables,
+                    positions, tokens, self._base_key, rids, steps,
+                    temps, top_ks, top_ps, mask_arg)
+                moe_stats = None
+                if self._routed:
+                    *got, moe_stats = got
+                if self._watchdog is not None:
+                    out, health, *pool = got
                 else:
-                    got = self._decode_jit(
-                        self._decode_params, self.cache.k, self.cache.v,
-                        positions, tokens, self._base_key, rids, steps,
-                        temps, top_ks, top_ps, mask_arg)
-                    moe_stats = None
-                    if self._moe:
-                        *got, moe_stats = got
-                    if self._watchdog is not None:
-                        out, health, self.cache.k, self.cache.v = got
-                    else:
-                        out, self.cache.k, self.cache.v = got
+                    out, *pool = got
+                self.cache.pool = tuple(pool)
                 with span("serving.device_wait", cat="serving",
                           args=self._tick_args()):
                     out = np.asarray(out)
@@ -2411,26 +2197,6 @@ class InferenceEngine:
                 if moe_stats is not None:
                     self._note_moe(moe_stats, span_args)
                 self._note_moe_pending()
-            else:
-                # reference decode: full recompute per sequence, no cache
-                out = np.zeros(self.n_slots, np.int32)
-                if self._watchdog is not None:
-                    health = np.ones(self.n_slots, bool)
-                for s in active:
-                    st = self._slots[s]
-                    seq = np.concatenate(
-                        [st.req.prompt, np.asarray(st.req.tokens, np.int32)])
-                    logits = self._model.forward(self.cfg, self._params,
-                                         jnp.asarray(seq[None]))
-                    if health is not None:
-                        health[s] = bool(np.all(np.isfinite(
-                            np.asarray(logits[:, -1]))))
-                    out[s] = int(sample_tokens(
-                        logits[:, -1],
-                        self._stream_key(int(rids[s]), int(steps[s])),
-                        temps[s:s + 1], top_ks[s:s + 1], top_ps[s:s + 1],
-                        mask=jnp.asarray(self._mask_row(st.req)))[0])
-                n_emit = None
             if use_spec:
                 span_args["proposed"] = self.spec_k * len(active)
                 span_args["accepted"] = int(sum(int(n_emit[s]) - 1
@@ -2494,9 +2260,8 @@ class InferenceEngine:
                                 int(sum(int(n_emit[s]) - 1 for s in active)))
             self._note_tokens(emitted)
             SERVING_SLOT_OCCUPANCY.set(self.cache.occupancy)
-            if self.paged:
-                # refresh kv_fragmentation vs lengths
-                self.cache.update_gauges()
+            # refresh kv_fragmentation vs lengths
+            self.cache.update_gauges()
 
     def _spec_dispatch(self, active, positions, tokens, rids, steps, temps,
                        top_ks, top_ps):
@@ -2506,33 +2271,20 @@ class InferenceEngine:
         or None) — health only when the watchdog is armed, computed over
         every verify position inside the same compiled program."""
         health = None
-        if self.paged:
-            tables = self.cache.tables_array(active)
-            tables = tables[:, :self._width_bucket(
-                max(len(self.cache.block_tables[s]) for s in active))]
-            got = self._spec_paged_jit(
-                self._decode_params, self._draft_params, self.cache.kb,
-                self.cache.vb, self.draft_cache.k, self.draft_cache.v,
-                tables, positions, tokens, self._base_key, rids, steps,
-                temps, top_ks, top_ps)
-            if self._watchdog is not None:
-                (out, n_emit, health, self.cache.kb, self.cache.vb,
-                 self.draft_cache.k, self.draft_cache.v) = got
-            else:
-                (out, n_emit, self.cache.kb, self.cache.vb,
-                 self.draft_cache.k, self.draft_cache.v) = got
+        tables = self.cache.tables_array(active)
+        tables = tables[:, :self._width_bucket(
+            max(len(self.cache.block_tables[s]) for s in active))]
+        got = self._spec_paged_jit(
+            self._decode_params, self._draft_params, self.cache.kb,
+            self.cache.vb, self.draft_cache.k, self.draft_cache.v,
+            tables, positions, tokens, self._base_key, rids, steps,
+            temps, top_ks, top_ps)
+        if self._watchdog is not None:
+            (out, n_emit, health, self.cache.kb, self.cache.vb,
+             self.draft_cache.k, self.draft_cache.v) = got
         else:
-            got = self._spec_jit(
-                self._decode_params, self._draft_params, self.cache.k,
-                self.cache.v, self.draft_cache.k, self.draft_cache.v,
-                positions, tokens, self._base_key, rids, steps, temps,
-                top_ks, top_ps)
-            if self._watchdog is not None:
-                (out, n_emit, health, self.cache.k, self.cache.v,
-                 self.draft_cache.k, self.draft_cache.v) = got
-            else:
-                (out, n_emit, self.cache.k, self.cache.v,
-                 self.draft_cache.k, self.draft_cache.v) = got
+            (out, n_emit, self.cache.kb, self.cache.vb,
+             self.draft_cache.k, self.draft_cache.v) = got
         with span("serving.device_wait", cat="serving",
                   args=self._tick_args()):
             return (np.asarray(out), np.asarray(n_emit),
@@ -2565,19 +2317,13 @@ class InferenceEngine:
 
     # -- watchdog: NaN/latency sentinel + auto-restart -----------------------
     def _poison_slot(self, slot: int) -> None:
-        """serving_nan fault effect: overwrite the slot's cached K/V rows
-        with NaN (the deterministic stand-in for poisoned HBM / a bad
-        collective). Only the jitted cache-decode paths read these rows —
-        the FLAGS_serving_jit=0 reference decode recomputes from tokens
-        and never sees them."""
+        """serving_nan fault effect: overwrite the slot's cached K/V
+        blocks with NaN (the deterministic stand-in for poisoned HBM / a
+        bad collective)."""
         nan = float("nan")
-        if self.paged:
-            rows = jnp.asarray(self.cache.block_tables[slot], jnp.int32)
-            self.cache.kb = self.cache.kb.at[rows].set(nan)
-            self.cache.vb = self.cache.vb.at[rows].set(nan)
-        else:
-            self.cache.k = self.cache.k.at[slot].set(nan)
-            self.cache.v = self.cache.v.at[slot].set(nan)
+        rows = jnp.asarray(self.cache.block_tables[slot], jnp.int32)
+        self.cache.kb = self.cache.kb.at[rows].set(nan)
+        self.cache.vb = self.cache.vb.at[rows].set(nan)
 
     def _watchdog_latency(self, tick_ms: float) -> None:
         """Latency rung of the sentinel: ``latency_trips`` consecutive
@@ -2650,20 +2396,7 @@ class InferenceEngine:
         """Fresh zeroed cache buffers + accounting (and a fresh prefix
         tree — cached prefixes may reference poisoned blocks; dropping
         the cache costs recompute, never correctness)."""
-        max_len, n_blocks, block_size = self._cache_args
-        if self.paged:
-            self.cache = PagedKVCache(self.cfg, self.n_slots,
-                                      n_blocks=n_blocks,
-                                      block_size=block_size,
-                                      shards=self._shards)
-            if self._mesh is not None:
-                self.cache.kb = self._put_cache(self.cache.kb)
-                self.cache.vb = self._put_cache(self.cache.vb)
-        else:
-            self.cache = KVCache(self.cfg, self.n_slots, max_len)
-            if self._mesh is not None:
-                self.cache.k = self._put_cache(self.cache.k)
-                self.cache.v = self._put_cache(self.cache.v)
+        self.cache = self._build_cache()
         if self._prefix is not None:
             self._prefix = RadixPrefixCache(self.cache)
         if self.draft is not None:
@@ -2671,8 +2404,7 @@ class InferenceEngine:
             # target rows — rebuild its fixed cache too, so the spec
             # path resumes from the same clean slate (ISSUE 14)
             self.draft_cache = self._build_draft_cache()
-        if hasattr(self.cache, "update_gauges"):
-            self.cache.update_gauges()
+        self.cache.update_gauges()
 
     # -- gauges --------------------------------------------------------------
     def _note_moe(self, moe_stats, span_args=None) -> None:

@@ -950,7 +950,7 @@ class ServingFrontend:
 # python -m paddle_tpu.serving.frontend
 # ==========================================================================
 
-def _demo_engine(paged: bool = True, prefix: bool = True):
+def _demo_engine(prefix: bool = True):
     """A gpt_tiny engine with the byte tokenizer — the zero-config demo
     target (swap in real weights by constructing ServingFrontend
     directly)."""
@@ -963,9 +963,9 @@ def _demo_engine(paged: bool = True, prefix: bool = True):
     tok = ByteTokenizer()
     cfg = gpt_tiny(seq_len=256, vocab_size=512, dtype=jnp.float32)
     params = gpt_init(cfg, seed=0)
-    return InferenceEngine(cfg, params, n_slots=8, paged=paged,
-                           block_size=16, prefill_chunk=64,
-                           prefix_cache=prefix and paged, tokenizer=tok)
+    return InferenceEngine(cfg, params, n_slots=8, block_size=16,
+                           prefill_chunk=64, prefix_cache=prefix,
+                           tokenizer=tok)
 
 
 def main(argv=None) -> int:

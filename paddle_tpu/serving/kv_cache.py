@@ -1,23 +1,27 @@
-"""Fixed-slot KV cache for the serving engine.
+"""KV caches for the serving engine: the paged block pool the target
+model is served from (:class:`PagedKVCache`), and the fixed-slot cache a
+speculative DRAFT model keeps for itself (:class:`KVCache`), which only
+``InferenceEngine(draft=...)`` builds.
 
 The reference's inference stack keeps per-predictor scratch memory alive
 across runs (AnalysisPredictor zero-copy tensors); the autoregressive
-analog is the decode cache. This one is Orca/vLLM-slot style, TPU-shaped:
-ONE pair of device buffers
+analog is the decode cache. The fixed-slot one is ONE pair of device
+buffers
 
     k, v : (n_slots, n_layers, n_heads, max_len, head_dim)   cfg.dtype
 
-allocated once and donated through every jitted prefill/decode call, so
-steady-state serving allocates nothing and the compiled decode program
-has a single static shape regardless of which slots are live. A slot is
-the unit of admission: a request owns exactly one slot from prefill to
-eviction; per-slot write positions and attention masks come from the
-``positions`` argument of :func:`paddle_tpu.models.gpt_decode_step`, so
-slots at different generation depths batch into one program.
+allocated once and donated through every jitted chunk and speculative
+tick, so steady-state serving allocates nothing and the draft's steps
+have a single static shape regardless of which slots are live. A draft
+row belongs to the engine slot of the same index; per-slot write
+positions and attention masks come from the ``positions`` argument of
+:func:`paddle_tpu.models.gpt_decode_step`, so slots at different
+generation depths batch into one program.
 
-Slot bookkeeping (free list, per-slot length) is host-side — it changes
-at request granularity, not token granularity, and keeping it out of the
-device state keeps the decode step free of host syncs.
+Slot bookkeeping (free lists, per-slot lengths, block tables) is
+host-side — it changes at request granularity, not token granularity,
+and keeping it out of the device state keeps the decode step free of
+host syncs.
 """
 from __future__ import annotations
 
@@ -34,7 +38,9 @@ __all__ = ["KVCache", "PagedKVCache", "cache_insert"]
 
 
 def cache_insert(k_cache, v_cache, slot, k_new, v_new):
-    """Write one sequence's prefill entries into a slot.
+    """Write one sequence's prefill entries into a slot (the tests'
+    oracles fill a :class:`KVCache` with it; the engine fills the draft's
+    a chunk at a time through ``verify_step``).
 
     k_new/v_new: (L, nh, S, hd) with S <= max_len (gpt_prefill output for
     one sequence); ``slot`` may be traced — one compiled insert serves
@@ -48,7 +54,8 @@ def cache_insert(k_cache, v_cache, slot, k_new, v_new):
 
 
 class KVCache:
-    """Slotted decode cache: device buffers + host-side slot accounting."""
+    """The draft model's slotted decode cache: device buffers + host-side
+    slot accounting."""
 
     def __init__(self, cfg, n_slots: int, max_len: Optional[int] = None,
                  dtype=None):
@@ -104,7 +111,8 @@ class KVCache:
 
 
 class PagedKVCache:
-    """Paged decode cache (FLAGS_paged_kv, ISSUE 7): a shared block pool
+    """Paged decode cache (ISSUE 7), the engine's one cache for the
+    target model: a shared block pool
     plus per-slot block tables — vLLM-style PagedAttention memory, TPU
     shaped.
 

@@ -127,7 +127,7 @@ class ReplicaSupervisor:
 
         ctl = OverloadController()
         def factory():
-            return InferenceEngine(cfg, params, seed=0, paged=True,
+            return InferenceEngine(cfg, params, seed=0,
                                    prefix_cache=True, overload=ctl)
         router = EngineRouter([factory(), factory()])
         sup = ReplicaSupervisor(router, factory, max_replicas=4)

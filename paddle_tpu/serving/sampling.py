@@ -31,8 +31,8 @@ engine feeds masks from per-request token-mask automata
 (serving.constrained); ``mask=None`` (and an all-True mask) leave every
 path bit-identical to the unmasked code.
 
-Everything here is pure jnp, so the FLAGS_serving_jit=0 reference path
-runs the SAME code un-jitted.
+Everything here is pure jnp: the engine's eager first-token sample and
+its jitted ticks run the SAME code.
 """
 from __future__ import annotations
 

@@ -552,22 +552,3 @@ class TestPlannerStatic:
         # the cost model runs at trace-build time on the host, so nothing
         # here may become traced code where a host sync would stall TPUs
         assert find_seeds(proj) == []
-
-
-class TestBenchConfig:
-    @pytest.mark.slow  # full bench leg; planner logic is pinned by the unit tests above
-    def test_gpt_1p3b_auto_analytic_leg(self):
-        import sys
-
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        import bench
-
-        out = bench.bench_gpt_1p3b_auto(False)
-        assert "plan" in out and "pp=" in out["plan"]
-        assert "plan_table" in out and "chosen" in out["plan_table"]
-        # the measured proxy leg ran on the 8-device virtual mesh and
-        # pins the ZeRO-3 acceptance row
-        m = out["measured"]
-        assert m["measured_zero3_param_opt_frac"] <= 0.40
-        assert m["planner"]["sps"] > 0

@@ -509,7 +509,6 @@ def engine():
 
     def make(**kw):
         kw.setdefault("n_slots", 2)
-        kw.setdefault("paged", True)
         kw.setdefault("block_size", 8)
         kw.setdefault("prefill_chunk", 16)
         kw.setdefault("seed", 0)
@@ -673,7 +672,7 @@ class TestChunkStreaming:
 # ==========================================================================
 
 def _factory():
-    return InferenceEngine(CFG, PARAMS, n_slots=2, paged=True,
+    return InferenceEngine(CFG, PARAMS, n_slots=2,
                            block_size=8, prefill_chunk=16, seed=0,
                            prefix_cache=True, n_blocks=129)
 

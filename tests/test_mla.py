@@ -349,14 +349,13 @@ def test_the_preset_is_the_published_model_and_takes_the_cut():
 # -- the engine ----------------------------------------------------------------
 
 def _engine(cfg, params, **kw):
-    base = dict(n_slots=2, paged=True, block_size=8, n_blocks=24,
+    base = dict(n_slots=2, block_size=8, n_blocks=24,
                 prefill_chunk=16, prefix_cache=False)
     base.update(kw)
     return InferenceEngine(cfg, params, **base)
 
 
 @pytest.mark.parametrize("kw, sentence", [
-    (dict(paged=False), "cannot serve from the fixed-slot KVCache yet"),
     (dict(draft=(gpt_tiny(), None)), "cannot take draft= yet"),
     (dict(prefix_cache=True), "cannot use prefix_cache yet"),
     (dict(int8_weights=True), "cannot take int8_weights yet"),

@@ -84,7 +84,6 @@ def engine():
 
     def make(params=PARAMS, cfg=CFG, **kw):
         kw.setdefault("n_slots", 2)
-        kw.setdefault("paged", True)
         kw.setdefault("block_size", 8)
         kw.setdefault("prefill_chunk", 16)
         kw.setdefault("seed", 0)
@@ -264,7 +263,7 @@ def frontend():
     cfg = gpt_tiny(dtype=jnp.float32, seq_len=256,
                    vocab_size=tok.vocab_size)
     params = gpt_init(cfg, seed=5)
-    eng = InferenceEngine(cfg, params, n_slots=4, paged=True,
+    eng = InferenceEngine(cfg, params, n_slots=4,
                           block_size=16, prefill_chunk=64, tokenizer=tok)
     fe = ServingFrontend(eng, tenants=[
         Tenant("load-co", "sk-load", rate=1000, burst=1000,
@@ -401,7 +400,7 @@ class TestRequestTracing:
         params = gpt_init(cfg, seed=5)
 
         def make():
-            return InferenceEngine(cfg, params, n_slots=2, paged=True,
+            return InferenceEngine(cfg, params, n_slots=2,
                                    block_size=8, prefill_chunk=16,
                                    seed=0, tokenizer=tok)
 

@@ -666,7 +666,7 @@ class TestServingWatchdog:
     # nano-scale target + class-cached watchdog-OFF baselines: the
     # token-identity pins need the SAME params everywhere, not a big
     # model, and each engine build costs a fresh set of jit traces
-    _baselines: dict = {}
+    _baseline_outs = None
 
     @classmethod
     def _cfg_params(cls):
@@ -679,14 +679,13 @@ class TestServingWatchdog:
             cls._cached = (cfg, gpt_init(cfg, seed=0))
         return cls._cached
 
-    def _run(self, watchdog, nan_rid=None, paged=False, n_new=10):
+    def _run(self, watchdog, nan_rid=None, n_new=10):
         from paddle_tpu.serving.engine import InferenceEngine
 
         cfg, params = self._cfg_params()
         configure_faults(f"serving_nan@step={nan_rid}"
                          if nan_rid is not None else "")
-        eng = InferenceEngine(cfg, params, n_slots=4, max_len=64,
-                              paged=paged, watchdog=watchdog)
+        eng = InferenceEngine(cfg, params, n_slots=4, watchdog=watchdog)
         prompts = [[1, 2, 3, 4, 5], [7, 8, 9], [11, 12, 13, 14],
                    [3, 1, 4, 1, 5]]
         reqs = [eng.submit(p, max_new_tokens=n_new) for p in prompts]
@@ -700,30 +699,23 @@ class TestServingWatchdog:
         configure_faults("")
         return outs
 
-    def _baseline(self, paged):
-        if paged not in self._baselines:
-            self._baselines[paged] = self._run(None, paged=paged)
-        return self._baselines[paged]
+    def _baseline(self):
+        cls = type(self)
+        if cls._baseline_outs is None:
+            cls._baseline_outs = self._run(None)
+        return cls._baseline_outs
 
-    def test_restart_token_identical_fixed(self):
-        base = self._baseline(False)
+    @pytest.mark.parametrize("nan_rid", [1, 2])
+    def test_restart_token_identical(self, nan_rid):
+        base = self._baseline()
         trips0 = monitor.stat_get("serving_watchdog_trips")
         rest0 = monitor.stat_get("serving_watchdog_restarts")
-        wd = self._run(True, nan_rid=1)
-        assert wd[1] == ("FAILED", "watchdog")
-        for i in (0, 2, 3):
+        wd = self._run(True, nan_rid=nan_rid)
+        assert wd[nan_rid] == ("FAILED", "watchdog")
+        for i in set(range(4)) - {nan_rid}:
             assert wd[i] == base[i], i
         assert monitor.stat_get("serving_watchdog_trips") - trips0 >= 1
         assert monitor.stat_get("serving_watchdog_restarts") - rest0 == 1
-
-    def test_restart_token_identical_paged(self):
-        base = self._baseline(True)
-        wd = self._run(True, nan_rid=2, paged=True)
-        assert wd[2] == ("FAILED", "watchdog")
-        for i in (0, 1, 3):
-            assert wd[i] == base[i], i
-        # paged and fixed agree (greedy pin sanity)
-        assert base == self._baseline(False)
 
     def test_watchdog_off_is_inert(self):
         """Watchdog off: no health output, no restart, gauges flat —
@@ -763,7 +755,7 @@ class TestServingWatchdog:
 
         cfg, params = self._cfg_params()
         eng = InferenceEngine(
-            cfg, params, n_slots=2, max_len=64,
+            cfg, params, n_slots=2,
             watchdog={"latency_budget_ms": 0.0001, "latency_trips": 2})
         trips0 = monitor.stat_get("serving_watchdog_trips")
         req = eng.submit([1, 2, 3], max_new_tokens=8)
@@ -778,7 +770,7 @@ class TestServingWatchdog:
         cfg, params = self._cfg_params()
         # two sequentially-poisoned requests against a one-restart budget
         configure_faults("serving_nan@step=0:repeat=2")
-        eng = InferenceEngine(cfg, params, n_slots=2, max_len=64,
+        eng = InferenceEngine(cfg, params, n_slots=2,
                               watchdog={"max_restarts": 1})
         r0 = eng.submit([1, 2, 3], max_new_tokens=6)
         with pytest.raises(RuntimeError) as ei:

@@ -61,7 +61,6 @@ def engine():
 
     def make(params=PARAMS, cfg=CFG, **kw):
         kw.setdefault("n_slots", 2)
-        kw.setdefault("paged", True)
         kw.setdefault("block_size", 8)
         kw.setdefault("prefill_chunk", 16)
         kw.setdefault("seed", 0)
@@ -86,7 +85,7 @@ def _clean_faults():
 
 
 def _factory():
-    return InferenceEngine(CFG, PARAMS, n_slots=2, paged=True,
+    return InferenceEngine(CFG, PARAMS, n_slots=2,
                            block_size=8, prefill_chunk=16, seed=0,
                            prefix_cache=True, n_blocks=129)
 
@@ -354,7 +353,7 @@ class TestFleetEndToEnd:
         params = gpt_init(cfg, seed=3)
 
         def factory():
-            return InferenceEngine(cfg, params, n_slots=2, paged=True,
+            return InferenceEngine(cfg, params, n_slots=2,
                                    block_size=8, prefill_chunk=16, seed=0,
                                    prefix_cache=True, n_blocks=129,
                                    tokenizer=tok)
@@ -431,7 +430,7 @@ class TestFleetEndToEnd:
         params = gpt_init(cfg, seed=3)
 
         def factory():
-            return InferenceEngine(cfg, params, n_slots=2, paged=True,
+            return InferenceEngine(cfg, params, n_slots=2,
                                    block_size=8, prefill_chunk=16, seed=0,
                                    prefix_cache=True, n_blocks=129,
                                    tokenizer=tok)
@@ -646,7 +645,7 @@ class TestFleetMultiProcess:
         params = _init(cfg, seed=3)
 
         def factory():
-            return _Engine(cfg, params, n_slots=2, paged=True,
+            return _Engine(cfg, params, n_slots=2,
                            block_size=8, prefill_chunk=16, seed=0,
                            prefix_cache=True, n_blocks=129, tokenizer=tok)
 
@@ -666,7 +665,7 @@ class TestFleetMultiProcess:
         cfg = gpt_tiny(dtype=jnp.float32, seq_len=128,
                        vocab_size=tok.vocab_size)
         params = gpt_init(cfg, seed=3)
-        mono = InferenceEngine(cfg, params, n_slots=2, paged=True,
+        mono = InferenceEngine(cfg, params, n_slots=2,
                                block_size=8, prefill_chunk=16, seed=0,
                                prefix_cache=True, n_blocks=129,
                                tokenizer=tok)

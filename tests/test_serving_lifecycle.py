@@ -57,7 +57,6 @@ def engine():
 
     def make(params=PARAMS, cfg=CFG, **kw):
         kw.setdefault("n_slots", 2)
-        kw.setdefault("paged", True)
         kw.setdefault("block_size", 8)
         kw.setdefault("prefill_chunk", 16)
         kw.setdefault("seed", 0)
@@ -204,7 +203,7 @@ class TestDynamicReplicaSet:
 # ==========================================================================
 
 class TestRestartRejoin:
-    def test_greedy_identity_paged(self, engine):
+    def test_greedy_identity(self, engine):
         prompts = [_prompt(9) for _ in range(3)]
         ref = engine(n_slots=4)
         expected = [ref.generate(p, max_new_tokens=12) for p in prompts]
@@ -221,31 +220,17 @@ class TestRestartRejoin:
         configure_faults("")
         router.shutdown(drain=True, timeout=60)
 
-    def test_greedy_identity_fixed(self, engine):
-        prompts = [_prompt(9) for _ in range(3)]
-        ref = engine(n_slots=4, paged=False)
-        expected = [ref.generate(p, max_new_tokens=12) for p in prompts]
-        configure_faults("replica_crash@step=4:replica=0")
-        router, sup = _supervised(engine, n=1,
-                                  factory_kw={"paged": False})
-        outs = [r.result(timeout=180) for r in
-                [router.submit(p, max_new_tokens=12) for p in prompts]]
-        assert outs == expected
-        configure_faults("")
-        router.shutdown(drain=True, timeout=60)
-
-    @pytest.mark.parametrize("paged", [True, False])
-    def test_sampled_identity_and_rid_space(self, engine, paged):
+    def test_sampled_identity_and_rid_space(self, engine):
         """Sampled streams survive a full-fleet death bit-exactly (rid +
         seed ride into the replacement), and a request submitted AFTER
         the rejoin continues the rid numbering — its stream matches the
-        fault-free run's. Both cache layouts."""
+        fault-free run's."""
         prompts = [_prompt(9) for _ in range(4)]
-        ref = engine(n_slots=4, paged=paged)
+        ref = engine(n_slots=4)
         expected = [ref.generate(p, max_new_tokens=10, temperature=0.9,
                                  top_k=7) for p in prompts]
         configure_faults("replica_crash@step=4:replica=0")
-        router, sup = _supervised(engine, n=1, factory_kw={"paged": paged})
+        router, sup = _supervised(engine, n=1)
         reqs = [router.submit(p, max_new_tokens=10, temperature=0.9,
                               top_k=7) for p in prompts[:3]]
         outs = [r.result(timeout=180) for r in reqs]
@@ -475,23 +460,21 @@ class TestAutoscale:
 class TestWatchdogDraftCompose:
     def test_healthy_compose_token_identity(self, engine):
         p = _prompt(9)
-        expected = engine(paged=False).generate(p, max_new_tokens=12)
-        eng = engine(paged=False, draft=DRAFT, spec_k=3, watchdog=True)
+        expected = engine().generate(p, max_new_tokens=12)
+        eng = engine(draft=DRAFT, spec_k=3, watchdog=True)
         assert eng.generate(p, max_new_tokens=12) == expected
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_nan_spec_tick_fails_only_poisoned_slot(self, engine, paged):
+    def test_nan_spec_tick_fails_only_poisoned_slot(self, engine):
         """serving_nan inside a SPECULATIVE tick: the verify program's
         in-jit verdict fingers the poisoned slot, only its stream fails
         (finish_reason watchdog), the healthy neighbor replays
         token-identically, and the draft cache is rebuilt alongside the
         target's."""
         p1, p2 = _prompt(9), _prompt(9)
-        ref = engine(n_slots=2, paged=paged)
+        ref = engine(n_slots=2)
         e1 = ref.generate(p1, max_new_tokens=12)
         e2 = ref.generate(p2, max_new_tokens=12)
-        eng = engine(n_slots=2, paged=paged, draft=DRAFT, spec_k=3,
-                     watchdog=True)
+        eng = engine(n_slots=2, draft=DRAFT, spec_k=3, watchdog=True)
         old_draft_cache = eng.draft_cache
         trips0 = monitor.stat_get("serving_watchdog_trips")
         configure_faults("serving_nan@step=2")      # rid 2 on THIS engine

@@ -50,7 +50,6 @@ def engine():
 
     def make(params=PARAMS, cfg=CFG, **kw):
         kw.setdefault("n_slots", 2)
-        kw.setdefault("paged", True)
         kw.setdefault("block_size", 8)
         kw.setdefault("prefill_chunk", 16)
         eng = InferenceEngine(cfg, params, **kw)
@@ -203,8 +202,8 @@ class TestChaosFaultSpecs:
 class TestEngineDeadlineShed:
     def test_expired_in_queue_sheds_before_prefill(self, engine):
         """A queued request whose deadline passes is shed WITHOUT any
-        prefill work: no serving.prefill/prefill_chunk span carries its
-        tokens, and serving_deadline_sheds counts it."""
+        prefill work: no serving.prefill_chunk span carries its tokens,
+        and serving_deadline_sheds counts it."""
         eng = engine(n_slots=1, queue_size=8)
         shed0 = monitor.stat_get("serving_deadline_sheds")
         blocker = eng.submit(_prompt(8), max_new_tokens=48)
@@ -219,8 +218,7 @@ class TestEngineDeadlineShed:
         # the shed burned zero prefill: every chunk span belongs to the
         # slot the blocker holds (slot 0 of a 1-slot engine)
         chunks = [e for e in writer.events()
-                  if e["name"] in ("serving.prefill",
-                                   "serving.prefill_chunk")]
+                  if e["name"] == "serving.prefill_chunk"]
         assert all(e["args"]["slot"] == 0 for e in chunks)
         blocker.result(timeout=120)
 

@@ -55,7 +55,6 @@ def engine(request):
 
     def make(params=PARAMS, **kw):
         kw.setdefault("n_slots", 2)
-        kw.setdefault("max_len", CFG.seq_len)
         eng = InferenceEngine(CFG, params, **kw)
         engines.append(eng)
         return eng
@@ -157,8 +156,9 @@ class TestSpecAccept:
 
 
 class TestSpeculativeEngine:
-    @pytest.mark.slow  # fixed-cache repeat of the paged identity leg below
-    def test_spec_greedy_token_identity_fixed(self, engine):
+    @pytest.mark.slow  # the identity leg below again, at the default
+    #                    block and chunk sizes, with the spec gauges
+    def test_spec_greedy_token_identity_and_gauges(self, engine):
         """Acceptance: speculative greedy == non-speculative greedy ==
         full-recompute reference, spec gauges move, report verdict."""
         prompt = _prompt(9)
@@ -176,7 +176,7 @@ class TestSpeculativeEngine:
     def test_spec_greedy_token_identity_paged(self, engine):
         prompt = _prompt(9)
         ref = _ref_greedy(prompt, 20)
-        eng = engine(paged=True, block_size=8, prefill_chunk=16,
+        eng = engine(block_size=8, prefill_chunk=16,
                      draft=DRAFT, spec_k=4)
         assert eng.submit(prompt, max_new_tokens=20).result(
             timeout=120) == ref
@@ -189,7 +189,7 @@ class TestSpeculativeEngine:
         pa, pb = _prompt(9), _prompt(11)
         ra_ref, rb_ref = _ref_greedy(pa, 20), _ref_greedy(pb, 20)
         pre0 = monitor.stat_get("serving_preemptions")
-        eng = engine(paged=True, block_size=8, prefill_chunk=16,
+        eng = engine(block_size=8, prefill_chunk=16,
                      n_blocks=7, draft=DRAFT, spec_k=3)
         ra = eng.submit(pa, max_new_tokens=20)
         rb = eng.submit(pb, max_new_tokens=20)
@@ -348,19 +348,14 @@ class TestMultiChipDecode:
         eng = engine(n_slots=8, mesh=mesh)
         assert eng._shards == 4
         assert monitor.stat_get("serving_shards") == 4
-        assert eng.cache.k.sharding.spec == P("data", None, "model",
-                                              None, None)
+        assert eng.cache.kb.sharding.spec == P("data", None, "model",
+                                               None, None)
         assert eng._params["blocks"]["qkv_w"].sharding.spec == \
             P(None, None, "model")
         assert eng.submit(prompt, max_new_tokens=12).result(
             timeout=300) == ref
 
-        B = eng.n_slots
-        z = np.zeros(B, np.int32)
-        hlo = jax.jit(eng._decode_fn).lower(
-            eng._params, eng.cache.k, eng.cache.v, z, z, eng._base_key,
-            z, z, np.zeros(B, np.float32), z,
-            np.ones(B, np.float32), eng._ones_mask).compile().as_text()
+        hlo = eng.lower_decode().compile().as_text()
         assert "all-reduce" in hlo or "all-gather" in hlo
 
     def test_paged_mesh_per_shard_block_accounting(self, engine):
@@ -370,7 +365,7 @@ class TestMultiChipDecode:
         mesh = _mesh42()
         prompt = _prompt(9)
         ref = _ref_greedy(prompt, 10)
-        eng = engine(n_slots=8, paged=True, block_size=8, prefill_chunk=16,
+        eng = engine(n_slots=8, block_size=8, prefill_chunk=16,
                      mesh=mesh)
         cache = eng.cache
         assert cache.shards == 4

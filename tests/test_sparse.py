@@ -442,8 +442,7 @@ class TestServingRank:
         from paddle_tpu.serving.engine import InferenceEngine
 
         cfg = gpt_tiny(dtype=jnp.float32, seq_len=64)
-        eng = InferenceEngine(cfg, gpt_init(cfg, 0), n_slots=2,
-                              paged=False, max_len=32)
+        eng = InferenceEngine(cfg, gpt_init(cfg, 0), n_slots=2)
         try:
             with pytest.raises(RuntimeError, match="embedding_tables"):
                 eng.rank({"t": [[1]]})
@@ -458,7 +457,6 @@ class TestServingRank:
 
         cfg = gpt_tiny(dtype=jnp.float32, seq_len=64)
         eng = InferenceEngine(cfg, gpt_init(cfg, 0), n_slots=2,
-                              paged=False, max_len=32,
                               embedding_tables={"t": table})
         try:
             scores = eng.rank({"t": np.array([[1, 2], [3, 4]], np.int32)})

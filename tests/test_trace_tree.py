@@ -1,6 +1,6 @@
 """ISSUE 26 — the program read off its own trace.
 
-- every scheduler turn of a paged engine is one span tree: the children
+- every scheduler turn of the engine is one span tree: the children
   nest in their ``serving.turn`` and carry its ``tick``;
 - a request submitted WITHOUT a front end still yields its chain
   (``serving.queue_wait`` -> ``serving.admit_to_first`` ->
@@ -11,7 +11,7 @@
   CPU-compiled step; a traced ``DistributedTrainStep`` emits the table
   once at ``stop_tracing()``; a failing ``on_stop`` callback never raises;
 - ``span()`` puts a ``jax.profiler.TraceAnnotation`` beside its event;
-- ``serving_prefill_chunks`` is registered and incremented, a paged decode
+- ``serving_prefill_chunks`` is registered and incremented, a decode
   tick counts its live and its tabled blocks, and graftlint GL005/GL006
   stay clean.
 """
@@ -60,7 +60,6 @@ def engine():
 
     def make(**kw):
         kw.setdefault("n_slots", 2)
-        kw.setdefault("paged", True)
         kw.setdefault("block_size", 8)
         kw.setdefault("prefill_chunk", 16)
         kw.setdefault("seed", 0)
@@ -90,8 +89,8 @@ def _traced_run(eng, lengths=(40, 9, 33), new=5):
 class TestTurnTree:
     @pytest.fixture(scope="class")
     def events(self):
-        eng = InferenceEngine(CFG, PARAMS, n_slots=2, paged=True,
-                              block_size=8, prefill_chunk=16, seed=0)
+        eng = InferenceEngine(CFG, PARAMS, n_slots=2, block_size=8,
+                              prefill_chunk=16, seed=0)
         try:
             eng.submit(_prompt(20, 9), max_new_tokens=2).result(timeout=120)
             evs, _, _ = _traced_run(eng)
@@ -155,13 +154,6 @@ class TestRequestChain:
             assert done["args"]["tokens"] == 5
             assert done["args"]["reason"] == "length"
 
-    def test_fixed_mode_chain_counts_one_quantum(self, engine):
-        events, reqs, _ = _traced_run(engine(paged=False), lengths=(12,))
-        first = [e for e in events
-                 if e["name"] == "serving.admit_to_first"]
-        assert len(first) == 1 and first[0]["args"]["chunks"] == 1
-        assert first[0]["args"]["rid"] == reqs[0].rid
-
     def test_traced_request_keeps_its_trace_ids_on_the_chain(self, engine):
         eng = engine()
         ctx = monitor.mint_trace()
@@ -201,13 +193,12 @@ class TestOffPath:
         engine().submit(_prompt(20, 1), max_new_tokens=4).result(timeout=120)
         assert len(writer) == 0
 
-    @pytest.mark.parametrize("paged", [True, False])
-    def test_greedy_tokens_identical_on_and_off(self, engine, paged):
+    def test_greedy_tokens_identical_on_and_off(self, engine):
         prompts = (40, 9, 33)
-        base = [engine(paged=paged).submit(
+        base = [engine().submit(
             _prompt(n, i), max_new_tokens=5).result(timeout=120)
             for i, n in enumerate(prompts)]
-        _, _, traced = _traced_run(engine(paged=paged), lengths=prompts)
+        _, _, traced = _traced_run(engine(), lengths=prompts)
         assert traced == base
 
     def test_off_path_builds_no_args(self, engine):
@@ -221,13 +212,10 @@ class TestChunkCounter:
         before = monitor.stat_get("serving_prefill_chunks")
         engine().submit(_prompt(40, 2), max_new_tokens=2).result(timeout=120)
         assert monitor.stat_get("serving_prefill_chunks") - before == 3
-        engine(paged=False).submit(
-            _prompt(12, 2), max_new_tokens=2).result(timeout=120)
-        assert monitor.stat_get("serving_prefill_chunks") - before == 4
 
     def test_decode_blocks_counted_on_the_span_and_in_the_registry(
             self, engine):
-        """Each paged decode tick says how many of its tabled blocks are
+        """Each decode tick says how many of its tabled blocks are
         live: the span's two arguments, the two counters by the same
         amounts, and the serving report's share."""
         names = ("serving_decode_blocks_live",
@@ -261,10 +249,6 @@ class TestChunkCounter:
                                      events=events)
         assert out["decode_blocks_live_share"] == pytest.approx(
             (after[0] - before[0]) / (after[1] - before[1]))
-        # the fixed-slot engine tables no blocks
-        events, _, _ = _traced_run(engine(paged=False), lengths=(12,))
-        assert all("decode_blocks_live" not in e["args"] for e in events
-                   if e["name"] == "serving.decode_step")
 
     def test_graftlint_gauges_clean(self):
         from paddle_tpu.analysis import run_lint

@@ -10,7 +10,7 @@ draft would fare. This tool produces that real draft on CPU in seconds:
     draft, info = distill_draft(cfg, params, steps=300)
     eng = InferenceEngine(cfg, params, draft=draft, spec_k=6)
 
-Recipe (short by design — the bench budget is seconds, not GPU-days):
+Recipe (short by design — seconds, not GPU-days):
 
 1. student = ``gpt_nano`` shape at the TARGET's hidden/vocab/seq_len
    (``n_layers`` defaults to 2), with wte/wpe/final-LN INITIALIZED from
@@ -25,9 +25,8 @@ Recipe (short by design — the bench budget is seconds, not GPU-days):
    state).
 
 Returns ``((draft_cfg, draft_params), info)`` where ``info`` carries
-the final KL and the held-out argmax-agreement rate — the number the
-``serving_spec`` bench reports as the distilled draft's acceptance
-proxy.
+the final KL and the held-out argmax-agreement rate — a proxy for the
+distilled draft's acceptance.
 """
 from __future__ import annotations
 

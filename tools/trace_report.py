@@ -2,7 +2,7 @@
 files (as written by paddle_tpu.profiler / monitor.trace.TraceWriter /
 the crash flight recorder, or any chrome://tracing export) and print the
 hot-span table plus every section report the events support — so CI and
-bench rounds can diff hot paths without TensorBoard.
+chip runs can diff hot paths without TensorBoard.
 
     python -m tools.trace_report trace.json [more.json ...]
         [--top 20] [--json] [--section NAME]
@@ -370,13 +370,14 @@ def _prefill_starvation(events: list) -> dict:
 def serving_report(rows: list, file=None, events: list | None = None) -> dict:
     """Prefill-vs-decode verdict from the serving spans (ISSUE 4/7).
 
-    The serving engine emits ``serving.prefill`` (one per whole-prompt
-    admission), ``serving.prefill_chunk`` (one per chunked-prefill tick
-    slice, paged mode) and ``serving.decode_step`` (one per batched
-    decode tick) spans. Their split answers the first question about a
-    slow serving trace: is admission or steady-state decode eating the
-    time budget? When raw ``events`` are passed, paged runs also get a
-    PREFILL STARVATION verdict — the max consecutive ticks any open
+    The serving engine emits ``serving.prefill_chunk`` (one per
+    chunked-prefill tick slice) and ``serving.decode_step`` (one per
+    batched decode tick) spans; a trace file from before PR 32 may also
+    hold ``serving.prefill`` (one per whole-prompt admission of the
+    fixed-slot engine) and is still read. Their split answers the first
+    question about a slow serving trace: is admission or steady-state
+    decode eating the time budget? When raw ``events`` are passed, the
+    run also gets a PREFILL STARVATION verdict — the max consecutive ticks any open
     stream waited behind chunked prefill work — and the share of the
     decode ticks' tabled blocks that were live (``decode_blocks_live``
     over ``decode_blocks_tabled`` of the ``serving.decode_step`` spans)."""
@@ -396,9 +397,8 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
         out["prefill_frac"] = pre_us / total
         out["verdict"] = (
             "prefill-bound: prompt prefills stall the decode batch for a "
-            "significant share of engine time — bucket prompts tighter, "
-            "admit fewer requests per tick, or chunk long prefills "
-            "(FLAGS_paged_kv=1 + prefill_chunk)"
+            "significant share of engine time — admit fewer requests per "
+            "tick or shrink the chunks (InferenceEngine(prefill_chunk=))"
             if pre_us > 0.5 * total else
             "decode-bound: steady-state batched decode dominates — "
             "throughput scales with slot occupancy; raise n_slots or "
@@ -1307,7 +1307,7 @@ def report(rows: list, top: int = 20, file=None) -> list:
 # the one CLI's section registry (ISSUE 15 satellite): name ->
 # callable(ctx, file) -> result. ``ctx`` carries events/rows/top/flights
 # so each section keeps its historical function signature for direct
-# callers (tests, bench) while the CLI drives them uniformly.
+# callers (tests) while the CLI drives them uniformly.
 SECTIONS = {
     "spans": lambda c, f: report(c["rows"], c["top"], file=f),
     "input_pipeline": lambda c, f: input_pipeline_report(c["rows"], file=f),
