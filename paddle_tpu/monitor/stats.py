@@ -298,6 +298,10 @@ DEFAULT_STATS = (
     "serving_prefill_chunks",  # prefill chunks dispatched
     "serving_decode_blocks_live",    # active slots' table entries, a tick
     "serving_decode_blocks_tabled",  # n_slots x table width, the same ticks
+    # decode ticks by the path their sampling takes (serving/sampling.py):
+    "serving_sample_ticks_greedy",   # no row samples: argmax
+    "serving_sample_ticks_select",   # samples, no sort of the vocabulary
+    "serving_sample_ticks_sort",     # the rows' parameters force the one sort
     # paged KV cache (ISSUE 7)
     "kv_blocks_free",          # gauge: pool blocks on the free list
     "kv_blocks_used",          # gauge: pool blocks owned by live slots
@@ -424,6 +428,11 @@ SERVING_PREFILL_CHUNKS = _registry.get_stat("serving_prefill_chunks")
 SERVING_DECODE_BLOCKS_LIVE = _registry.get_stat("serving_decode_blocks_live")
 SERVING_DECODE_BLOCKS_TABLED = _registry.get_stat(
     "serving_decode_blocks_tabled")
+SERVING_SAMPLE_TICKS_GREEDY = _registry.get_stat(
+    "serving_sample_ticks_greedy")
+SERVING_SAMPLE_TICKS_SELECT = _registry.get_stat(
+    "serving_sample_ticks_select")
+SERVING_SAMPLE_TICKS_SORT = _registry.get_stat("serving_sample_ticks_sort")
 KV_BLOCKS_FREE = _registry.get_stat("kv_blocks_free")
 KV_BLOCKS_USED = _registry.get_stat("kv_blocks_used")
 KV_FRAGMENTATION = _registry.get_stat("kv_fragmentation")

@@ -171,7 +171,10 @@ Span args are built only under ``recording()``; ``serving_prefill_chunks``
 counts prefill chunks, always. ``serving.decode_step``
 carries ``decode_blocks_live`` (the active slots' table entries) and
 ``decode_blocks_tabled`` (``n_slots`` x the tick's table width), which
-``serving_decode_blocks_live`` / ``_tabled`` sum. The jitted programs carry
+``serving_decode_blocks_live`` / ``_tabled`` sum, and ``sample_path``
+(``greedy`` / ``select`` / ``sort``: which way the tick's sampling goes,
+by its rows' parameters; ``serving_sample_ticks_<path>`` count the ticks
+of each). The jitted programs carry
 ``jax.named_scope``s (``kv_pool``, ``sampling``, and the model's
 ``embed`` / ``ln`` / ``attn`` / ``mlp`` / ``head``) for xprof; the engine
 registers no ``on_stop`` table: it keeps serving while another thread
@@ -204,6 +207,9 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              SERVING_PREFILL_CHUNK_MS, SERVING_PREFILL_CHUNKS,
                              SERVING_PREFILL_MS,
                              SERVING_QUEUE_DEPTH, SERVING_QUEUE_WAIT_MS,
+                             SERVING_SAMPLE_TICKS_GREEDY,
+                             SERVING_SAMPLE_TICKS_SELECT,
+                             SERVING_SAMPLE_TICKS_SORT,
                              SERVING_SHARDS, SERVING_SLOT_OCCUPANCY,
                              SERVING_TOKENS_PER_S,
                              SERVING_WATCHDOG_RESTARTS,
@@ -216,8 +222,8 @@ from ..monitor.flight import arm_flight_recorder, dump_flight
 from ..monitor.trace import emit_complete, emit_flow, recording, span
 from .kv_cache import KVCache, PagedKVCache
 from .prefix_cache import RadixPrefixCache
-from .sampling import (DRAFT_SALT, sample_tokens, sample_tokens_streams,
-                       spec_accept, stream_keys)
+from .sampling import (DRAFT_SALT, sample_one, sample_path,
+                       sample_tokens_streams, spec_accept, stream_keys)
 
 __all__ = ["InferenceEngine", "GenerationRequest", "QueueFull",
            "WatchdogTripped", "ReplicaEvacuated"]
@@ -1978,12 +1984,10 @@ class InferenceEngine:
         # the chunk (and whatever was queued ahead of it) to finish
         with span("serving.first_token", cat="serving",
                   args=self._tick_args(rid=st.req.rid, slot=slot)):
-            tok = int(sample_tokens(
+            tok = sample_one(
                 logits[0:1, c_true - 1], self._stream_key(st.req.rid, 0),
-                jnp.float32(st.req.temperature)[None],
-                jnp.int32(st.req.top_k)[None],
-                jnp.float32(st.req.top_p)[None],
-                mask=jnp.asarray(self._mask_row(st.req)))[0])
+                st.req.temperature, st.req.top_k, st.req.top_p,
+                mask=jnp.asarray(self._mask_row(st.req)))
             st.last_token = tok
             st.generated = 1
             self._note_moe_pending()
@@ -2146,7 +2150,17 @@ class InferenceEngine:
             else:
                 mask_arg = self._mask_dev
 
-        span_args = {"batch": len(active), "tick": self._ticks}
+        # which way the tick's sampling goes, from the rows' parameters
+        # (a tie that overflows the candidates sorts unseen from here)
+        path = sample_path(temps, top_ks, top_ps)
+        if path == "greedy":
+            SERVING_SAMPLE_TICKS_GREEDY.add(1)
+        elif path == "select":
+            SERVING_SAMPLE_TICKS_SELECT.add(1)
+        else:
+            SERVING_SAMPLE_TICKS_SORT.add(1)
+        span_args = {"batch": len(active), "tick": self._ticks,
+                     "sample_path": path}
         if self.replica_id is not None:
             span_args["replica"] = self.replica_id
         if self._shards > 1:

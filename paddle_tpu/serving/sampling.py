@@ -31,16 +31,27 @@ engine feeds masks from per-request token-mask automata
 (serving.constrained); ``mask=None`` (and an all-True mask) leave every
 path bit-identical to the unmasked code.
 
-Everything here is pure jnp: the engine's eager first-token sample and
-its jitted ticks run the SAME code.
+Selection, not sorting (ISSUE 33): the filter chain finds its two
+cut-offs (the k-th value, the nucleus's least kept value) among a row's
+``K_CAP`` largest entries whenever every row that filters has
+``0 < top_k <= K_CAP``, and sorts the vocabulary, once, only for
+parameters that force it (top_p alone, a larger top_k, ties at the k-th
+value that overflow the candidates). The kept set is the same either
+way; only rows that sample AND enable a filter are filtered at all.
+
+Everything here is pure jnp: the engine's eager first-token sample
+(:func:`sample_one`, which picks its path on the host) and its jitted
+ticks run the SAME code.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["sample_tokens", "sample_tokens_streams", "stream_keys",
-           "spec_accept", "MASKED_LOGIT"]
+__all__ = ["sample_tokens", "sample_tokens_streams", "sample_one",
+           "sample_path", "stream_keys", "spec_accept", "MASKED_LOGIT",
+           "K_CAP"]
 
 # suppression value for masked-out vocabulary entries: finite (softmax
 # over an all-masked row stays NaN-free long enough to be caught
@@ -57,6 +68,62 @@ def _apply_mask(logits, mask):
     return jnp.where(mask, logits, jnp.float32(MASKED_LOGIT))
 
 
+# the bounded path's candidate count: a row whose top_k is at most this
+# finds both cut-offs among its K_CAP largest entries. Chosen by timing
+# the filter alone on the chip (PERF.md §6, PR 33)
+K_CAP = 64
+
+
+def _scale(logits, temperature):
+    return logits / jnp.maximum(temperature, 1e-6)[:, None]
+
+
+def _nucleus_cutoff(desc, kth, top_p):
+    """The least value both filters keep: ``desc`` (B, N) holds a row's
+    largest entries in descending order and ``kth`` (B, 1) its k-th
+    value. Of the entries >= kth the smallest prefix whose mass reaches
+    top_p survives (the top token always); never below kth, which a
+    nucleus that keeps every survivor would otherwise let through."""
+    desc = jnp.where(desc >= kth, desc, -jnp.inf)
+    probs = jax.nn.softmax(desc, axis=-1)
+    exclusive_cum = jnp.cumsum(probs, axis=-1) - probs
+    keep = exclusive_cum < top_p[:, None]
+    return jnp.maximum(kth, jnp.min(jnp.where(keep, desc, jnp.inf),
+                                    axis=-1, keepdims=True))
+
+
+def _sort_cutoff(scaled, top_k, top_p):
+    """The one-sort path: any top_k, any top_p. One descending sort gives
+    the k-th value, and with its tail set to -inf it IS the k-filtered
+    row sorted, which the nucleus needs. Values alone are sorted, so
+    stability buys nothing; on the chip a stable sort carries an index
+    along and takes 2.2 times as long (PERF.md §6, PR 33)."""
+    V = scaled.shape[-1]
+    k_eff = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
+    desc = -jnp.sort(-scaled, axis=-1, stable=False)
+    kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)
+    return _nucleus_cutoff(desc, kth, top_p)
+
+
+def _select_cutoff(scaled, top_k, top_p):
+    """The bounded path, for rows with 0 < top_k <= K_CAP: the same two
+    cut-offs found among the row's K_CAP largest entries, no sort of the
+    vocabulary. Returns ``(cutoff (B, 1), fits (B,))``; a row fits when
+    every value >= its k-th is among the candidates (ties at the k-th
+    can overflow them, and the nucleus would then lose mass): only a
+    row that fits may use its cutoff."""
+    n = min(K_CAP, scaled.shape[-1])
+    cand = jax.lax.top_k(scaled, n)[0]                    # descending
+    kth = jnp.take_along_axis(
+        cand, (jnp.clip(top_k, 1, n) - 1)[:, None], axis=-1)
+    fits = jnp.sum(scaled >= kth, axis=-1) <= n
+    return _nucleus_cutoff(cand, kth, top_p), fits
+
+
+def _keep(scaled, cutoff):
+    return jnp.where(scaled >= cutoff, scaled, -jnp.inf)
+
+
 def _filter_logits(logits, temperature, top_k, top_p):
     """Temperature scale → top-k → top-p (nucleus, on the k-filtered
     distribution); logits (B, V) fp32, per-row params. Returns filtered
@@ -64,42 +131,45 @@ def _filter_logits(logits, temperature, top_k, top_p):
     order — shared by the sampling draw AND the speculative
     accept/residual math so both see the same distribution.
 
-    Pure unconditional math — safe to call eagerly (``lax.cond`` in
-    eager mode re-traces and re-compiles per call, a ~0.3s stall each
-    time; see :func:`_filter_logits_cond` for the jit-context variant
-    that skips the sorts when no row enables the filters)."""
-    V = logits.shape[-1]
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-
-    # top-k with per-row k: keep values >= the k-th largest
-    k_eff = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    kth = jnp.take_along_axis(sorted_desc, (k_eff - 1)[:, None], axis=-1)
-    scaled = jnp.where(scaled >= kth, scaled, -jnp.inf)
-
-    # top-p: keep the smallest prefix of the sorted distribution whose
-    # mass reaches top_p (the top token always survives)
-    sorted_desc = -jnp.sort(-scaled, axis=-1)
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    exclusive_cum = jnp.cumsum(probs, axis=-1) - probs
-    keep = exclusive_cum < top_p[:, None]
-    cutoff = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1,
-                     keepdims=True)
-    return jnp.where(scaled >= cutoff, scaled, -jnp.inf)
+    Pure unconditional math, every row through the one-sort path — safe
+    to call eagerly (``lax.cond`` in eager mode re-traces and
+    re-compiles per call, a ~0.3s stall each time; see
+    :func:`_filter_logits_cond` for the jit-context variant that picks a
+    path from the rows' parameters)."""
+    scaled = _scale(logits, temperature)
+    return _keep(scaled, _sort_cutoff(scaled, top_k, top_p))
 
 
 def _filter_logits_cond(logits, temperature, top_k, top_p):
-    """JIT-CONTEXT filter: the sort-based k/p filters only RUN when some
-    row enables them (with every top_k <= 0 and top_p >= 1 they are
-    mathematically the identity, and two (B, V) sorts per draw is real
-    money on a CPU host). Only call from inside a jitted program —
-    eager ``lax.cond`` re-compiles per call."""
-    need = jnp.any(top_k > 0) | jnp.any(top_p < 1.0)
-    return jax.lax.cond(
-        need,
-        lambda lg: _filter_logits(lg, temperature, top_k, top_p),
-        lambda lg: lg / jnp.maximum(temperature, 1e-6)[:, None],
-        logits)
+    """JIT-CONTEXT filter: the work follows the rows' own parameters.
+    Only rows that sample (``temperature > 0``) with a filter enabled
+    are filtered; greedy rows (never read: callers argmax the raw
+    logits) and unfiltered rows come back scaled. With no row to filter
+    nothing else runs; with every such row at ``0 < top_k <= K_CAP`` the
+    cut-offs come from :func:`_select_cutoff`; any other parameters, or
+    ties that overflow the candidates, take :func:`_sort_cutoff`. Same
+    kept set either way, up to the nucleus's summation order. Only call
+    from inside a jitted program — eager ``lax.cond`` re-compiles per
+    call."""
+    scaled = _scale(logits, temperature)
+    filt = (temperature > 0.0) & ((top_k > 0) | (top_p < 1.0))
+
+    def cut(cutoff):
+        return _keep(scaled, jnp.where(filt[:, None], cutoff, -jnp.inf))
+
+    def sort_path(_):
+        return cut(_sort_cutoff(scaled, top_k, top_p))
+
+    def select_path(_):
+        cutoff, fits = _select_cutoff(scaled, top_k, top_p)
+        return jax.lax.cond(jnp.all(fits | ~filt),
+                            lambda _: cut(cutoff), sort_path, None)
+
+    def filtered(_):
+        bounded = jnp.all(~filt | ((top_k > 0) & (top_k <= K_CAP)))
+        return jax.lax.cond(bounded, select_path, sort_path, None)
+
+    return jax.lax.cond(jnp.any(filt), filtered, lambda _: scaled, None)
 
 
 def _finish(logits, scaled, gumbel, temperature):
@@ -123,6 +193,50 @@ def sample_tokens(logits, key, temperature, top_k, top_p, mask=None):
     scaled = _filter_logits(logits, temperature, top_k, top_p)
     gumbel = jax.random.gumbel(key, logits.shape, jnp.float32)
     return _finish(logits, scaled, gumbel, temperature)
+
+
+def sample_path(temperature, top_k, top_p):
+    """Which path a batch's sampling takes, from its parameters on the
+    HOST (numpy arrays or Python numbers): ``"greedy"`` (no row
+    samples), ``"select"`` (every row that filters has
+    ``0 < top_k <= K_CAP``; also a batch that samples with no filter)
+    or ``"sort"``. Mirrors :func:`_filter_logits_cond`'s predicates;
+    it cannot see a tie that overflows the candidates, which sorts."""
+    temperature, top_k, top_p = (np.asarray(temperature),
+                                 np.asarray(top_k), np.asarray(top_p))
+    samples = temperature > 0.0
+    if not samples.any():
+        return "greedy"
+    filt = samples & ((top_k > 0) | (top_p < 1.0))
+    bounded = (top_k > 0) & (top_k <= K_CAP)
+    return "select" if (~filt | bounded).all() else "sort"
+
+
+def sample_one(logits, key, temperature, top_k, top_p, mask=None):
+    """logits (1, V) → one token id (a Python int), EAGERLY: the row's
+    parameters are Python numbers, so the path is picked here on the
+    host (an eager ``lax.cond`` would re-compile per call). A greedy
+    row is an argmax: no sort, no Gumbel draw. The same math, key and
+    draw as a one-row :func:`sample_tokens`."""
+    logits = _apply_mask(logits.astype(jnp.float32), mask)
+    if temperature <= 0.0:
+        return int(jnp.argmax(logits, axis=-1)[0])
+    k, p = jnp.int32(top_k)[None], jnp.float32(top_p)[None]
+    scaled = _scale(logits, jnp.float32(temperature)[None])
+    gumbel = jax.random.gumbel(key, logits.shape, jnp.float32)
+
+    def draw(filtered):
+        return jnp.argmax(filtered + gumbel, axis=-1)[0]
+
+    if top_k <= 0 and top_p >= 1.0:
+        return int(draw(scaled))
+    if 0 < top_k <= K_CAP:
+        cutoff, fits = _select_cutoff(scaled, k, p)
+        # one wait for the token and for whether it may be used
+        tok, fits = jax.device_get((draw(_keep(scaled, cutoff)), fits))
+        if fits[0]:
+            return int(tok)
+    return int(draw(_keep(scaled, _sort_cutoff(scaled, k, p))))
 
 
 def stream_keys(base_key, req_ids, draws):

@@ -72,11 +72,14 @@ def engine():
         eng.shutdown(drain=False, timeout=30)
 
 
-def _traced_run(eng, lengths=(40, 9, 33), new=5):
-    """Run a few requests under tracing; returns the serving events."""
+def _traced_run(eng, lengths=(40, 9, 33), new=5, sampling=()):
+    """Run a few requests under tracing; returns the serving events.
+    ``sampling``: submit arguments for the first requests (the rest are
+    greedy)."""
     writer = monitor.start_tracing()
     try:
-        reqs = [eng.submit(_prompt(n, i), max_new_tokens=new)
+        reqs = [eng.submit(_prompt(n, i), max_new_tokens=new,
+                           **(sampling[i] if i < len(sampling) else {}))
                 for i, n in enumerate(lengths)]
         toks = [r.result(timeout=120) for r in reqs]
         time.sleep(0.05)    # let the scheduler close the last turn's span
@@ -84,6 +87,17 @@ def _traced_run(eng, lengths=(40, 9, 33), new=5):
         monitor.stop_tracing()
     return [e for e in writer.events()
             if e.get("name", "").startswith("serving.")], reqs, toks
+
+
+def _serving_report(events):
+    """``tools/trace_report.py --section serving`` over ``events``."""
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(_ROOT, "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with open(os.devnull, "w") as sink:
+        return mod.serving_report(mod.aggregate(events), file=sink,
+                                  events=events)
 
 
 class TestTurnTree:
@@ -240,15 +254,42 @@ class TestChunkCounter:
             a["decode_blocks_live"] for a in ticks)
         assert after[1] - before[1] == sum(
             a["decode_blocks_tabled"] for a in ticks)
-        spec = importlib.util.spec_from_file_location(
-            "trace_report", os.path.join(_ROOT, "tools", "trace_report.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        with open(os.devnull, "w") as sink:
-            out = mod.serving_report(mod.aggregate(events), file=sink,
-                                     events=events)
+        out = _serving_report(events)
         assert out["decode_blocks_live_share"] == pytest.approx(
             (after[0] - before[0]) / (after[1] - before[1]))
+
+    @pytest.mark.parametrize("sampling, paths", [
+        ((), {"greedy"}),
+        (({"temperature": 0.8, "top_k": 40, "top_p": 0.95},),
+         {"greedy", "select"}),
+        (({"temperature": 0.8, "top_p": 0.9}, {"temperature": 0.8,
+                                               "top_k": 40}),
+         {"greedy", "select", "sort"}),
+    ])
+    def test_sample_paths_counted_on_the_span_and_in_the_registry(
+            self, engine, sampling, paths):
+        """Each decode tick says which way its sampling goes (greedy,
+        select or sort, by its rows' parameters): the span's argument,
+        one of three counters, and the serving report's shares. The
+        sampled requests are the short ones: once they leave, the tail
+        of the run is greedy again."""
+        order = ("greedy", "select", "sort")
+        names = [f"serving_sample_ticks_{p}" for p in order]
+        assert set(names) <= set(monitor.DEFAULT_STATS)
+        before = [monitor.stat_get(n) for n in names]
+        events, _, _ = _traced_run(engine(n_slots=4), lengths=(9, 12, 40),
+                                   new=6, sampling=sampling)
+        ticks = [e["args"]["sample_path"] for e in events
+                 if e["name"] == "serving.decode_step"]
+        counted = [monitor.stat_get(n) - b for n, b in zip(names, before)]
+        assert counted == [ticks.count(p) for p in order]
+        assert sum(counted) == len(ticks) > 0
+        assert set(ticks) <= paths
+        assert max(paths, key=order.index) in ticks
+        out = _serving_report(events)
+        for p, n in zip(order, counted):
+            assert out[f"sample_ticks_{p}"] == n
+            assert out[f"sample_{p}_share"] == pytest.approx(n / len(ticks))
 
     def test_graftlint_gauges_clean(self):
         from paddle_tpu.analysis import run_lint

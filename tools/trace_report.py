@@ -380,7 +380,11 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
     run also gets a PREFILL STARVATION verdict — the max consecutive ticks any open
     stream waited behind chunked prefill work — and the share of the
     decode ticks' tabled blocks that were live (``decode_blocks_live``
-    over ``decode_blocks_tabled`` of the ``serving.decode_step`` spans)."""
+    over ``decode_blocks_tabled`` of the ``serving.decode_step`` spans)
+    and the share of the ticks whose sampling went each way (the spans'
+    ``sample_path``: ``greedy`` argmax, ``select`` among a row's largest
+    entries, ``sort`` of the vocabulary; the engine counts the same
+    ticks in ``serving_sample_ticks_<path>``)."""
     pre = [r for r in rows if r["name"] == "serving.prefill"]
     chk = [r for r in rows if r["name"] == "serving.prefill_chunk"]
     dec = [r for r in rows if r["name"] == "serving.decode_step"]
@@ -413,6 +417,10 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
             live = sum(int(a.get("decode_blocks_live", 0)) for a in ticks)
             out.update(decode_blocks_live=live, decode_blocks_tabled=tabled,
                        decode_blocks_live_share=live / tabled)
+        paths = [a["sample_path"] for a in ticks if "sample_path" in a]
+        for path in ("greedy", "select", "sort") if paths else ():
+            out[f"sample_ticks_{path}"] = paths.count(path)
+            out[f"sample_{path}_share"] = paths.count(path) / len(paths)
         starve = _prefill_starvation(events)
         if starve:
             out.update(starve)
