@@ -422,6 +422,7 @@ def phase_kernels():
     ma = importlib.import_module("paddle_tpu.ops.mla_attention")
     md = importlib.import_module("paddle_tpu.ops.moe_dispatch")
     pa = importlib.import_module("paddle_tpu.ops.paged_attention")
+    pr = importlib.import_module("paddle_tpu.ops.power_retention")
     rf = importlib.import_module("paddle_tpu.parallel.ring_flash")
     from paddle_tpu.parallel.mesh import AXES
 
@@ -531,6 +532,38 @@ def phase_kernels():
         args=(normal((32, 64, 512)), normal((32, 64, 64)),
               normal((513, 2, 64, 640)), tables, lengths, jnp.int32(1)),
         tol=TOL_BF16)
+
+    # -- power retention at brumby_14b's heads: 40 query heads over 8 of
+    # 128, states of (136, 9216) float32; 4 lanes of which one is dead
+    # (its table names the sink), a 256-token chunk with a padded tail --
+    def ret_case(T, seeded=64):
+        def draw(T):
+            return (normal((T, 40, 128), scale=0.3),
+                    normal((T, 8, 128), scale=0.3), normal((T, 8, 128)),
+                    -jnp.abs(normal((T, 8), f32, 0.5)))
+        # states that a recurrence left (a normaliser is a sum of
+        # squares), one a block, by the composed path
+        pool = jnp.zeros((5, 2, 8, 136, 9216), f32)
+        for blk in range(1, 5):
+            _, pool = pr.retention_chunk(*draw(seeded), pool, blk, 1, 0,
+                                         seeded, 1e-6, composed=True)
+        return draw(T) + (pool,)
+
+    lanes = jnp.asarray([3, 0, 1, 4], jnp.int32)
+    run("power_retention_decode.cell.dead_lane",
+        fn=lambda q, k, v, lg, pool: pr.retention_decode(
+            q, k, v, lg, pool, lanes, lanes > 0, 1, 1e-6),
+        ref_fn=lambda q, k, v, lg, pool: pr.retention_decode(
+            q, k, v, lg, pool, lanes, lanes > 0, 1, 1e-6, composed=True),
+        args=ret_case(4), tol=1e-4)
+    for tag, start, n_true in (("first", 0, 256), ("padded_tail", 512, 77)):
+        run("power_retention_chunk.c256." + tag,
+            fn=lambda q, k, v, lg, pool, start=start, n=n_true:
+            pr.retention_chunk(q, k, v, lg, pool, 2, 1, start, n, 1e-6),
+            ref_fn=lambda q, k, v, lg, pool, start=start, n=n_true:
+            pr.retention_chunk(q, k, v, lg, pool, 2, 1, start, n, 1e-6,
+                               composed=True),
+            args=ret_case(256), tol=TOL_BF16)
 
     # -- fused LN+MLP and add+LN (bert_base block shapes) -------------------
     H, M = 768, 3072

@@ -36,6 +36,16 @@ from .mla import (
     mla_tiny,
     sarvam_105b,
 )
+from .retention import (
+    RetentionConfig,
+    brumby_14b,
+    retention_decode_step_paged,
+    retention_forward,
+    retention_init,
+    retention_param_specs,
+    retention_prefill_chunk,
+    retention_tiny,
+)
 from .serving_api import ServingModel
 from .dlrm import (
     DLRMConfig,
@@ -58,6 +68,9 @@ __all__ = [
     "gpt_tiny", "gpt_small", "gpt_1p3b", "gpt_nano", "bert_base_config",
     "MLAConfig", "mla_init", "mla_forward", "mla_prefill_chunk",
     "mla_decode_step_paged", "mla_param_specs", "mla_tiny", "sarvam_105b",
+    "RetentionConfig", "brumby_14b", "retention_init", "retention_forward",
+    "retention_prefill_chunk", "retention_decode_step_paged",
+    "retention_param_specs", "retention_tiny",
     "ServingModel",
     "DLRMConfig", "dlrm_init", "dlrm_forward", "dlrm_forward_from_emb",
     "dlrm_loss", "dlrm_loss_from_emb", "dlrm_param_specs", "dlrm_score_fn",

@@ -1291,8 +1291,10 @@ def _block_chunk(cfg: GPTConfig, p, x, kb, vb, li, table_row, start):
 
 
 def gpt_prefill_chunk(cfg: GPTConfig, params, pool, table_row, tokens,
-                      start):
-    """One chunk of a paged, chunked prefill.
+                      start, n_true=None):
+    """One chunk of a paged, chunked prefill. ``n_true`` (how many of
+    the chunk's tokens are real) is the step contract's and unused here:
+    rows past a slot's length are never read from a paged pool.
 
     tokens (1, C) int32 — the next C prompt tokens, end-padded to a
     multiple of block_size (one compile per padded chunk length); start

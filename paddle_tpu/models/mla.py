@@ -500,9 +500,10 @@ def _pool_put(pool, update, at):
 
 
 def mla_prefill_chunk(cfg: MLAConfig, params, pool, table_row, tokens,
-                      start):
+                      start, n_true=None):
     """One chunk of a paged, chunked prefill (the contract of
-    ``gpt_prefill_chunk``): tokens (1, C) end-padded to whole blocks,
+    ``gpt_prefill_chunk``): tokens (1, C) end-padded to whole blocks
+    (``n_true`` of them real: unused, a padded row is never read),
     ``start`` block-aligned, table_row (W,) covering ``start + C``.
     Writes the chunk's rows into the pool, then attends over every
     cached row. -> (logits (1, C, V) f32, pool, router stats)."""

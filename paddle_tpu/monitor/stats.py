@@ -298,6 +298,7 @@ DEFAULT_STATS = (
     "serving_prefill_chunks",  # prefill chunks dispatched
     "serving_decode_blocks_live",    # active slots' table entries, a tick
     "serving_decode_blocks_tabled",  # n_slots x table width, the same ticks
+    "serving_state_slots_live",      # lanes whose recurrent state a tick moved
     # decode ticks by the path their sampling takes (serving/sampling.py):
     "serving_sample_ticks_greedy",   # no row samples: argmax
     "serving_sample_ticks_select",   # samples, no sort of the vocabulary
@@ -428,6 +429,7 @@ SERVING_PREFILL_CHUNKS = _registry.get_stat("serving_prefill_chunks")
 SERVING_DECODE_BLOCKS_LIVE = _registry.get_stat("serving_decode_blocks_live")
 SERVING_DECODE_BLOCKS_TABLED = _registry.get_stat(
     "serving_decode_blocks_tabled")
+SERVING_STATE_SLOTS_LIVE = _registry.get_stat("serving_state_slots_live")
 SERVING_SAMPLE_TICKS_GREEDY = _registry.get_stat(
     "serving_sample_ticks_greedy")
 SERVING_SAMPLE_TICKS_SELECT = _registry.get_stat(

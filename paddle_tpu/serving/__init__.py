@@ -20,7 +20,11 @@ Layers, bottom up:
   prefix cache's contract), with ``kv_blocks_free`` / ``kv_blocks_used``
   / ``kv_fragmentation`` gauges and loud ``AssertionError`` on
   refcount/free-list corruption. ``shards=D`` (multi-chip) partitions
-  the pool into per-shard block ranges. :class:`KVCache`, fixed-slot
+  the pool into per-shard block ranges. The pool's layout and what a
+  block is come from the model (``models/serving_api.py``): for a model
+  whose layers keep a recurrent state (``models/retention.py``) a block
+  is one sequence's whole state, a slot owns one, and admission is by
+  free states. :class:`KVCache`, fixed-slot
   donated buffers ``(slots, layers, heads, max_len, head_dim)``, is the
   speculative DRAFT model's private cache and nothing else;
 - :mod:`prefix_cache` — :class:`~prefix_cache.RadixPrefixCache`

@@ -28,7 +28,11 @@ surface in steady state: a decode step at the fixed ``(n_slots,)`` batch
 shape, one per block-table width bucket (``_decode_paged_fn``), and a
 prefill chunk per block-padded chunk length and table width
 (``_chunk_fn``). The pool's arrays are donated through both, so serving
-allocates nothing per token.
+allocates nothing per token. What a block of the pool is the model
+says: a run of ``block_size`` tokens' cached rows, or (a model whose
+layers keep a recurrent state) one sequence's whole state, of which a
+slot owns exactly one: then there is one decode program, a chunk
+program per padded chunk length, and admission is by free states.
 
 Admission is by block capacity: a prompt up to ``cfg.seq_len - 1``
 tokens is admitted whenever enough free blocks exist, and otherwise
@@ -200,6 +204,7 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              MOE_EXPERT_SHARE_PCT, MOE_TOKENS_DROPPED,
                              PREFIX_COW_COPIES, SERVING_DEADLINE_SHEDS,
                              SERVING_DECODE_BLOCKS_LIVE,
+                             SERVING_STATE_SLOTS_LIVE,
                              SERVING_DECODE_BLOCKS_TABLED,
                              SERVING_DECODE_MS, SERVING_DECODE_TICK_MS,
                              SERVING_EVICTIONS, SERVING_FIRST_TOKEN_MS,
@@ -500,7 +505,16 @@ class InferenceEngine:
     at ``prefill_chunk`` tokens per tick and interleaved with decode,
     and the Pallas paged-attention kernel on TPU. ``block_size`` tokens
     per pool block; ``n_blocks`` defaults to worst-case (every slot at
-    seq_len) — size it smaller to actually overcommit. ``paged`` selects
+    seq_len) — size it smaller to actually overcommit. What a block IS
+    the model says (``ServingModel.state_pad``): for a model whose
+    layers keep a recurrent state (``models/retention.py``) a block is
+    one sequence's whole state, a slot owns exactly one however long it
+    runs, ``n_blocks`` is the states the pool holds (one is the sink),
+    admission is by free states, no tick can run out of blocks, and
+    ``block_size`` is replaced by the model's own pad granule for
+    prefill chunks. The chunk program is told how many of a padded
+    chunk's tokens are real, and a slot's first chunk starts from a
+    zero state (on resume after a preemption too). ``paged`` selects
     nothing: ``None`` and ``True`` build this engine, ``False`` raises.
 
     ``draft=(draft_cfg, draft_params)`` enables speculative decoding:
@@ -934,11 +948,13 @@ class InferenceEngine:
         # one prefill chunk: writes the chunk's rows into the pool,
         # returns the chunk logits (only the final chunk's last live row
         # is read); args: the pool's arrays, then (table_row, tokens,
-        # start); a routed model's router stats ride last
-        pool, (table_row, tokens, start) = \
+        # start, n_true: how many of the padded chunk's tokens are real,
+        # which a model whose cache is a recurrent state must know); a
+        # routed model's router stats ride last
+        pool, (table_row, tokens, start, n_true) = \
             args[:self._n_pool], args[self._n_pool:]
         got = self._model.prefill_chunk(
-            self.cfg, params, pool, table_row, tokens, start)
+            self.cfg, params, pool, table_row, tokens, start, n_true)
         return (got[0],) + tuple(got[1]) + tuple(got[2:])
 
     def _chunk_spec_fn(self, params, dparams, kb, vb, dk, dv, table_row,
@@ -1950,7 +1966,8 @@ class InferenceEngine:
             else:
                 got = self._chunk_jit(
                     self._params, *self.cache.pool, jnp.asarray(row),
-                    jnp.asarray(toks), np.int32(st.length))
+                    jnp.asarray(toks), np.int32(st.length),
+                    np.int32(c_true))
                 logits = got[0]
                 self.cache.pool = tuple(got[1:1 + self._n_pool])
                 if self._model.routed:
@@ -2192,6 +2209,11 @@ class InferenceEngine:
                 span_args["decode_blocks_tabled"] = tabled
                 SERVING_DECODE_BLOCKS_LIVE.add(live)
                 SERVING_DECODE_BLOCKS_TABLED.add(tabled)
+                if self.cache.state_blocks:
+                    # each active lane's whole state is read, decayed
+                    # and written back by this tick, whatever its context
+                    span_args["state_slots_live"] = live
+                    SERVING_STATE_SLOTS_LIVE.add(live)
                 got = self._decode_paged_jit(
                     self._decode_params, *self.cache.pool, tables,
                     positions, tokens, self._base_key, rids, steps,

@@ -128,6 +128,23 @@ class PagedKVCache:
     block_size, row)``. The allocator, the tables and the gauges below
     know nothing of it.
 
+    WHAT A BLOCK IS the model says too (``ServingModel.state_pad``). For
+    the two above it is a run of ``block_size`` tokens, and the rest of
+    this text describes that. For a model whose layers keep a recurrent
+    state (``models/retention.py``) a block is ONE SEQUENCE'S WHOLE
+    STATE at every layer, ``(n_layers, ...)`` of a fixed size
+    (``state_blocks`` is then True): a slot's table has one entry,
+    ``blocks_for(n)`` is 1 for any ``n > 0``, admission needs a free
+    slot and a free state and never counts tokens (``alloc`` takes the
+    state with the slot), ``grow`` after it can not fail, the
+    ``kv_blocks_*`` gauges count states and ``kv_fragmentation`` reads
+    0. ``block_size`` is then the granule a
+    prefill chunk is padded to, the model's constant, whatever the
+    caller passed. Block 0 stays the sink: the table of a lane without
+    a request names it, and the model's decode step leaves such a lane
+    out. The refcount, copy-on-write and splice machinery below serves
+    the prefix cache, which such a model refuses.
+
     Block-major: one (block, layer) pair is one contiguous
     ``(n_heads, block_size, head_dim)`` run, which is what the paged
     steps (models/gpt.py) address — they write the new tokens' rows and
@@ -193,7 +210,11 @@ class PagedKVCache:
             raise ValueError(f"block_size={block_size} must be >= 1")
         self.cfg = cfg
         self.n_slots = int(n_slots)
-        self.block_size = int(block_size)
+        model = cfg.serving_model()
+        # a block is a sequence's whole state, not block_size tokens
+        self.state_blocks = model.state_pad is not None
+        self.block_size = int(model.state_pad if self.state_blocks
+                              else block_size)
         self.shards = int(shards)
         if self.shards < 1:
             raise ValueError(f"shards={shards} must be >= 1")
@@ -202,7 +223,8 @@ class PagedKVCache:
                              f"shards={shards}")
         # widest table any slot can need: the positional table is the
         # per-slot length ceiling
-        self.table_width = -(-cfg.seq_len // self.block_size)
+        self.table_width = 1 if self.state_blocks \
+            else -(-cfg.seq_len // self.block_size)
         if n_blocks is None:
             # worst case every slot runs to seq_len, +1 sink per shard
             n_blocks = self.shards + self.n_slots * self.table_width
@@ -219,8 +241,7 @@ class PagedKVCache:
         self.dtype = cfg.dtype if dtype is None else dtype
         self.pool = tuple(
             jnp.zeros(a.shape, a.dtype if dtype is None else dtype)
-            for a in cfg.serving_model().pool_spec(
-                cfg, self.n_blocks, self.block_size))
+            for a in model.pool_spec(cfg, self.n_blocks, self.block_size))
         self.lengths = np.zeros(self.n_slots, np.int32)
         self.block_tables: List[List[int]] = [[] for _ in range(self.n_slots)]
         # per-shard free lists; the first block of each range is the sink
@@ -252,19 +273,24 @@ class PagedKVCache:
 
     # -- slot accounting (same surface as KVCache) ---------------------------
     def alloc(self, prefer_shard: Optional[int] = None) -> Optional[int]:
-        if not self._slot_free:
-            return None
-        if prefer_shard is not None:
-            for i, s in enumerate(self._slot_free):
-                if self.shard_of(s) == prefer_shard:
-                    slot = self._slot_free.pop(i)
-                    break
-            else:
-                return None
+        """Claim a free slot (None when there is none). Where a block is
+        a sequence's state the slot takes its one state with it, so
+        admission reserves it: None too when the slot's shard has no
+        free state."""
+        for i, s in enumerate(self._slot_free):
+            shard = self.shard_of(s)
+            if prefer_shard is not None and shard != prefer_shard:
+                continue
+            if self.state_blocks and not self._free[shard]:
+                continue
+            slot = self._slot_free.pop(i)
+            break
         else:
-            slot = self._slot_free.pop(0)
+            return None
         self.lengths[slot] = 0
         self.block_tables[slot] = []
+        if self.state_blocks:
+            self.grow(slot, 1)
         return slot
 
     def release(self, slot: int) -> None:
@@ -285,6 +311,8 @@ class PagedKVCache:
 
     # -- block accounting ----------------------------------------------------
     def blocks_for(self, n_tokens: int) -> int:
+        if self.state_blocks:
+            return min(int(n_tokens), 1)
         return -(-int(n_tokens) // self.block_size)
 
     def can_admit(self, n_tokens: int) -> bool:
@@ -456,7 +484,8 @@ class PagedKVCache:
         used = self.used_blocks_count
         KV_BLOCKS_FREE.set(self.free_blocks_count)
         KV_BLOCKS_USED.set(used)
-        cap = used * self.block_size
+        # a state is whole whatever its sequence's length: nothing to fragment
+        cap = 0 if self.state_blocks else used * self.block_size
         live = int(self.lengths.sum())
         KV_FRAGMENTATION.set(
             0 if cap == 0 else int(round(100.0 * (1.0 - min(1.0, live / cap)))))

@@ -431,7 +431,7 @@ def test_a_gpt_engine_is_what_it_was():
             eng._mask_dev)
         chk = eng._chunk_jit.lower(
             eng._params, eng.cache.kb, eng.cache.vb, np.zeros(4, np.int32),
-            np.zeros((1, 16), np.int32), np.int32(0))
+            np.zeros((1, 16), np.int32), np.int32(0), np.int32(16))
         for low, name, n_out in ((dec, "jit__decode_paged_fn", 3),
                                  (chk, "jit__chunk_fn", 3)):
             text = low.as_text()

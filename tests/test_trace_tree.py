@@ -383,7 +383,7 @@ class TestOpScopes:
             low = eng._chunk_jit.lower(
                 eng._params, eng.cache.kb, eng.cache.vb,
                 np.zeros(4, np.int32), np.zeros((1, 16), np.int32),
-                np.int32(0))
+                np.int32(0), np.int32(16))
         labels = set(trace.op_scopes(low.compile().as_text()).values())
         assert "forward/" + scope in labels, labels
 
@@ -508,8 +508,12 @@ class TestAnnotation:
                     kw = {k.arg: k.value for k in node.keywords}
                     assert "name" in kw, (path, node.lineno)
                     names.append(kw["name"].value)
-        assert len(names) == 15 and len(set(names)) == 15
+        assert len(names) == 17 and len(set(names)) == 17
         for pattern, kernel in (("flash_forward", "flash_forward"),
                                 ("flash_backward", "flash_backward"),
-                                ("_paged_decode", "pallas_paged_decode")):
+                                ("_paged_decode", "pallas_paged_decode"),
+                                ("power_retention_decode",
+                                 "power_retention_decode"),
+                                ("power_retention_chunk",
+                                 "power_retention_chunk")):
             assert [n for n in names if re.search(pattern, n)] == [kernel]

@@ -380,8 +380,11 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
     run also gets a PREFILL STARVATION verdict — the max consecutive ticks any open
     stream waited behind chunked prefill work — and the share of the
     decode ticks' tabled blocks that were live (``decode_blocks_live``
-    over ``decode_blocks_tabled`` of the ``serving.decode_step`` spans)
-    and the share of the ticks whose sampling went each way (the spans'
+    over ``decode_blocks_tabled`` of the ``serving.decode_step`` spans),
+    for a model whose cache is a recurrent state the lanes whose state a
+    tick moved (``state_slots_live``: the tick's state traffic is that
+    times one state's bytes, twice), and the share of the ticks whose
+    sampling went each way (the spans'
     ``sample_path``: ``greedy`` argmax, ``select`` among a row's largest
     entries, ``sort`` of the vocabulary; the engine counts the same
     ticks in ``serving_sample_ticks_<path>``)."""
@@ -417,6 +420,11 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
             live = sum(int(a.get("decode_blocks_live", 0)) for a in ticks)
             out.update(decode_blocks_live=live, decode_blocks_tabled=tabled,
                        decode_blocks_live_share=live / tabled)
+        moved = [int(a["state_slots_live"]) for a in ticks
+                 if "state_slots_live" in a]
+        if moved:
+            out.update(state_slots_live=sum(moved),
+                       state_slots_live_a_tick=sum(moved) / len(moved))
         paths = [a["sample_path"] for a in ticks if "sample_path" in a]
         for path in ("greedy", "select", "sort") if paths else ():
             out[f"sample_ticks_{path}"] = paths.count(path)
