@@ -41,6 +41,7 @@ import time
 
 PHASES = ("train", "serve", "kernels", "train4")
 MOSAIC = "tpu_custom_call"
+WRITER = "pool_write_rows"     # the decode tick's row writer, by its name
 
 # Stated tolerances. bf16 keeps 8 bits of mantissa (eps 2^-8 = 3.9e-3), so
 # results that went through bf16 matmuls are held to a few eps of the
@@ -288,6 +289,17 @@ def pool_slab_moves(hlo, pool_shape):
     return found
 
 
+def check_no_pool_moves(hlo, pool, program):
+    """Every array of a paged pool (``PagedKVCache.pool``, or a model's
+    ``pool_spec``) against a compiled serve program: none is copied,
+    and no layer's slab of one is cut out."""
+    for i, arr in enumerate(pool):
+        moves = pool_slab_moves(hlo, tuple(arr.shape))
+        check(not moves, f"serve: {program} moves a layer's slab of pool "
+                         f"array {i} {tuple(arr.shape)}, or the array "
+                         f"whole: {moves[:4]}")
+
+
 def latent_decode_program(clock, cfg=None, n_slots=32, n_blocks=6801,
                           block=64, width=128):
     """Compiled from shapes alone (no weights are made): the paged decode
@@ -312,9 +324,9 @@ def latent_decode_program(clock, cfg=None, n_slots=32, n_blocks=6801,
             i32(n_slots)).compile().as_text()
     check(MOSAIC in hlo, "serve: the latent decode program holds no Mosaic "
                          "custom call")
-    moves = pool_slab_moves(hlo, tuple(pool[0].shape))
-    check(not moves, "serve: the latent decode program moves a layer's slab "
-                     f"of the latent pool, or the pool whole: {moves[:4]}")
+    check(WRITER in hlo, "serve: the latent decode program holds no "
+                         f"{WRITER} call")
+    check_no_pool_moves(hlo, pool, "the latent decode program")
 
 
 def phase_serve(cfg=None, n_slots=8):
@@ -340,9 +352,9 @@ def phase_serve(cfg=None, n_slots=8):
                 table_width=PROMPT_LENS[0] // BLOCK + 1).compile().as_text()
         check(MOSAIC in hlo, "serve: the engine's decode program holds no "
                              "Mosaic custom call")
-        moves = pool_slab_moves(hlo, tuple(eng.cache.kb.shape))
-        check(not moves, "serve: the decode program moves a layer's slab of "
-                         f"the KV pool, or the pool whole: {moves[:4]}")
+        check(WRITER in hlo, "serve: the engine's decode program holds no "
+                             f"{WRITER} call")
+        check_no_pool_moves(hlo, eng.cache.pool, "the decode program")
         t0 = time.perf_counter()
         cold = run_traffic(eng, cfg)
         cold_s = time.perf_counter() - t0
@@ -423,6 +435,7 @@ def phase_kernels():
     md = importlib.import_module("paddle_tpu.ops.moe_dispatch")
     pa = importlib.import_module("paddle_tpu.ops.paged_attention")
     pr = importlib.import_module("paddle_tpu.ops.power_retention")
+    pw = importlib.import_module("paddle_tpu.ops.pool_write")
     rf = importlib.import_module("paddle_tpu.parallel.ring_flash")
     from paddle_tpu.parallel.mesh import AXES
 
@@ -532,6 +545,35 @@ def phase_kernels():
         args=(normal((32, 64, 512)), normal((32, 64, 64)),
               normal((513, 2, 64, 640)), tables, lengths, jnp.int32(1)),
         tol=TOL_BF16)
+
+    # -- the decode tick's row writer at the serve cells' blocks: K and V
+    # of 16 heads, and the latent row; the lanes of ``live_case`` (a
+    # dead lane names the sink and writes nothing), then none live. The
+    # reference is the composed loop with the sink put back: bit for bit
+    def write_case(pool_shape, n_arrays, lengths):
+        n_blocks, bs = pool_shape[0], pool_shape[-2]
+        live = lengths > 0
+        blk = np.where(live, rng.permutation(n_blocks - 1)[:32] + 1, 0)
+        off = np.where(live, (lengths - 1) % bs, 0)
+        row = pool_shape[2:-2] + pool_shape[-1:]
+        return (tuple(normal(pool_shape) for _ in range(n_arrays)),
+                tuple(normal((32,) + row) for _ in range(n_arrays)),
+                blk.astype(np.int32), off.astype(np.int32), lengths,
+                jnp.int32(1))
+
+    def write_ref(pools, rows, blk, off, n, li):
+        out = pw.write_rows_composed(pools, rows, blk, off, li)
+        return tuple(o.at[0].set(p[0]) for o, p in zip(out, pools))
+
+    for tag, shape, n_arrays in (("heads", (1025, 2, 16, BLOCK, 128), 2),
+                                 ("latent", (513, 2, 64, 640), 1)):
+        for lanes_tag, n in (("dead_lanes", lengths),
+                             ("none_live", np.zeros_like(lengths))):
+            run(f"pool_write_rows.cells.{tag}.{lanes_tag}",
+                fn=lambda pools, rows, blk, off, n, li: pw.pool_write_rows(
+                    pools, rows, blk, off, li, lanes=pw.live_lanes(n)),
+                ref_fn=write_ref, args=write_case(shape, n_arrays, n),
+                tol=0.0)
 
     # -- power retention at brumby_14b's heads: 40 query heads over 8 of
     # 128, states of (136, 9216) float32; 4 lanes of which one is dead

@@ -35,6 +35,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..core import native as _native
 from ..ops.flash_attention import NEG_INF, _attention_reference, _on_tpu
+from ..ops.pool_write import (live_lanes, pool_put, pool_write_rows,
+                              write_rows_composed)
 from .serving_api import ServingModel
 
 __all__ = ["GPTConfig", "gpt_init", "gpt_forward", "gpt_loss",
@@ -968,31 +970,26 @@ def gpt_verify_step(cfg: GPTConfig, params, cache, positions, tokens):
 # (n_blocks, nh, block_size, hd) slab is never cut out, copied or put
 # back. ``li`` below is the layer: the scan's traced index, or a Python
 # int in the unrolled MoE branches.
-
-def _pool_put(pool, update, at):
-    """One in-place write of ``update`` into the pool at ``at`` (block,
-    layer, 0, offset, 0). A dynamic-update-slice keeps the pool's own
-    layout (a scatter makes XLA re-lay the whole pool out around it);
-    block ids and offsets are never negative, so no index is wrapped."""
-    return jax.lax.dynamic_update_slice(
-        pool, update.astype(pool.dtype), at, allow_negative_indices=False)
-
+#
+# Who writes: the decode tick's one new row a lane goes through
+# ``ops.pool_write.pool_write_rows`` (on a TPU one kernel call a layer
+# for K and V and the live lanes only; elsewhere the composed loop of
+# ``pool_put``); the verify step's several rows a lane keep that
+# composed loop (``_pool_write_rows``); a prefill chunk's whole blocks
+# are one ``pool_put`` each (``_pool_write_blocks``).
 
 @jax.named_scope("kv_pool")
 def _pool_write_rows(kb, vb, li, blk, off, k, v):
-    """Write new tokens' K/V into the pool at layer ``li``, in place.
+    """Write new tokens' K/V into the pool at layer ``li``, in place,
+    one ``pool_put`` a token and an array.
 
     blk/off (...,) int32 — each token's block and offset in it; k/v
     (..., nh, hd). Live slots own their blocks exclusively, so the only
     collisions are stale lanes piling onto a garbage sink."""
     nh, hd = k.shape[-2:]
-    blk, off = blk.reshape(-1), off.reshape(-1)
-    k = k.reshape(-1, 1, 1, nh, 1, hd)
-    v = v.reshape(-1, 1, 1, nh, 1, hd)
-    for n in range(blk.shape[0]):
-        at = (blk[n], li, 0, off[n], 0)
-        kb, vb = _pool_put(kb, k[n], at), _pool_put(vb, v[n], at)
-    return kb, vb
+    return write_rows_composed(
+        (kb, vb), (k.reshape(-1, nh, hd), v.reshape(-1, nh, hd)),
+        blk.reshape(-1), off.reshape(-1), li)
 
 
 @jax.named_scope("kv_pool")
@@ -1003,8 +1000,8 @@ def _pool_write_blocks(kb, vb, li, bids, k, v):
     for j in range(bids.shape[0]):
         at = (bids[j], li, 0, 0, 0)
         rows = slice(j * bs, (j + 1) * bs)
-        kb = _pool_put(kb, k[None, None, :, rows], at)
-        vb = _pool_put(vb, v[None, None, :, rows], at)
+        kb = pool_put(kb, k[None, None, :, rows], at)
+        vb = pool_put(vb, v[None, None, :, rows], at)
     return kb, vb
 
 
@@ -1019,11 +1016,12 @@ def _pool_gather(kb, vb, li, tables):
 
 @jax.named_scope("attn")
 def _dec_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions,
-                    lengths, walk):
+                    lengths, walk, lanes):
     """Attention half of the paged one-token block step at layer ``li``
     (pool write + paged attention + proj residual); ``lengths`` (B,) are
     the tokens each slot attends over, ``walk`` the tick's
-    ``ops.paged_attention.decode_walk`` of them. Returns (x, kb, vb)."""
+    ``ops.paged_attention.decode_walk`` of them and ``lanes`` its
+    ``ops.pool_write.live_lanes``. Returns (x, kb, vb)."""
     B = x.shape[0]
     nh, hd = cfg.n_heads, cfg.head_dim
     bs = kb.shape[3]
@@ -1036,7 +1034,9 @@ def _dec_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions,
     q, k, v = to_heads(q), to_heads(k), to_heads(v)
 
     blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
-    kb, vb = _pool_write_rows(kb, vb, li, blk, positions % bs, k, v)
+    with jax.named_scope("kv_pool"):
+        kb, vb = pool_write_rows((kb, vb), (k, v), blk, positions % bs, li,
+                                 lanes=lanes)
 
     from ..ops.paged_attention import paged_attention_arrays
     o = paged_attention_arrays(q, kb, vb, tables, lengths,
@@ -1049,17 +1049,17 @@ def _dec_attn_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions,
 
 
 def _block_decode_paged(cfg: GPTConfig, p, x, kb, vb, li, tables, positions,
-                        lengths, walk):
+                        lengths, walk, lanes):
     """One-token block step at layer ``li`` of the block pool.
 
     x (B, 1, H); kb/vb the whole pool (n_blocks, L, nh, block_size, hd);
     tables (B, W) int32; positions (B,) int32 — where each slot's
-    incoming token lands; ``lengths`` (B,) the tokens it attends over
-    and ``walk`` the tick's list of live blocks.
+    incoming token lands; ``lengths`` (B,) the tokens it attends over,
+    ``walk`` the tick's list of live blocks and ``lanes`` of live lanes.
     Attention routes through ops.paged_attention (Pallas kernel on TPU,
     identical composed gather elsewhere)."""
     x, kb, vb = _dec_attn_paged(cfg, p, x, kb, vb, li, tables, positions,
-                                lengths, walk)
+                                lengths, walk, lanes)
     return _dec_mlp(cfg, p, x), kb, vb
 
 
@@ -1083,11 +1083,13 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
     cd = cfg.dtype
     L = kb.shape[1]
     # a lane whose table row is the sink (block 0) holds no request:
-    # length 0, which costs the kernel no step and reads as zeros. The
-    # kernel's list of live blocks is the same at every layer, so it is
-    # built here, once a tick, and not inside the layer loop
+    # length 0, which costs the kernels no step: it reads as zeros and
+    # writes no row. The attention kernel's list of live blocks and the
+    # row writer's list of live lanes are the same at every layer, so
+    # they are built here, once a tick, and not inside the layer loop
     lengths = jnp.where(tables[:, 0] > 0, positions + 1, 0)
     walk = decode_walk(lengths, tables.shape[1], kb.shape[3])
+    lanes = live_lanes(lengths)
     with jax.named_scope("embed"):
         x = (params["wte"].astype(cd)[tokens]
              + params["wpe"].astype(cd)[positions])[:, None, :]  # (B, 1, H)
@@ -1101,7 +1103,7 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
         for i in range(cfg.n_layers):
             pa = _layer_params(blocks, i, _ATTN_KEYS)
             x, kb, vb = _dec_attn_paged(cfg, pa, x, kb, vb, i, tables,
-                                        positions, lengths, walk)
+                                        positions, lengths, walk, lanes)
             if i in moe_ids:
                 pm = _layer_params(params["moe"], mi, _MOE_KEYS)
                 mi += 1
@@ -1117,7 +1119,8 @@ def gpt_decode_step_paged(cfg: GPTConfig, params, pool, tables, positions,
         x, kb, vb = carry
         layer_p, li = inp
         x, kb, vb = _block_decode_paged(cfg, layer_p, x, kb, vb, li,
-                                        tables, positions, lengths, walk)
+                                        tables, positions, lengths, walk,
+                                        lanes)
         return (x, kb, vb), None
 
     (x, kb, vb), _ = jax.lax.scan(

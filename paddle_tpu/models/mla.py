@@ -55,6 +55,7 @@ from ..nn.moe import moe_ffn_held, moe_route_sigmoid
 from ..ops.flash_attention import NEG_INF
 from ..ops.mla_attention import (decode_walk, gather_rows,
                                  mla_decode_arrays)
+from ..ops.pool_write import live_lanes, pool_put, pool_write_rows
 from .serving_api import ServingModel
 
 __all__ = ["MLAConfig", "sarvam_105b", "mla_tiny", "mla_init",
@@ -494,11 +495,6 @@ def _pool_row(cfg: MLAConfig, row):
     return jnp.pad(row, ((0, 0), (0, pad))) if pad else row
 
 
-def _pool_put(pool, update, at):
-    return jax.lax.dynamic_update_slice(
-        pool, update.astype(pool.dtype), at, allow_negative_indices=False)
-
-
 def mla_prefill_chunk(cfg: MLAConfig, params, pool, table_row, tokens,
                       start, n_true=None):
     """One chunk of a paged, chunked prefill (the contract of
@@ -522,8 +518,8 @@ def mla_prefill_chunk(cfg: MLAConfig, params, pool, table_row, tokens,
             row = _pool_row(cfg, row)
             bids = jnp.take(table_row, start // bs + jnp.arange(C // bs))
             for j in range(C // bs):
-                lat = _pool_put(lat, row[None, None, j * bs:(j + 1) * bs],
-                                (bids[j], li, 0, 0))
+                lat = pool_put(lat, row[None, None, j * bs:(j + 1) * bs],
+                               (bids[j], li, 0, 0))
             rows = gather_rows(lat, table_row, li)
         o = _expanded_attention(cfg, q_nope, q_rope, rows, _wkvb(cfg, p),
                                 live)
@@ -548,19 +544,20 @@ def mla_decode_step_paged(cfg: MLAConfig, params, pool, tables, positions,
     blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
     off = positions % bs
     live = tables[:, 0] > 0
-    # such a lane has length 0: no step of the kernel, and zeros. The
-    # kernel's list of live blocks is the same at every layer
+    # such a lane has length 0: no step of either kernel, so zeros and
+    # no row written. The lists of live blocks and of live lanes are the
+    # same at every layer
     lengths = jnp.where(live, positions + 1, 0)
     walk = decode_walk(lengths, tables.shape[1], bs)
+    lanes = live_lanes(lengths)
 
     @jax.named_scope("attn")
     def attn(p, x, lat, li):
         u = _rms(x, p["ln1"], cfg.rms_eps, cfg.dtype)
         q_nope, q_rope, row = _project(cfg, p, u, positions)
         with jax.named_scope("kv_pool"):
-            row = _pool_row(cfg, row)[:, None, None, None]
-            for n in range(B):
-                lat = _pool_put(lat, row[n], (blk[n], li, off[n], 0))
+            (lat,) = pool_write_rows((lat,), (_pool_row(cfg, row),), blk,
+                                     off, li, lanes=lanes)
         w = _wkvb(cfg, p)
         q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w[..., :dn])
         o_lat = mla_decode_arrays(q_lat, q_rope, lat, tables, lengths,

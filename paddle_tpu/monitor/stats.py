@@ -299,6 +299,7 @@ DEFAULT_STATS = (
     "serving_decode_blocks_live",    # active slots' table entries, a tick
     "serving_decode_blocks_tabled",  # n_slots x table width, the same ticks
     "serving_state_slots_live",      # lanes whose recurrent state a tick moved
+    "serving_kv_rows_written",       # active lanes x layers: rows put into a paged pool
     # decode ticks by the path their sampling takes (serving/sampling.py):
     "serving_sample_ticks_greedy",   # no row samples: argmax
     "serving_sample_ticks_select",   # samples, no sort of the vocabulary
@@ -430,6 +431,7 @@ SERVING_DECODE_BLOCKS_LIVE = _registry.get_stat("serving_decode_blocks_live")
 SERVING_DECODE_BLOCKS_TABLED = _registry.get_stat(
     "serving_decode_blocks_tabled")
 SERVING_STATE_SLOTS_LIVE = _registry.get_stat("serving_state_slots_live")
+SERVING_KV_ROWS_WRITTEN = _registry.get_stat("serving_kv_rows_written")
 SERVING_SAMPLE_TICKS_GREEDY = _registry.get_stat(
     "serving_sample_ticks_greedy")
 SERVING_SAMPLE_TICKS_SELECT = _registry.get_stat(

@@ -5,7 +5,9 @@ in one shared block pool ``(n_blocks, n_layers, n_heads, block_size,
 head_dim)``; a slot's tokens live in the blocks its block table names,
 in table order. The batched one-token decode step then needs attention
 of a single query per slot over that slot's *scattered* blocks, at one
-layer — this module provides it:
+layer — this module provides it. (The step's new row reaches the pool
+before this kernel reads it: ``ops/pool_write.py`` writes the live
+lanes' rows in place, one call a layer; this kernel only reads.)
 
 - :func:`paged_attention_arrays` — the routed entry every caller uses.
   It takes the whole 5-D pool and a ``layer`` and addresses blocks by

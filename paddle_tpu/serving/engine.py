@@ -175,7 +175,11 @@ Span args are built only under ``recording()``; ``serving_prefill_chunks``
 counts prefill chunks, always. ``serving.decode_step``
 carries ``decode_blocks_live`` (the active slots' table entries) and
 ``decode_blocks_tabled`` (``n_slots`` x the tick's table width), which
-``serving_decode_blocks_live`` / ``_tabled`` sum, and ``sample_path``
+``serving_decode_blocks_live`` / ``_tabled`` sum, ``kv_rows_written``
+(active lanes x layers: the rows the tick's writer puts into the paged
+pool, summed by ``serving_kv_rows_written``) beside ``kv_rows_grid``
+(``n_slots`` x layers: what a writer with a step for every lane would
+put), and ``sample_path``
 (``greedy`` / ``select`` / ``sort``: which way the tick's sampling goes,
 by its rows' parameters; ``serving_sample_ticks_<path>`` count the ticks
 of each). The jitted programs carry
@@ -208,6 +212,7 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              SERVING_DECODE_BLOCKS_TABLED,
                              SERVING_DECODE_MS, SERVING_DECODE_TICK_MS,
                              SERVING_EVICTIONS, SERVING_FIRST_TOKEN_MS,
+                             SERVING_KV_ROWS_WRITTEN,
                              SERVING_PER_TOKEN_MS, SERVING_PREEMPTIONS,
                              SERVING_PREFILL_CHUNK_MS, SERVING_PREFILL_CHUNKS,
                              SERVING_PREFILL_MS,
@@ -2214,6 +2219,13 @@ class InferenceEngine:
                     # and written back by this tick, whatever its context
                     span_args["state_slots_live"] = live
                     SERVING_STATE_SLOTS_LIVE.add(live)
+                else:
+                    # one new row a layer for each active lane; a grid
+                    # over every lane would write n_slots x layers
+                    layers = self.cache.pool[0].shape[1]
+                    span_args["kv_rows_written"] = len(active) * layers
+                    span_args["kv_rows_grid"] = self.n_slots * layers
+                    SERVING_KV_ROWS_WRITTEN.add(len(active) * layers)
                 got = self._decode_paged_jit(
                     self._decode_params, *self.cache.pool, tables,
                     positions, tokens, self._base_key, rids, steps,

@@ -47,6 +47,19 @@ _HLO_CASES = {
                      "fusion(%param_0.5, %tables)", 0),
     "slab_parameter": ("%param_0.1 = bf16[1025,16,16,128]{3,2,1,0} "
                        "parameter(0)", 0),
+    # the decode tick's row writer: one custom call a layer, K and V
+    # aliased to its outputs, and what reads them
+    "writer_call": ("%pool_write_rows.1 = (bf16[1025,24,16,16,128]"
+                    "{4,3,2,1,0:T(8,128)(2,1)}, bf16[1025,24,16,16,128]"
+                    "{4,3,2,1,0:T(8,128)(2,1)}) custom-call(%lane, %blk, "
+                    "%off, %li, %k, %v, %kb, %vb), "
+                    "custom_call_target=\"tpu_custom_call\"", 0),
+    "writer_result": ("%get-tuple-element.7 = bf16[1025,24,16,16,128]"
+                      "{4,3,2,1,0:T(8,128)(2,1)} get-tuple-element("
+                      "%pool_write_rows.1), index=0", 0),
+    # a relapse: the pool copied because the call could not take it in place
+    "writer_unaliased": ("%copy.12 = bf16[1025,24,16,16,128]{4,3,2,1,0:"
+                         "T(8,128)(2,1)} copy(%get-tuple-element.3)", 1),
 }
 
 

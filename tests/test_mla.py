@@ -465,10 +465,11 @@ def test_the_programs_carry_the_router_and_experts_scopes(tiny):
 @pytest.mark.kernels
 def test_the_decode_step_walks_live_lanes_only_and_builds_the_walk_once(
         tiny, monkeypatch):
-    """``mla_decode_step_paged`` through the kernel (interpret mode)
-    against the composed path, dead lanes between live ones; the walk's
-    running sum over the lanes stands once in the program, outside the
-    scan over the expert layers."""
+    """``mla_decode_step_paged`` through both kernels, attention and
+    the row writer (interpret mode), against the composed path, dead
+    lanes between live ones; the two work-lists' running sums over the
+    lanes stand once each in the program, outside the scan over the
+    expert layers."""
     import functools
 
     from paddle_tpu.models import mla as mla_model
@@ -485,6 +486,8 @@ def test_the_decode_step_walks_live_lanes_only_and_builds_the_walk_once(
     want = mla_decode_step_paged(cfg, params, pool, tables, pos, toks)
     monkeypatch.setattr(mla_model, "mla_decode_arrays", functools.partial(
         mla_attention.mla_decode_arrays, interpret=True))
+    monkeypatch.setattr(mla_model, "pool_write_rows", functools.partial(
+        mla_model.pool_write_rows, interpret=True))
     step = functools.partial(mla_decode_step_paged, cfg)
     got = jax.jit(step)(params, pool, tables, pos, toks)
     live = np.asarray([1, 3])
@@ -493,6 +496,10 @@ def test_the_decode_step_walks_live_lanes_only_and_builds_the_walk_once(
     assert np.isfinite(np.asarray(got[0])).all()
     np.testing.assert_allclose(np.asarray(got[1][0])[1:],
                                np.asarray(want[1][0])[1:], atol=1e-5)
+    # the writer's kernel gives a lane with no request no step: the sink
+    # block is as it was
+    np.testing.assert_array_equal(np.asarray(got[1][0])[0],
+                                  np.asarray(pool[0])[0])
 
     def builds(jaxpr, in_scan=False):
         for eqn in jaxpr.eqns:
@@ -505,4 +512,5 @@ def test_the_decode_step_walks_live_lanes_only_and_builds_the_walk_once(
                                   or eqn.primitive.name == "scan")
 
     jaxpr = jax.make_jaxpr(step)(params, pool, tables, pos, toks)
-    assert list(builds(jaxpr.jaxpr)) == [False]
+    # two lists: the kernel's live blocks, the row writer's live lanes
+    assert list(builds(jaxpr.jaxpr)) == [False, False]

@@ -15,6 +15,8 @@ from paddle_tpu.ops.flash_attention import _attention_reference
 from paddle_tpu.ops.paged_attention import (_paged_attention_reference,
                                             _paged_decode,
                                             paged_attention_arrays)
+from paddle_tpu.ops.pool_write import (live_lanes, pool_write_rows,
+                                       write_rows_composed)
 
 pytestmark = pytest.mark.kernels
 
@@ -282,6 +284,74 @@ class TestPagedDecodeStep:
                                    rtol=2e-4, atol=2e-4)
 
 
+class TestPoolWriteRows:
+    """The decode tick's row writer (``ops/pool_write.py``): the kernel
+    in interpret mode against the composed loop of ``pool_put``, bit for
+    bit, and a lane with no request writes nothing."""
+
+    POOLS = {
+        # (n_blocks, L, *block), the dtype, arrays in the pool
+        "heads_f32": ((7, 3, 4, 8, 32), jnp.float32, 2),
+        "heads_bf16": ((7, 3, 4, 16, 128), jnp.bfloat16, 2),
+        "latent": ((7, 2, 64, 640), jnp.bfloat16, 1),
+    }
+    LIVE = {"all": [1, 1, 1, 1, 1], "one": [0, 0, 1, 0, 0],
+            "none": [0, 0, 0, 0, 0], "some": [0, 1, 1, 0, 1]}
+
+    @pytest.mark.parametrize("first_off", [0, 5, -1])
+    @pytest.mark.parametrize("live", sorted(LIVE))
+    @pytest.mark.parametrize("pool", sorted(POOLS))
+    def test_kernel_matches_the_composed_loop(self, pool, live, first_off):
+        shape, dtype, n_arrays = self.POOLS[pool]
+        bs, L = shape[-2], shape[1]
+        alive = np.asarray(self.LIVE[live], bool)
+        B = alive.shape[0]
+        pools = tuple(jnp.asarray(RNG.normal(size=shape), dtype)
+                      for _ in range(n_arrays))
+        rows = tuple(jnp.asarray(
+            RNG.normal(size=(B,) + shape[2:-2] + shape[-1:]), dtype)
+            for _ in range(n_arrays))
+        # a live lane owns a block of its own; a lane with no request
+        # names the sink, as its table row does. Offsets step through
+        # the block from 0, an odd row or the last one
+        blk = np.where(alive, 1 + np.arange(B), 0).astype(np.int32)
+        off = ((first_off % bs + 3 * np.arange(B)) % bs).astype(np.int32)
+        lanes = live_lanes(jnp.asarray(np.where(alive, 1 + off, 0)))
+        assert int(lanes.count[0]) == alive.sum()
+        assert list(np.asarray(lanes.lane)[:alive.sum()]) == \
+            list(np.flatnonzero(alive))
+        layer = L - 1
+        got = jax.jit(lambda li: pool_write_rows(
+            pools, rows, blk, off, li, lanes=lanes, interpret=True))(
+                jnp.int32(layer))                 # a traced layer index
+        want = write_rows_composed(pools, rows, jnp.asarray(blk),
+                                   jnp.asarray(off), layer)
+        for g, w, was, row in zip(got, want, pools, rows):
+            assert g.dtype == was.dtype and g.shape == was.shape
+            g, w, was = np.asarray(g), np.asarray(w), np.asarray(was)
+            # the composed loop writes a dead lane's row into the sink
+            np.testing.assert_array_equal(g[1:], w[1:])
+            # the kernel: the live lanes' rows and nothing else, the
+            # sink included
+            only = was.copy()
+            for b in np.flatnonzero(alive):
+                only[blk[b], layer, ..., off[b], :] = np.asarray(row)[b]
+            np.testing.assert_array_equal(g, only)
+
+    def test_entry_routes_to_the_composed_loop_off_tpu(self):
+        shape, dtype, _ = self.POOLS["heads_f32"]
+        pools = tuple(jnp.asarray(RNG.normal(size=shape), dtype)
+                      for _ in range(2))
+        rows = tuple(jnp.asarray(RNG.normal(size=(3, 4, 32)), dtype)
+                     for _ in range(2))
+        blk, off = jnp.asarray([2, 0, 5]), jnp.asarray([7, 1, 0])
+        got = pool_write_rows(pools, rows, blk, off, 1,
+                              lanes=live_lanes(jnp.asarray([8, 0, 1])))
+        want = write_rows_composed(pools, rows, blk, off, 1)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 class TestPoolInPlace:
     """The paged steps address the WHOLE 5-D pool by (block, layer): the
     kernel entry reads a layer's blocks straight out of it, and no paged
@@ -326,16 +396,20 @@ class TestPoolInPlace:
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from TestPoolInPlace._walk(sub, inner)
 
-    @pytest.mark.parametrize("step", ["decode", "chunk", "verify"])
-    def test_no_step_materialises_a_slab(self, step):
-        """Structure of the three paged programs: inside the layer scan
-        no equation yields an array of the slab's shape
+    @pytest.mark.parametrize("step", ["decode", "decode_kernel", "chunk",
+                                      "verify"])
+    def test_no_step_materialises_a_slab(self, step, monkeypatch):
+        """Structure of the paged programs: inside the layer scan no
+        equation yields an array of the slab's shape
         (n_blocks, nh, bs, hd), and the only pool-shaped values are the
-        carry and the in-place writes into it (with the pin that keeps
-        its layout)."""
+        carry and the in-place writes into it: the composed path's
+        ``dynamic_update_slice`` (with the pin that keeps the pool's
+        layout) or, where the decode step's row writer runs its kernel
+        (``decode_kernel``: what a TPU runs), that one call a layer."""
         from paddle_tpu.models import (gpt_decode_step_paged, gpt_init,
                                        gpt_prefill_chunk, gpt_tiny,
                                        gpt_verify_step_paged)
+        from paddle_tpu.models import gpt as gpt_model
         from paddle_tpu.serving import PagedKVCache
 
         cfg = gpt_tiny(dtype=jnp.float32, seq_len=64)
@@ -345,7 +419,11 @@ class TestPoolInPlace:
         pool = (cache.kb, cache.vb)
         tables = jnp.zeros((2, 3), jnp.int32)
         pos = jnp.asarray([9, 0], jnp.int32)
-        if step == "decode":
+        if step == "decode_kernel":
+            monkeypatch.setattr(gpt_model, "pool_write_rows",
+                                functools.partial(pool_write_rows,
+                                                  interpret=True))
+        if step.startswith("decode"):
             jaxpr = jax.make_jaxpr(
                 lambda p, kv: gpt_decode_step_paged(
                     cfg, p, kv, tables, pos, jnp.asarray([1, 2])))(
@@ -364,22 +442,27 @@ class TestPoolInPlace:
         slab_shape = pool_shape[:1] + pool_shape[2:]
         writes = scans = 0
         for eqn, in_scan in self._walk(jaxpr.jaxpr):
-            scans += eqn.primitive.name == "scan"
+            name = eqn.primitive.name
+            scans += name == "scan"
+            if name == "pallas_call":
+                name = eqn.params["name"]
             for out in eqn.outvars:
                 shape = tuple(getattr(out.aval, "shape", ()))
                 assert shape != slab_shape, (
-                    f"{step}: {eqn.primitive.name} yields a layer's slab "
-                    f"{shape}")
+                    f"{step}: {name} yields a layer's slab {shape}")
                 if shape == pool_shape and in_scan:
-                    assert eqn.primitive.name in (
-                        "dynamic_update_slice", "layout_constraint"), (
-                        f"{step}: {eqn.primitive.name} yields a pool-"
-                        "shaped value inside the layer scan")
-                    writes += eqn.primitive.name == "dynamic_update_slice"
+                    assert name in ("dynamic_update_slice",
+                                    "layout_constraint",
+                                    "pool_write_rows"), (
+                        f"{step}: {name} yields a pool-shaped value "
+                        "inside the layer scan")
+                    writes += name != "layout_constraint"
         assert scans == 1
-        # K and V: one row a token (decode 2, verify 2 x 3), or one
-        # block a 8-token block of the chunk (16 / 8)
-        assert writes == 2 * {"decode": 2, "chunk": 2, "verify": 6}[step]
+        # pool-shaped values written a layer, K and V: one row a token
+        # (decode 2, verify 2 x 3), one block a 8-token block of the
+        # chunk (16 / 8), or the two outputs of the writer's one call
+        assert writes == 2 * {"decode": 2, "decode_kernel": 1, "chunk": 2,
+                              "verify": 6}[step]
 
     def test_moe_paged_steps_match_contiguous(self):
         """The unrolled MoE branches (a Python-int layer) write and read
@@ -438,7 +521,8 @@ class TestPoolInPlace:
 
 def walk_builds(jaxpr, n_slots):
     """(outside, inside) the layer scan: how many equations build a
-    work-list, told by its running sum over an int32 (n_slots,)."""
+    work-list (the attention kernel's live blocks, the row writer's live
+    lanes), each told by its running sum over an int32 (n_slots,)."""
     found = [0, 0]
     for eqn, in_scan in TestPoolInPlace._walk(jaxpr.jaxpr):
         if eqn.primitive.name == "cumsum" \
@@ -449,9 +533,9 @@ def walk_builds(jaxpr, n_slots):
 
 
 class TestWalkInTheModel:
-    """``gpt_decode_step_paged`` builds the live walk once a tick, hands
-    it to the kernel at every layer, and gives a lane with no request
-    length 0."""
+    """``gpt_decode_step_paged`` builds the live walk and the writer's
+    list of live lanes once a tick, hands them to the kernels at every
+    layer, and gives a lane with no request length 0."""
 
     @staticmethod
     def _tick(moe=False):
@@ -475,6 +559,7 @@ class TestWalkInTheModel:
     @pytest.mark.parametrize("moe", [False, True])
     def test_step_through_the_kernel_matches_the_composed_path(
             self, monkeypatch, moe):
+        from paddle_tpu.models import gpt as gpt_model
         from paddle_tpu.models import gpt_decode_step_paged
         from paddle_tpu.ops import paged_attention as pa
 
@@ -482,6 +567,8 @@ class TestWalkInTheModel:
         want = gpt_decode_step_paged(cfg, params, pool, tables, pos, toks)
         monkeypatch.setattr(pa, "paged_attention_arrays", functools.partial(
             pa.paged_attention_arrays, interpret=True))
+        monkeypatch.setattr(gpt_model, "pool_write_rows", functools.partial(
+            pool_write_rows, interpret=True))
         got = jax.jit(functools.partial(gpt_decode_step_paged, cfg))(
             params, pool, tables, pos, toks)
         live = np.asarray([1, 3])
@@ -489,16 +576,21 @@ class TestWalkInTheModel:
                                    np.asarray(want[0])[live],
                                    rtol=2e-4, atol=2e-4)
         assert np.isfinite(np.asarray(got[0])).all()
-        for a, b in zip(got[1], want[1]):
-            # rows of live lanes; dead lanes write the sink block only
+        for a, b, was in zip(got[1], want[1], pool):
+            # rows of live lanes; in the composed path a dead lane
+            # writes the sink block, in the writer's kernel nothing
             np.testing.assert_allclose(np.asarray(a)[1:], np.asarray(b)[1:],
                                        rtol=2e-5, atol=2e-5)
+            np.testing.assert_array_equal(np.asarray(a)[0],
+                                          np.asarray(was)[0])
 
     @pytest.mark.parametrize("moe", [False, True])
     def test_the_walk_is_built_once_a_tick(self, moe):
+        """Two lists (live blocks, live lanes), both outside the layer
+        loop."""
         from paddle_tpu.models import gpt_decode_step_paged
 
         cfg, params, pool, tables, pos, toks = self._tick(moe)
         jaxpr = jax.make_jaxpr(functools.partial(
             gpt_decode_step_paged, cfg))(params, pool, tables, pos, toks)
-        assert walk_builds(jaxpr, 5) == (1, 0)
+        assert walk_builds(jaxpr, 5) == (2, 0)

@@ -258,6 +258,30 @@ class TestChunkCounter:
         assert out["decode_blocks_live_share"] == pytest.approx(
             (after[0] - before[0]) / (after[1] - before[1]))
 
+    def test_rows_written_counted_on_the_span_and_in_the_registry(
+            self, engine):
+        """Each decode tick says how many rows its writer puts into the
+        paged pool (active lanes x layers) beside what a grid over every
+        lane would put (slots x layers): the span's two arguments, the
+        counter by the first, and the serving report's means a tick."""
+        assert "serving_kv_rows_written" in monitor.DEFAULT_STATS
+        before = monitor.stat_get("serving_kv_rows_written")
+        eng = engine(n_slots=4)
+        layers = eng.cache.pool[0].shape[1]
+        events, _, _ = _traced_run(eng, lengths=(40, 9), new=4)
+        ticks = [e["args"] for e in events
+                 if e["name"] == "serving.decode_step"]
+        assert ticks
+        for a in ticks:
+            assert a["kv_rows_written"] == a["batch"] * layers
+            assert a["kv_rows_grid"] == 4 * layers
+        written = sum(a["kv_rows_written"] for a in ticks)
+        assert monitor.stat_get("serving_kv_rows_written") - before == written
+        out = _serving_report(events)
+        assert out["kv_rows_written_a_tick"] == pytest.approx(
+            written / len(ticks))
+        assert out["kv_rows_grid_a_tick"] == 4 * layers
+
     @pytest.mark.parametrize("sampling, paths", [
         ((), {"greedy"}),
         (({"temperature": 0.8, "top_k": 40, "top_p": 0.95},),
@@ -508,10 +532,11 @@ class TestAnnotation:
                     kw = {k.arg: k.value for k in node.keywords}
                     assert "name" in kw, (path, node.lineno)
                     names.append(kw["name"].value)
-        assert len(names) == 17 and len(set(names)) == 17
+        assert len(names) == 18 and len(set(names)) == 18
         for pattern, kernel in (("flash_forward", "flash_forward"),
                                 ("flash_backward", "flash_backward"),
                                 ("_paged_decode", "pallas_paged_decode"),
+                                ("pool_write", "pool_write_rows"),
                                 ("power_retention_decode",
                                  "power_retention_decode"),
                                 ("power_retention_chunk",

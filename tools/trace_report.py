@@ -381,6 +381,9 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
     stream waited behind chunked prefill work — and the share of the
     decode ticks' tabled blocks that were live (``decode_blocks_live``
     over ``decode_blocks_tabled`` of the ``serving.decode_step`` spans),
+    the rows a tick's writer put into the paged pool beside the rows a
+    grid over every lane would put (``kv_rows_written``: active lanes x
+    layers; ``kv_rows_grid``: slots x layers),
     for a model whose cache is a recurrent state the lanes whose state a
     tick moved (``state_slots_live``: the tick's state traffic is that
     times one state's bytes, twice), and the share of the ticks whose
@@ -420,6 +423,12 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
             live = sum(int(a.get("decode_blocks_live", 0)) for a in ticks)
             out.update(decode_blocks_live=live, decode_blocks_tabled=tabled,
                        decode_blocks_live_share=live / tabled)
+        rows = [(int(a["kv_rows_written"]), int(a.get("kv_rows_grid", 0)))
+                for a in ticks if "kv_rows_written" in a]
+        if rows:
+            out.update(
+                kv_rows_written_a_tick=sum(r for r, _ in rows) / len(rows),
+                kv_rows_grid_a_tick=sum(g for _, g in rows) / len(rows))
         moved = [int(a["state_slots_live"]) for a in ticks
                  if "state_slots_live" in a]
         if moved:
