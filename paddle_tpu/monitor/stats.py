@@ -304,6 +304,10 @@ DEFAULT_STATS = (
     "serving_sample_ticks_greedy",   # no row samples: argmax
     "serving_sample_ticks_select",   # samples, no sort of the vocabulary
     "serving_sample_ticks_sort",     # the rows' parameters force the one sort
+    # decode ticks by whether another was still unread at their dispatch
+    "serving_decode_ticks_ahead",    # left with the tick before in flight
+    "serving_decode_ticks_synced",   # left with no tick unread
+    "serving_decode_lanes_discarded",  # lane results of a tick never pushed
     # paged KV cache (ISSUE 7)
     "kv_blocks_free",          # gauge: pool blocks on the free list
     "kv_blocks_used",          # gauge: pool blocks owned by live slots
@@ -437,6 +441,11 @@ SERVING_SAMPLE_TICKS_GREEDY = _registry.get_stat(
 SERVING_SAMPLE_TICKS_SELECT = _registry.get_stat(
     "serving_sample_ticks_select")
 SERVING_SAMPLE_TICKS_SORT = _registry.get_stat("serving_sample_ticks_sort")
+SERVING_DECODE_TICKS_AHEAD = _registry.get_stat("serving_decode_ticks_ahead")
+SERVING_DECODE_TICKS_SYNCED = _registry.get_stat(
+    "serving_decode_ticks_synced")
+SERVING_DECODE_LANES_DISCARDED = _registry.get_stat(
+    "serving_decode_lanes_discarded")
 KV_BLOCKS_FREE = _registry.get_stat("kv_blocks_free")
 KV_BLOCKS_USED = _registry.get_stat("kv_blocks_used")
 KV_FRAGMENTATION = _registry.get_stat("kv_fragmentation")
@@ -535,8 +544,8 @@ DEFAULT_HISTOGRAMS = (
      "admission wait (ms)"),
     ("serving_decode_tick_ms",
      "batched decode tick wall latency, dispatch to tokens on the host; "
-     "it includes the device time of any prefill chunk queued ahead of "
-     "the tick (ms)"),
+     "it includes the device time of any prefill chunk or tick queued "
+     "ahead of the tick (ms)"),
     ("serving_prefill_chunk_ms",
      "host latency of the asynchronous DISPATCH of one prefill chunk "
      "(about a millisecond whatever the chunk costs the device, which "

@@ -21,6 +21,15 @@ reference's AnalysisPredictor has no such path; batching there is
 caller-side). Finished sequences release their slot between ticks; the
 batch never stalls on the longest request.
 
+One decode tick stays in flight: step (3) dispatches tick n+1 from the
+host's projected state (each lane of tick n one token on, its input
+tick n's sampled tokens on the device) and only then reads tick n's
+tokens and emits them, so the read, the emit and the next turn's host
+work run while the device runs tick n+1. A lane whose token in flight
+is its last sits tick n+1 out; one found finished only at the read has
+its tick n+1 result discarded. The turn reads synchronously instead
+when it observes what needs the tokens first (``_may_go_ahead``).
+
 One cache, one set of programs. The target model is served from a
 :class:`~paddle_tpu.serving.kv_cache.PagedKVCache` block pool and
 reached through ``cfg.serving_model()`` (models/serving_api.py). Jit
@@ -143,9 +152,11 @@ Observability v2 (ISSUE 15): latency HISTOGRAMS recorded at the source
 front end's Prometheus ``GET /metrics``).
 serving_prefill_chunk_ms times only the asynchronous DISPATCH of a chunk
 (about a millisecond whatever the chunk costs the device: nothing in the
-span waits for it), and serving_decode_tick_ms runs from the tick's
-dispatch to its tokens on the host, so it INCLUDES the device time of a
-chunk queued ahead of the tick; the device's own times are on the
+span waits for it), and serving_decode_tick_ms (like the ``tick_ms`` the
+overload controller and the watchdog see) runs from the tick's dispatch
+to its tokens on the host, so it INCLUDES the device time of a chunk or
+a tick queued ahead of it, and the turn between a tick left in flight
+and its read; the device's own times are on the
 profiler's trace (the benchmark's ``decode_tick_ms.serve`` /
 ``prefill_chunk_ms.serve``). CAUSAL TRACING — a request
 submitted with ``trace=TraceContext`` stamps every span it touches
@@ -166,8 +177,14 @@ the end of the decode tick) ⊃ ``serving.admit``, ``serving.prefill_chunk``,
 prompt's first token: where the host waits for the chunk),
 ``serving.decode_prep`` (sweep, grow, host arrays, block tables),
 ``serving.decode_step`` ⊃ ``serving.device_wait`` (the blocking read of
-the tick's tokens), ``serving.emit`` (push / finish / evict / gauges) —
-each carrying the turn's id as ``tick``. Every request, with or without
+the tokens of the tick in flight, dispatched a turn before, or of this
+turn's tick when it is read at once; a turn that dispatches no tick
+reads in the turn itself), ``serving.emit`` (push / finish / evict /
+gauges of the tick read) — each carrying the turn's id as ``tick``.
+``serving.decode_step``'s ``ahead`` is 1 where the tick left with
+another unread (``serving_decode_ticks_ahead``; else
+``serving_decode_ticks_synced``); ``serving_decode_lanes_discarded``
+counts lane results never pushed. Every request, with or without
 a front-end TraceContext, leaves one chain keyed by ``rid``:
 ``serving.queue_wait`` (submit → admit), ``serving.admit_to_first``
 (admit → first token, with its ``chunks``), ``serving.request_done``.
@@ -210,7 +227,10 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              SERVING_DECODE_BLOCKS_LIVE,
                              SERVING_STATE_SLOTS_LIVE,
                              SERVING_DECODE_BLOCKS_TABLED,
+                             SERVING_DECODE_LANES_DISCARDED,
                              SERVING_DECODE_MS, SERVING_DECODE_TICK_MS,
+                             SERVING_DECODE_TICKS_AHEAD,
+                             SERVING_DECODE_TICKS_SYNCED,
                              SERVING_EVICTIONS, SERVING_FIRST_TOKEN_MS,
                              SERVING_KV_ROWS_WRITTEN,
                              SERVING_PER_TOKEN_MS, SERVING_PREEMPTIONS,
@@ -244,6 +264,9 @@ _CACHE_SPEC = P("data", None, "model", None, None)
 # prefills draw RNG streams that can never collide with, or shift the
 # numbering of, live request ids — rejoined replicas stay token-identical
 _WARM_RID_BASE = 2**30
+
+# _prep_decode: a projected table cannot grow from free blocks alone
+_NO_ROOM = object()
 
 
 class QueueFull(RuntimeError):
@@ -480,6 +503,26 @@ class _Slot:
         #                               unaligned cached length (_tail_jit)
         self.t_admit = time.perf_counter()  # serving.admit_to_first starts
         self.chunks = 0               # prefill chunks run so far
+
+
+class _Tick:
+    """One dispatched decode tick as the host holds it until its tokens
+    are read: the program's outputs (device arrays until the read), the
+    lanes it ran (slot -> the _Slot that held the slot at dispatch),
+    when it left the host and its ``serving.decode_step`` span's args."""
+
+    __slots__ = ("out", "n_emit", "health", "moe", "lanes", "t0", "args",
+                 "ms")
+
+    def __init__(self, out, n_emit, health, moe, lanes, t0, args):
+        self.out = out                # sampled tokens: (B,), (B, k+1) spec
+        self.n_emit = n_emit          # spec: tokens accepted a lane
+        self.health = health          # watchdog: per-lane all-finite
+        self.moe = moe                # router stats, a routed model's
+        self.lanes = lanes
+        self.t0 = t0
+        self.args = args
+        self.ms = 0.0                 # dispatch to tokens on the host
 
 
 class InferenceEngine:
@@ -739,6 +782,16 @@ class InferenceEngine:
         # common path ships no (slots, vocab) buffer per tick
         self._ones_mask = np.ones((self.n_slots, cfg.vocab_size), bool)
         self._mask_dev = jax.device_put(self._ones_mask)
+        # the decode tick dispatched and not yet read (a _Tick), and the
+        # sampled tokens of the last plain tick, on the device: the next
+        # tick's input for the lanes it carries on
+        self._inflight = None
+        # placed as the program places its sampled tokens, so either may
+        # be the next tick's input to the one compiled program
+        self._prev_toks = jax.device_put(
+            np.zeros(self.n_slots, np.int32),
+            None if self._mesh is None
+            else NamedSharding(self._mesh, P("data")))
         self.eos_id = eos_id
         self._queue: collections.deque = collections.deque()
         self._queue_size = int(queue_size)
@@ -915,10 +968,13 @@ class InferenceEngine:
 
     def _decode_paged_fn(self, params, *args):
         # args: the pool's arrays, then (tables, positions, tokens,
-        # base_key, rids, steps, temps, top_ks, top_ps, mask)
-        pool, (tables, positions, tokens, base_key, rids, steps, temps,
-               top_ks, top_ps, mask) = \
+        # prev_toks, use_prev, base_key, rids, steps, temps, top_ks,
+        # top_ps, mask); a lane whose use_prev is set takes its input
+        # from prev_toks, the tokens the tick before sampled
+        pool, (tables, positions, tokens, prev_toks, use_prev, base_key,
+               rids, steps, temps, top_ks, top_ps, mask) = \
             args[:self._n_pool], args[self._n_pool:]
+        tokens = jnp.where(use_prev, prev_toks, tokens)
         got = self._model.decode_step_paged(
             self.cfg, params, pool, tables, positions, tokens)
         logits, pool = got[0], got[1]
@@ -1209,15 +1265,16 @@ class InferenceEngine:
         the tick executes."""
         n = self.n_slots
         i32 = np.zeros(n, np.int32)
-        tail = (i32, i32, self._base_key, i32, i32, np.zeros(n, np.float32),
-                i32, np.ones(n, np.float32), self._mask_dev)
+        tail = (self._base_key, i32, i32, np.zeros(n, np.float32), i32,
+                np.ones(n, np.float32), self._mask_dev)
 
         def lower(eng):
             width = eng.cache.table_width if table_width is None \
                 else eng._width_bucket(int(table_width))
             return eng._decode_paged_jit.lower(
                 eng._decode_params, *eng.cache.pool,
-                np.zeros((n, width), np.int32), *tail)
+                np.zeros((n, width), np.int32), i32, i32, eng._prev_toks,
+                np.zeros(n, bool), *tail)
 
         # on the scheduler thread: between ticks no donated buffer is
         # mid-flight
@@ -1550,16 +1607,18 @@ class InferenceEngine:
                         raise ReplicaEvacuated(
                             f"replica {self.replica_id} evacuated "
                             "(drain-shrink)")
-                    busy = bool(self._queue) or any(
-                        s is not None for s in self._slots)
+                    busy = bool(self._queue) or self._inflight is not None \
+                        or any(s is not None for s in self._slots)
                     if self._stop and (not self._drain or not busy):
                         break
                     # run_on_scheduler closures (ISSUE 19): popped under
                     # the lock, run outside it — between ticks, so the
                     # pool buffers are quiescent (no donated jit call in
-                    # flight) and the radix tree is consistent
+                    # flight) and the radix tree is consistent. With a
+                    # tick in flight they wait: the turn below reads it
+                    # and leaves none in flight (_may_go_ahead)
                     calls = None
-                    if self._host_calls:
+                    if self._host_calls and self._inflight is None:
                         calls = list(self._host_calls)
                         self._host_calls.clear()
                     if not busy and not calls:
@@ -1603,7 +1662,8 @@ class InferenceEngine:
                               args=self._tick_args()):
                         self._admit()
                     self._prefill_chunk_tick()
-                    if any(s is not None for s in self._slots):
+                    if self._inflight is not None or any(
+                            s is not None for s in self._slots):
                         self._decode_tick()
         except BaseException as e:  # noqa: BLE001 — fail every request, not silently
             self._abort(e)
@@ -1936,6 +1996,11 @@ class InferenceEngine:
             # makes progress — no preemption livelock)
             if self._reclaim_blocks(slot, st.length + c_pad):
                 continue
+            if self._inflight is not None:
+                # the victim may be a lane of the tick in flight: read
+                # that tick first (its evictions may free the blocks)
+                self._sync()
+                continue
             victim = self._youngest_slot(exclude=slot)
             if victim is None \
                     or self._slots[victim].admit_order <= st.admit_order:
@@ -2093,85 +2158,173 @@ class InferenceEngine:
             load[s // per] += 1
         return load
 
+    def _may_go_ahead(self, now: float) -> bool:
+        """Whether this turn's tick may leave before the tick in flight
+        is read. Each refusal is something the scheduler observes, not a
+        knob: a speculative or a watchdog engine (the tick's outputs
+        decide what stands), fault injection, a live constrained row (its
+        mask needs the token), a lane in flight cancelled or past its
+        deadline, host calls waiting for a quiescent pool, shutdown and
+        evacuation. A refused turn reads the tick in flight first and
+        reads its own tick before it ends."""
+        if (self.draft is not None or self._watchdog is not None
+                or _faults.ENABLED[0] or self._stop or self._evacuate
+                or self._host_calls):
+            return False
+        if any(st is not None and st.pending is None
+               and st.req.constraint is not None for st in self._slots):
+            return False
+        if self._inflight is not None:
+            for s, st in self._inflight.lanes.items():
+                if self._slots[s] is st and (
+                        st.req._cancelled
+                        or (st.req.deadline is not None
+                            and now > st.req.deadline)):
+                    return False
+        return True
+
     def _decode_tick(self) -> None:
-        # the tick's host work ahead of the dispatch: sweep, grow, the
-        # batch's host arrays and block tables
+        """One decode tick. Where ``_may_go_ahead`` allows, tick n+1
+        leaves from the host's projected state while tick n is in
+        flight: a lane of tick n is one token on, and its input is tick
+        n's output, on the device. Only then is tick n read and emitted,
+        so the read, the emit and the next turn's host work run while
+        the device runs tick n+1. Otherwise the tick in flight is read
+        first and this tick is read in the same turn."""
+        now = time.monotonic()
+        ahead = self._may_go_ahead(now)
+        if not ahead:
+            self._sync()
         with span("serving.decode_prep", cat="serving",
                   args=self._tick_args()):
-            now = time.monotonic()
-            for s, st in enumerate(self._slots):
-                if st is None:
+            batch = self._prep_decode(now, ahead)
+        if batch is _NO_ROOM:
+            # a table cannot grow from its shard's free blocks alone: read
+            # the tick in flight, then reclaim or preempt as a synchronous
+            # tick does
+            self._sync()
+            ahead = False
+            with span("serving.decode_prep", cat="serving",
+                      args=self._tick_args()):
+                batch = self._prep_decode(now, False)
+        if batch is None:
+            self._sync()        # nothing to dispatch: read the tick in flight
+            return
+        done = self._dispatch_decode(batch, ahead)
+        if done is not None:
+            self._emit_tick(done)
+        # the router stats of this turn's chunks, which the device runs
+        # ahead of the tick just dispatched: read after the emit, so that
+        # tick n's tokens do not wait for them
+        self._note_moe_pending()
+
+    def _prep_decode(self, now: float, ahead: bool):
+        """The tick's host work ahead of the dispatch: sweep, grow, the
+        batch's host arrays and block tables. With ``ahead`` a lane of
+        the tick in flight is projected one token on (its position, its
+        draw and its table) and takes that tick's output as its input;
+        a lane whose token in flight is its last sits the tick out.
+        Returns the batch, None when no lane decodes, or _NO_ROOM when a
+        projected table cannot grow from free blocks alone."""
+        for s, st in enumerate(self._slots):
+            if st is None:
+                continue
+            if st.req._cancelled:
+                self._evict(s, CANCELLED)
+            elif st.req.deadline is not None and now > st.req.deadline:
+                self._evict(s, DEADLINE)
+        flying = self._inflight.lanes if self._inflight is not None else {}
+        active, carried = [], set()
+        for s, st in enumerate(self._slots):
+            if st is None or st.pending is not None:
+                continue
+            if flying.get(s) is st:
+                if (st.generated + 1 >= st.req.max_new_tokens
+                        or st.length + 1 >= self.max_len):
                     continue
-                if st.req._cancelled:
-                    self._evict(s, CANCELLED)
-                elif st.req.deadline is not None and now > st.req.deadline:
-                    self._evict(s, DEADLINE)
-            active = [s for s in range(self.n_slots)
-                      if self._slots[s] is not None
-                      and self._slots[s].pending is None]
-            if not active:
-                return
-            # speculation needs k+1 positions of cache headroom on every
-            # active slot; a near-cap slot drops the whole tick to the plain
-            # one-token program (correct, just unaccelerated) rather than
-            # splitting the batch across two programs. Constrained rows
-            # force the same fallback: draft proposals are not mask-aware,
-            # so speculating through an automaton would emit illegal tokens.
-            constrained = [s for s in active
-                           if self._slots[s].req.constraint is not None]
-            use_spec = (self.draft is not None
-                        and (self.overload is None
-                             or self.overload.spec_allowed())
-                        and all(self._slots[s].length + self.spec_k + 1
-                                <= self.max_len for s in active))
-            if use_spec and constrained:
-                use_spec = False
-                CONSTRAINED_FALLBACK_TICKS.add(1)
-            if use_spec:
-                use_spec = self._try_spec_grow(active)
-            if not use_spec:
-                active = self._grow_for_decode(active)
-                if not active:
-                    return
-
-            if _faults.ENABLED[0]:
-                # serving_nan fault (FLAGS_fault_inject, keyed by REQUEST id):
-                # NaN the slot's cached K/V — the deterministic stand-in for
-                # poisoned HBM — so the watchdog path is testable on CPU
-                for s in active:
-                    f = _faults.FAULTS.take_request("serving_nan",
-                                                   self._slots[s].req.rid)
-                    if f is not None:
-                        FAULTS_INJECTED.add()
-                        self._poison_slot(s)
-
-            positions = np.zeros(self.n_slots, np.int32)
-            tokens = np.zeros(self.n_slots, np.int32)
-            temps = np.zeros(self.n_slots, np.float32)
-            top_ks = np.zeros(self.n_slots, np.int32)
-            top_ps = np.ones(self.n_slots, np.float32)
-            rids = np.zeros(self.n_slots, np.int32)
-            steps = np.zeros(self.n_slots, np.int32)
-            for s in active:
+                carried.add(s)
+            active.append(s)
+        if not active:
+            return None
+        # speculation needs k+1 positions of cache headroom on every
+        # active slot; a near-cap slot drops the whole tick to the plain
+        # one-token program (correct, just unaccelerated) rather than
+        # splitting the batch across two programs. Constrained rows
+        # force the same fallback: draft proposals are not mask-aware,
+        # so speculating through an automaton would emit illegal tokens.
+        constrained = [s for s in active
+                       if self._slots[s].req.constraint is not None]
+        use_spec = (self.draft is not None
+                    and (self.overload is None
+                         or self.overload.spec_allowed())
+                    and all(self._slots[s].length + self.spec_k + 1
+                            <= self.max_len for s in active))
+        if use_spec and constrained:
+            use_spec = False
+            CONSTRAINED_FALLBACK_TICKS.add(1)
+        if use_spec:
+            use_spec = self._try_spec_grow(active)
+        if ahead:
+            for s in sorted(active,
+                            key=lambda s: self._slots[s].admit_order):
                 st = self._slots[s]
-                positions[s] = st.length
-                tokens[s] = st.last_token
-                temps[s] = st.req.temperature
-                top_ks[s] = st.req.top_k
-                top_ps[s] = st.req.top_p
-                rids[s] = st.req.rid % (2**31 - 1)
-                steps[s] = len(st.req.tokens)
-            # per-slot sampling mask: the device-resident all-true buffer on
-            # unconstrained ticks (no per-tick transfer), a fresh host array
-            # carrying each constrained row's automaton mask otherwise
-            if constrained:
-                masks = self._ones_mask.copy()
-                for s in constrained:
-                    masks[s] = self._mask_row(self._slots[s].req)[0]
-                mask_arg = jnp.asarray(masks)
-            else:
-                mask_arg = self._mask_dev
+                if not self.cache.grow(s, st.length + 1 + (s in carried)):
+                    return _NO_ROOM
+        elif not use_spec:
+            active = self._grow_for_decode(active)
+            if not active:
+                return None
 
+        if _faults.ENABLED[0]:
+            # serving_nan fault (FLAGS_fault_inject, keyed by REQUEST id):
+            # NaN the slot's cached K/V — the deterministic stand-in for
+            # poisoned HBM — so the watchdog path is testable on CPU
+            for s in active:
+                f = _faults.FAULTS.take_request("serving_nan",
+                                               self._slots[s].req.rid)
+                if f is not None:
+                    FAULTS_INJECTED.add()
+                    self._poison_slot(s)
+
+        positions = np.zeros(self.n_slots, np.int32)
+        tokens = np.zeros(self.n_slots, np.int32)
+        use_prev = np.zeros(self.n_slots, bool)
+        temps = np.zeros(self.n_slots, np.float32)
+        top_ks = np.zeros(self.n_slots, np.int32)
+        top_ps = np.ones(self.n_slots, np.float32)
+        rids = np.zeros(self.n_slots, np.int32)
+        steps = np.zeros(self.n_slots, np.int32)
+        for s in active:
+            st = self._slots[s]
+            on = s in carried
+            positions[s] = st.length + on
+            tokens[s] = st.last_token
+            use_prev[s] = on
+            temps[s] = st.req.temperature
+            top_ks[s] = st.req.top_k
+            top_ps[s] = st.req.top_p
+            rids[s] = st.req.rid % (2**31 - 1)
+            steps[s] = len(st.req.tokens) + on
+        # per-slot sampling mask: the device-resident all-true buffer on
+        # unconstrained ticks (no per-tick transfer), a fresh host array
+        # carrying each constrained row's automaton mask otherwise
+        if constrained:
+            masks = self._ones_mask.copy()
+            for s in constrained:
+                masks[s] = self._mask_row(self._slots[s].req)[0]
+            mask_arg = jnp.asarray(masks)
+        else:
+            mask_arg = self._mask_dev
+        return (active, positions, tokens, use_prev, rids, steps, temps,
+                top_ks, top_ps, mask_arg, use_spec)
+
+    def _dispatch_decode(self, batch, ahead: bool) -> Optional[_Tick]:
+        """Dispatch the tick (``serving.decode_step``), then read the
+        tick whose tokens are due: with ``ahead`` the one that was in
+        flight, and this one stays in flight; else this one. Returns the
+        tick read, or None."""
+        (active, positions, tokens, use_prev, rids, steps, temps, top_ks,
+         top_ps, mask_arg, use_spec) = batch
         # which way the tick's sampling goes, from the rows' parameters
         # (a tie that overflows the candidates sorts unseen from here)
         path = sample_path(temps, top_ks, top_ps)
@@ -2181,8 +2334,13 @@ class InferenceEngine:
             SERVING_SAMPLE_TICKS_SELECT.add(1)
         else:
             SERVING_SAMPLE_TICKS_SORT.add(1)
+        behind = self._inflight is not None     # a tick is still unread
+        if behind:
+            SERVING_DECODE_TICKS_AHEAD.add(1)
+        else:
+            SERVING_DECODE_TICKS_SYNCED.add(1)
         span_args = {"batch": len(active), "tick": self._ticks,
-                     "sample_path": path}
+                     "sample_path": path, "ahead": int(behind)}
         if self.replica_id is not None:
             span_args["replica"] = self.replica_id
         if self._shards > 1:
@@ -2190,15 +2348,13 @@ class InferenceEngine:
             span_args["shard_load"] = self._shard_load(active)
         if use_spec:
             span_args["spec_k"] = self.spec_k
-        t0 = time.perf_counter()
-        health = None
-        # span_args is serialized when the span closes, so the spec
-        # proposed/accepted counts added below land in the trace event
+        # the span's event holds span_args itself: what the tick's read
+        # adds later (router stats, speculation counts) lands in it
         with span("serving.decode_step", cat="serving", args=span_args):
+            t0 = time.perf_counter()
             if use_spec:
-                out, n_emit, health = self._spec_dispatch(
-                    active, positions, tokens, rids, steps, temps,
-                    top_ks, top_ps)
+                outs = self._spec_dispatch(active, positions, tokens, rids,
+                                           steps, temps, top_ks, top_ps)
             else:
                 # table width bucketed to the live maximum (next pow2):
                 # attention/gather work tracks LIVE tokens, not the
@@ -2228,9 +2384,10 @@ class InferenceEngine:
                     SERVING_KV_ROWS_WRITTEN.add(len(active) * layers)
                 got = self._decode_paged_jit(
                     self._decode_params, *self.cache.pool, tables,
-                    positions, tokens, self._base_key, rids, steps,
-                    temps, top_ks, top_ps, mask_arg)
-                moe_stats = None
+                    positions, tokens, self._prev_toks, use_prev,
+                    self._base_key, rids, steps, temps, top_ks, top_ps,
+                    mask_arg)
+                moe_stats = health = None
                 if self._routed:
                     *got, moe_stats = got
                 if self._watchdog is not None:
@@ -2238,25 +2395,84 @@ class InferenceEngine:
                 else:
                     out, *pool = got
                 self.cache.pool = tuple(pool)
-                with span("serving.device_wait", cat="serving",
-                          args=self._tick_args()):
-                    out = np.asarray(out)
-                n_emit = None
-                if moe_stats is not None:
-                    self._note_moe(moe_stats, span_args)
-                self._note_moe_pending()
-            if use_spec:
-                span_args["proposed"] = self.spec_k * len(active)
-                span_args["accepted"] = int(sum(int(n_emit[s]) - 1
-                                               for s in active))
-        tick_ms = (time.perf_counter() - t0) * 1e3
+                self._prev_toks = out
+                outs = (out, None, health, moe_stats)
+            tick = _Tick(*outs, {s: self._slots[s] for s in active}, t0,
+                         span_args)
+            if ahead:
+                done, self._inflight = self._inflight, tick
+            else:
+                done = tick
+            if done is not None:
+                self._read(done)
+        return done
+
+    def _spec_dispatch(self, active, positions, tokens, rids, steps, temps,
+                       top_ks, top_ps):
+        """Dispatch the one-program speculative tick: draft proposes
+        spec_k, target verifies k+1 positions, rejection sampling
+        accepts. Returns its outputs on the device: (out_tokens (B, k+1),
+        n_emit (B,), health (B,) or None, no router stats) — health only
+        when the watchdog is armed, computed over every verify position
+        inside the same compiled program."""
+        health = None
+        tables = self.cache.tables_array(active)
+        tables = tables[:, :self._width_bucket(
+            max(len(self.cache.block_tables[s]) for s in active))]
+        got = self._spec_paged_jit(
+            self._decode_params, self._draft_params, self.cache.kb,
+            self.cache.vb, self.draft_cache.k, self.draft_cache.v,
+            tables, positions, tokens, self._base_key, rids, steps,
+            temps, top_ks, top_ps)
+        if self._watchdog is not None:
+            (out, n_emit, health, self.cache.kb, self.cache.vb,
+             self.draft_cache.k, self.draft_cache.v) = got
+        else:
+            (out, n_emit, self.cache.kb, self.cache.vb,
+             self.draft_cache.k, self.draft_cache.v) = got
+        return out, n_emit, health, None
+
+    def _read(self, tick: _Tick) -> None:
+        """Block on a tick's tokens (``serving.device_wait``); then its
+        router stats, and a speculative tick's counts, go to its span's
+        args."""
+        with span("serving.device_wait", cat="serving",
+                  args=self._tick_args()):
+            tick.out = np.asarray(tick.out)
+            if tick.n_emit is not None:
+                tick.n_emit = np.asarray(tick.n_emit)
+            if tick.health is not None:
+                tick.health = np.asarray(tick.health)
+        tick.ms = (time.perf_counter() - tick.t0) * 1e3
+        if tick.moe is not None:
+            self._note_moe(tick.moe, tick.args)
+        if tick.n_emit is not None:
+            tick.args["proposed"] = self.spec_k * len(tick.lanes)
+            tick.args["accepted"] = int(sum(int(tick.n_emit[s]) - 1
+                                            for s in tick.lanes))
+
+    def _sync(self) -> None:
+        """Read the tick in flight, if one is, and emit its tokens."""
+        tick, self._inflight = self._inflight, None
+        if tick is None:
+            return
+        self._read(tick)
+        self._emit_tick(tick)
+        self._note_moe_pending()
+
+    def _emit_tick(self, tick: _Tick) -> None:
+        """A read tick's latency to the gauges and the watchdog, then its
+        tokens to their streams (``serving.emit``). A lane whose slot no
+        longer holds the request it ran for (the stream ended at the
+        tick before, or was cancelled) has its result discarded."""
+        tick_ms = tick.ms
         self._note_ms(SERVING_DECODE_MS, "_decode_ms", tick_ms)
         SERVING_DECODE_TICK_MS.observe(tick_ms)
         if self.overload is not None:
             self.overload.observe_tick(tick_ms)
         if self._watchdog is not None:
-            poisoned = [] if health is None else \
-                [s for s in active if not bool(np.asarray(health)[s])]
+            poisoned = [] if tick.health is None else \
+                [s for s in tick.lanes if not bool(tick.health[s])]
             if poisoned:
                 SERVING_WATCHDOG_TRIPS.add(len(poisoned))
                 # the whole tick's outputs are dropped: poisoned streams
@@ -2267,11 +2483,14 @@ class InferenceEngine:
             self._watchdog_latency(tick_ms)
 
         # push, finish, evict and the gauges: host work after the wait
+        out, n_emit = tick.out, tick.n_emit
         with span("serving.emit", cat="serving", args=self._tick_args()):
-            emitted = 0
+            emitted = discarded = 0
             traced = []   # (req, tokens pushed) for per-request tick events
-            for s in active:
-                st = self._slots[s]
+            for s, st in tick.lanes.items():
+                if self._slots[s] is not st:
+                    discarded += 1
+                    continue
                 burst = [int(out[s])] if n_emit is None \
                     else [int(t) for t in out[s, :int(n_emit[s])]]
                 pushed = 0
@@ -2297,46 +2516,21 @@ class InferenceEngine:
                 dur = tick_ms / 1e3
                 for req, n_toks in traced:
                     rq_args = req.trace.args(rid=req.rid, tokens=n_toks,
-                                             tick=self._ticks)
+                                             tick=tick.args["tick"])
                     if self.replica_id is not None:
                         rq_args["replica"] = self.replica_id
-                    emit_complete("serving.decode_tick", t0, dur,
+                    emit_complete("serving.decode_tick", tick.t0, dur,
                                   cat="serving", args=rq_args)
-                    emit_flow("t", req.trace.trace_id, t0)
-            if use_spec:
-                self._note_spec(self.spec_k * len(active),
-                                int(sum(int(n_emit[s]) - 1 for s in active)))
+                    emit_flow("t", req.trace.trace_id, tick.t0)
+            if discarded:
+                tick.args["lanes_discarded"] = discarded
+                SERVING_DECODE_LANES_DISCARDED.add(discarded)
+            if n_emit is not None:
+                self._note_spec(tick.args["proposed"], tick.args["accepted"])
             self._note_tokens(emitted)
             SERVING_SLOT_OCCUPANCY.set(self.cache.occupancy)
             # refresh kv_fragmentation vs lengths
             self.cache.update_gauges()
-
-    def _spec_dispatch(self, active, positions, tokens, rids, steps, temps,
-                       top_ks, top_ps):
-        """Run the one-program speculative tick: draft proposes spec_k,
-        target verifies k+1 positions, rejection sampling accepts.
-        Returns (out_tokens (B, k+1) np, n_emit (B,) np, health (B,) np
-        or None) — health only when the watchdog is armed, computed over
-        every verify position inside the same compiled program."""
-        health = None
-        tables = self.cache.tables_array(active)
-        tables = tables[:, :self._width_bucket(
-            max(len(self.cache.block_tables[s]) for s in active))]
-        got = self._spec_paged_jit(
-            self._decode_params, self._draft_params, self.cache.kb,
-            self.cache.vb, self.draft_cache.k, self.draft_cache.v,
-            tables, positions, tokens, self._base_key, rids, steps,
-            temps, top_ks, top_ps)
-        if self._watchdog is not None:
-            (out, n_emit, health, self.cache.kb, self.cache.vb,
-             self.draft_cache.k, self.draft_cache.v) = got
-        else:
-            (out, n_emit, self.cache.kb, self.cache.vb,
-             self.draft_cache.k, self.draft_cache.v) = got
-        with span("serving.device_wait", cat="serving",
-                  args=self._tick_args()):
-            return (np.asarray(out), np.asarray(n_emit),
-                    None if health is None else np.asarray(health))
 
     def _finish_reason(self, st: _Slot, tok: int) -> Optional[str]:
         """Why generation stops after emitting ``tok`` (None = keep

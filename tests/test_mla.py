@@ -426,7 +426,8 @@ def test_a_gpt_engine_is_what_it_was():
         i32 = np.zeros(2, np.int32)
         dec = eng._decode_paged_jit.lower(
             eng._decode_params, eng.cache.kb, eng.cache.vb,
-            np.zeros((2, 4), np.int32), i32, i32, eng._base_key, i32, i32,
+            np.zeros((2, 4), np.int32), i32, i32, eng._prev_toks,
+            np.zeros(2, bool), eng._base_key, i32, i32,
             np.zeros(2, np.float32), i32, np.ones(2, np.float32),
             eng._mask_dev)
         chk = eng._chunk_jit.lower(
@@ -451,8 +452,9 @@ def test_the_programs_carry_the_router_and_experts_scopes(tiny):
         i32 = np.zeros(2, np.int32)
         low = eng._decode_paged_jit.lower(
             eng._decode_params, *eng.cache.pool, np.zeros((2, 4), np.int32),
-            i32, i32, eng._base_key, i32, i32, np.zeros(2, np.float32), i32,
-            np.ones(2, np.float32), eng._mask_dev)
+            i32, i32, eng._prev_toks, np.zeros(2, bool), eng._base_key, i32,
+            i32, np.zeros(2, np.float32), i32, np.ones(2, np.float32),
+            eng._mask_dev)
         assert "module @jit__decode_paged_fn " in low.as_text()
         labels = set(trace.op_scopes(low.compile().as_text()).values())
         for scope in ("router", "experts", "attn", "kv_pool", "mlp", "head",

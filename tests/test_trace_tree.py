@@ -130,14 +130,21 @@ class TestTurnTree:
             assert e["ts"] + e["dur"] <= turn["ts"] + turn["dur"] + 1
 
     def test_device_wait_nests_in_decode_step(self, events):
+        """Every tick is read once, by one ``serving.device_wait``. A
+        turn that dispatches a tick reads inside its
+        ``serving.decode_step`` (the tick before, when that one was
+        still in flight); a turn that dispatches none, because its lanes'
+        last tokens were in flight, reads in the turn itself."""
+        turns = {e["args"]["tick"]: e for e in events if e["name"] == TURN}
         steps = {e["args"]["tick"]: e for e in events
                  if e["name"] == "serving.decode_step"}
         waits = [e for e in events if e["name"] == "serving.device_wait"]
         assert waits and len(waits) == len(steps)
         for e in waits:
-            step = steps[e["args"]["tick"]]
-            assert step["ts"] - 1 <= e["ts"]
-            assert e["ts"] + e["dur"] <= step["ts"] + step["dur"] + 1
+            outer = steps.get(e["args"]["tick"], turns[e["args"]["tick"]])
+            assert outer["ts"] - 1 <= e["ts"]
+            assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1
+        assert {s["args"]["ahead"] for s in steps.values()} == {0, 1}
 
     def test_turn_children_do_not_overlap(self, events):
         by_tick = collections.defaultdict(list)
@@ -315,6 +322,29 @@ class TestChunkCounter:
             assert out[f"sample_ticks_{p}"] == n
             assert out[f"sample_{p}_share"] == pytest.approx(n / len(ticks))
 
+    def test_ticks_ahead_synced_and_discarded_on_the_span_and_in_the_registry(
+            self, engine):
+        """Each decode tick says whether it left with the tick before
+        still unread (the span's ``ahead``), and a tick whose lane
+        results were thrown away says how many: three counters by the
+        same amounts, and the serving report's three numbers."""
+        names = ("serving_decode_ticks_ahead", "serving_decode_ticks_synced",
+                 "serving_decode_lanes_discarded")
+        assert set(names) <= set(monitor.DEFAULT_STATS)
+        before = [monitor.stat_get(n) for n in names]
+        events, _, _ = _traced_run(engine(n_slots=4), lengths=(9, 12, 40),
+                                   new=6)
+        ticks = [e["args"] for e in events
+                 if e["name"] == "serving.decode_step"]
+        counted = [monitor.stat_get(n) - b for n, b in zip(names, before)]
+        assert counted[0] == sum(a["ahead"] for a in ticks) > 0
+        assert counted[1] == len(ticks) - counted[0] >= 1
+        assert counted[2] == sum(a.get("lanes_discarded", 0)
+                                 for a in ticks) == 0
+        out = _serving_report(events)
+        assert [out["decode_ticks_ahead"], out["decode_ticks_synced"],
+                out["decode_lanes_discarded"]] == counted
+
     def test_graftlint_gauges_clean(self):
         from paddle_tpu.analysis import run_lint
 
@@ -401,6 +431,7 @@ class TestOpScopes:
             low = eng._decode_paged_jit.lower(
                 eng._decode_params, eng.cache.kb, eng.cache.vb,
                 np.zeros((eng.n_slots, 4), np.int32), i32, i32,
+                eng._prev_toks, np.zeros(eng.n_slots, bool),
                 eng._base_key, i32, i32, np.zeros(eng.n_slots, np.float32),
                 i32, np.ones(eng.n_slots, np.float32), eng._mask_dev)
         else:

@@ -390,7 +390,12 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
     sampling went each way (the spans'
     ``sample_path``: ``greedy`` argmax, ``select`` among a row's largest
     entries, ``sort`` of the vocabulary; the engine counts the same
-    ticks in ``serving_sample_ticks_<path>``)."""
+    ticks in ``serving_sample_ticks_<path>``), how many ticks left with
+    the tick before still in flight (the spans' ``ahead``: the engine's
+    ``serving_decode_ticks_ahead``, the rest
+    ``serving_decode_ticks_synced``) and how many lane results were
+    never pushed (``lanes_discarded``, the engine's
+    ``serving_decode_lanes_discarded``)."""
     pre = [r for r in rows if r["name"] == "serving.prefill"]
     chk = [r for r in rows if r["name"] == "serving.prefill_chunk"]
     dec = [r for r in rows if r["name"] == "serving.decode_step"]
@@ -434,6 +439,13 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
         if moved:
             out.update(state_slots_live=sum(moved),
                        state_slots_live_a_tick=sum(moved) / len(moved))
+        ahead = [int(a["ahead"]) for a in ticks if "ahead" in a]
+        if ahead:
+            out.update(
+                decode_ticks_ahead=sum(ahead),
+                decode_ticks_synced=len(ahead) - sum(ahead),
+                decode_lanes_discarded=sum(
+                    int(a.get("lanes_discarded", 0)) for a in ticks))
         paths = [a["sample_path"] for a in ticks if "sample_path" in a]
         for path in ("greedy", "select", "sort") if paths else ():
             out[f"sample_ticks_{path}"] = paths.count(path)
