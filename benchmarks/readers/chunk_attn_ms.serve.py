@@ -1,0 +1,8 @@
+"""``chunk_attn_ms.serve``: device self time a run of the engine's
+prefill chunk in the scopes ``attn``, ``kv_pool`` and ``retention``
+(``serve_scopes``)."""
+from benchmarks.readers import serve_scopes
+
+
+def read(ctx):
+    return serve_scopes.group_ms(ctx, serve_scopes.CHUNK, "attn")

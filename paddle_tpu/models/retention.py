@@ -186,7 +186,8 @@ def _run_layers(cfg: RetentionConfig, params, x, state, mix):
         n = _rms(x, p["ln1"], cfg.rms_eps, cfg.dtype)
         with jax.named_scope("retention"):
             y, state = mix(p, n, state, li)
-        x = x + _into_residual(y.astype(cfg.dtype), p["wo"])
+            y = _into_residual(y.astype(cfg.dtype), p["wo"])
+        x = x + y
         return (_dense_ffn(cfg, p, x), state), None
 
     (x, state), _ = jax.lax.scan(

@@ -23,7 +23,8 @@ jax profiler session runs beside it the program's spans sit in the same
 callbacks let a program add what it can only know about itself when the
 window closes (``op_scopes``: which phase and ``named_scope`` each
 compiled instruction came from — the profiler's device events carry the
-bare instruction name and nothing else).
+bare instruction name and nothing else); a component that runs several
+programs keeps the ones a window saw in a `ProgramLog`.
 """
 from __future__ import annotations
 
@@ -38,7 +39,7 @@ import time
 __all__ = ["TraceWriter", "TRACING", "FLIGHT", "is_tracing",
            "start_tracing", "stop_tracing", "get_writer", "span",
            "recording", "emit_complete", "emit_instant", "emit_flow",
-           "on_stop", "op_scopes", "emit_op_scopes"]
+           "on_stop", "op_scopes", "emit_op_scopes", "ProgramLog"]
 
 # shared mutable gate — hot paths read TRACING[0] directly
 TRACING = [False]
@@ -210,14 +211,15 @@ def recording() -> bool:
 
 
 def emit_complete(name: str, ts: float, dur: float, cat: str = "op",
-                  args: dict | None = None) -> None:
+                  args: dict | None = None, tid: int | None = None) -> None:
     """One complete event to every live consumer (trace writer when
-    tracing, flight-recorder ring when armed)."""
+    tracing, flight-recorder ring when armed); ``tid``: the thread whose
+    line it lies on, where not the caller's."""
     if TRACING[0]:
-        _writer.add_complete(name, ts, dur, cat=cat, args=args)
+        _writer.add_complete(name, ts, dur, tid=tid, cat=cat, args=args)
     rec = FLIGHT[0]
     if rec is not None:
-        rec.add_complete(name, ts, dur, cat=cat, args=args)
+        rec.add_complete(name, ts, dur, tid=tid, cat=cat, args=args)
 
 
 def emit_instant(name: str, ts: float, cat: str = "instant") -> None:
@@ -300,22 +302,27 @@ def _scopes_of(op_name: str) -> list:
     return out
 
 
-def op_scopes(hlo_text: str) -> dict:
+def op_scopes(hlo_text: str, known=None) -> dict:
     """{instruction name: "phase/scope"} from ``compiled.as_text()``.
 
     Phase is ``optimizer`` if a scope of that name is in the
     instruction's ``op_name``, else ``backward`` if ``transpose(`` is
     (forward work recomputed for the backward counts there), else
     ``forward``; scope is the innermost ``named_scope`` (``backward/attn``,
-    or the bare phase where there is none). An instruction the compiler
-    made without metadata (the async copies and slices that prefetch an
-    operand) takes the label of the first instruction that uses it; one
-    nothing labelled uses is left out, and a reader counts it unplaced."""
+    or the bare phase where there is none). ``known``: the scope names
+    that count, where a caller gives them; any other entry is passed
+    over, so a Pallas kernel (whose ``name=`` sits on the stack inside
+    the scope that called it) takes its caller's scope. An instruction
+    the compiler made without metadata (the async copies and slices that
+    prefetch an operand), or with an ``op_name`` of its own that is no
+    path (``ragged-dot-none``: a TPU's grouped matmul), takes the label
+    of the first instruction that uses it; one nothing labelled uses is
+    left out, and a reader counts it unplaced."""
     instrs = _HLO_INSTR.findall(hlo_text)
     table = {}
     for name, rest in instrs:
         op = _OP_NAME.search(rest)
-        if op is None:
+        if op is None or "/" not in op.group(1):
             continue
         scopes = _scopes_of(op.group(1))
         if "optimizer" in scopes:
@@ -325,6 +332,8 @@ def op_scopes(hlo_text: str) -> dict:
             phase = "backward"
         else:
             phase = "forward"
+        if known is not None:
+            scopes = [s for s in scopes if s in known]
         table[name] = phase + "/" + scopes[-1] if scopes else phase
     # users come after definitions: walking back, each labelled user
     # hands its label to the operands that have none (the earliest wins)
@@ -340,7 +349,69 @@ def op_scopes(hlo_text: str) -> dict:
     return inherited
 
 
-def emit_op_scopes(writer: TraceWriter, program: str, hlo_text: str) -> None:
-    """The ``op_scopes`` metadata event of one compiled program."""
-    writer.add_metadata("op_scopes", {"program": program,
-                                      "scopes": op_scopes(hlo_text)})
+def emit_op_scopes(writer: TraceWriter, program: str, hlo_text: str,
+                   known=None, signature: str | None = None) -> None:
+    """The ``op_scopes`` metadata event of one compiled program, with the
+    ``signature`` it was compiled for where a caller names one."""
+    args = {"program": program, "scopes": op_scopes(hlo_text, known)}
+    if signature is not None:
+        args["signature"] = signature
+    writer.add_metadata("op_scopes", args)
+
+
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+
+
+def _aval(x):
+    """An argument as a lowering must see it again to find the same
+    executable: a jax array as its shape, dtype and weak type, with its
+    sharding only where it is committed (an uncommitted array lowered
+    with one is another program, and a compile); anything else (numpy
+    arrays, scalars) as it is."""
+    import jax
+
+    if not isinstance(x, jax.Array):
+        return x
+    a = jax.typeof(x)
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, weak_type=a.weak_type,
+                                sharding=x.sharding if x.committed else None)
+
+
+class ProgramLog:
+    """The jitted programs a component dispatched in a traced window, each
+    kept once, by the avals it was called with, so that the window's
+    ``on_stop`` can look its executable up again and write its
+    ``op_scopes`` table (the ``DistributedTrainStep`` idiom for a
+    component that runs more than one program)."""
+
+    def __init__(self):
+        self._kept = {}
+        self._lock = threading.Lock()
+
+    def note(self, fn, args, signature) -> bool:
+        """For a caller that found ``TRACING[0]`` set: one dict lookup on
+        the jitted ``fn`` (one executable: a program at one signature).
+        The first time a window sees it, its arguments are kept as avals;
+        returns True then."""
+        if fn in self._kept:
+            return False
+        import jax
+
+        avals = jax.tree_util.tree_map(_aval, args)
+        with self._lock:
+            self._kept.setdefault(fn, (avals, signature))
+        return True
+
+    def emit(self, writer: TraceWriter, known=None) -> int:
+        """One ``op_scopes`` event for each program kept, named as the
+        profiler's ``XLA Modules`` line names its runs (less the ``(N)``),
+        with its ``signature``; empties the log. Lowering the kept avals
+        again finds jax's own executable: no compiler runs. Returns how
+        many tables it wrote."""
+        with self._lock:
+            kept, self._kept = self._kept, {}
+        for fn, (avals, signature) in kept.items():
+            text = fn.lower(*avals).compile().as_text()
+            emit_op_scopes(writer, _HLO_MODULE.match(text).group(1), text,
+                           known, signature=signature)
+        return len(kept)

@@ -199,11 +199,26 @@ pool, summed by ``serving_kv_rows_written``) beside ``kv_rows_grid``
 put), and ``sample_path``
 (``greedy`` / ``select`` / ``sort``: which way the tick's sampling goes,
 by its rows' parameters; ``serving_sample_ticks_<path>`` count the ticks
-of each). The jitted programs carry
+of each). ``serving.device_wait`` names the turn whose tick it reads
+(``reads``). The scheduler's stretch with no work (no queue, no open
+slot, no tick in flight, no host call) is one ``serving.idle`` span, no
+``rid``, emitted when the stretch ends. The jitted programs carry
 ``jax.named_scope``s (``kv_pool``, ``sampling``, and the model's
-``embed`` / ``ln`` / ``attn`` / ``mlp`` / ``head``) for xprof; the engine
-registers no ``on_stop`` table: it keeps serving while another thread
-stops the trace.
+``embed`` / ``ln`` / ``attn`` / ``mlp`` / ``router`` / ``experts`` /
+``retention`` / ``head``). Each signature of a step program (a table
+width; a chunk's padded length and width) is a jit of its own, named for
+it by ``_program`` (``jit__decode_paged_fn_w64`` on the profiler's
+``XLA Modules`` line), so a run on a trace names its executable. While
+tracing, each dispatch looks its jit up in the window's
+``monitor.trace.ProgramLog`` (the first sighting keeps the avals and
+registers one ``on_stop`` callback a window); when the window stops, the
+callback writes the idle stretch still open, one ``op_scopes`` table for
+each program kept (with its ``signature``), a ``serving.op_scopes`` span
+over its own time and one ``serving_engine`` event (the spans the engine
+records, the callback's ``seconds``). It runs on the thread that stops
+the trace while the scheduler serves on; the lookups find jax's
+executables, so no compiler runs. Tracing off, a dispatch pays one list
+index.
 """
 from __future__ import annotations
 
@@ -249,7 +264,8 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
 from ..resilience import faults as _faults
 from ..resilience.sentinel import logits_finite
 from ..monitor.flight import arm_flight_recorder, dump_flight
-from ..monitor.trace import emit_complete, emit_flow, recording, span
+from ..monitor.trace import (TRACING, ProgramLog, emit_complete, emit_flow,
+                             on_stop, recording, span)
 from .kv_cache import KVCache, PagedKVCache
 from .prefix_cache import RadixPrefixCache
 from .sampling import (DRAFT_SALT, sample_one, sample_path,
@@ -267,6 +283,19 @@ _WARM_RID_BASE = 2**30
 
 # _prep_decode: a projected table cannot grow from free blocks alone
 _NO_ROOM = object()
+
+# the named_scopes of the engine's programs that their op_scopes tables
+# keep: the models' layers and the sampler (a Pallas kernel's own name,
+# which jax puts on the stack, passes to the scope that called it)
+_SCOPES = frozenset(("embed", "ln", "attn", "kv_pool", "retention", "mlp",
+                     "router", "experts", "head", "sampling"))
+# every span the engine records, as its serving_engine event lists them
+_SPANS = ("serving.turn", "serving.admit", "serving.prefill_chunk",
+          "serving.first_token", "serving.decode_prep",
+          "serving.decode_step", "serving.device_wait", "serving.emit",
+          "serving.idle", "serving.queue_wait", "serving.admit_to_first",
+          "serving.request_done", "serving.decode_tick",
+          "serving.failover_hop", "serving.op_scopes")
 
 
 class QueueFull(RuntimeError):
@@ -500,7 +529,7 @@ class _Slot:
         self.resume_last = None       # last token of a preempted run
         self.admit_order = 0          # preemption picks the youngest
         self.tail_mode = False        # prefix hit: chunks continue from an
-        #                               unaligned cached length (_tail_jit)
+        #                               unaligned cached length (_tail_fn)
         self.t_admit = time.perf_counter()  # serving.admit_to_first starts
         self.chunks = 0               # prefill chunks run so far
 
@@ -750,9 +779,11 @@ class InferenceEngine:
         # donated: (params, kb, vb, ...) for the per-head pair
         self._n_pool = len(self.cache.pool)
         pool_args = tuple(range(1, 1 + self._n_pool))
-        self._decode_paged_jit = jax.jit(self._decode_paged_fn,
-                                         donate_argnums=pool_args)
-        self._chunk_jit = jax.jit(self._chunk_fn, donate_argnums=pool_args)
+        # the step programs by kind: (function, donated arguments, how a
+        # signature names it); one jit a signature, made by _program
+        self._kinds = {"decode": (self._decode_paged_fn, pool_args, "w{}"),
+                       "chunk": (self._chunk_fn, pool_args, "c{}_w{}")}
+        self._jits = {}
         self.n_slots = self.cache.n_slots
         if use_prefix and draft is not None:
             raise ValueError("prefix_cache and draft= are not combinable: "
@@ -766,7 +797,7 @@ class InferenceEngine:
                              "routed-expert path")
         if use_prefix:
             self._prefix = RadixPrefixCache(self.cache)
-            self._tail_jit = jax.jit(self._tail_fn, donate_argnums=(1, 2))
+            self._kinds["tail"] = (self._tail_fn, (1, 2), "c{}_w{}")
             self._cow_jit = jax.jit(self._cow_fn, donate_argnums=(0, 1))
         else:
             self._prefix = None
@@ -856,6 +887,12 @@ class InferenceEngine:
         # path touches the donated pool buffers, which only the
         # scheduler thread may do (guarded by self._cv)
         self._host_calls: collections.deque = collections.deque()
+        # the traced window's programs (kept while tracing only), whether
+        # its on_stop callback is registered, and when the scheduler's
+        # current stretch with no work began (None while it has work)
+        self._programs = ProgramLog()
+        self._trace_armed = False
+        self._idle_t0 = None
         self._thread = threading.Thread(target=self._run,
                                         name="serving-scheduler", daemon=True)
         self._thread.start()
@@ -932,10 +969,9 @@ class InferenceEngine:
         self.draft = (draft_cfg, self._draft_params)
         self.spec_k = int(spec_k)
         self._draft_len = draft_len
-        self._spec_paged_jit = jax.jit(self._spec_paged_fn,
-                                       donate_argnums=(2, 3, 4, 5))
-        self._chunk_spec_jit = jax.jit(self._chunk_spec_fn,
-                                       donate_argnums=(2, 3, 4, 5))
+        self._kinds["spec"] = (self._spec_paged_fn, (2, 3, 4, 5), "w{}")
+        self._kinds["chunk_spec"] = (self._chunk_spec_fn, (2, 3, 4, 5),
+                                     "c{}_w{}")
 
     def _build_cache(self):
         """Fresh zeroed block pool + accounting (construction and the
@@ -959,6 +995,26 @@ class InferenceEngine:
         return cache
 
     # -- compiled programs ---------------------------------------------------
+    def _program(self, kind: str, *dims):
+        """(jitted program, signature) of ``kind`` at one signature (a
+        table width; a chunk's padded length and width), made on first
+        use under a name of its own: ``jit__decode_paged_fn_w64`` on the
+        profiler's ``XLA Modules`` line, so that each run there names
+        its executable, and so the window's ``op_scopes`` table of it.
+        One executable a jit, as one a signature before."""
+        got = self._jits.get((kind, dims))
+        if got is None:
+            fn, donate, form = self._kinds[kind]
+            sig = form.format(*dims)
+
+            def run(*args):
+                return fn(*args)
+
+            run.__name__ = run.__qualname__ = f"{fn.__name__}_{sig}"
+            got = self._jits[kind, dims] = (
+                jax.jit(run, donate_argnums=donate), sig)
+        return got
+
     def _sample_args(self, logits, base_key, rids, steps, temps, top_ks,
                     top_ps, mask):
         with jax.named_scope("sampling"):
@@ -1168,6 +1224,7 @@ class InferenceEngine:
             req._t_submit = time.monotonic()
             self._queue.append(req)
             SERVING_QUEUE_DEPTH.set(len(self._queue))
+            self._end_idle()
             self._cv.notify_all()
         return req
 
@@ -1209,6 +1266,7 @@ class InferenceEngine:
             self._rid = max(self._rid, req.rid + 1)
             self._queue.append(req)
             SERVING_QUEUE_DEPTH.set(len(self._queue))
+            self._end_idle()
             self._cv.notify_all()
 
     # -- replica lifecycle (serving/lifecycle.py, ISSUE 14) ------------------
@@ -1233,6 +1291,7 @@ class InferenceEngine:
             req._t_submit = time.monotonic()
             self._queue.append(req)        # warm runs pre-traffic: the
             SERVING_QUEUE_DEPTH.set(len(self._queue))  # bound is moot
+            self._end_idle()
             self._cv.notify_all()
         return req
 
@@ -1271,7 +1330,7 @@ class InferenceEngine:
         def lower(eng):
             width = eng.cache.table_width if table_width is None \
                 else eng._width_bucket(int(table_width))
-            return eng._decode_paged_jit.lower(
+            return eng._program("decode", width)[0].lower(
                 eng._decode_params, *eng.cache.pool,
                 np.zeros((n, width), np.int32), i32, i32, eng._prev_toks,
                 np.zeros(n, bool), *tail)
@@ -1294,6 +1353,7 @@ class InferenceEngine:
         with self._cv:
             self._check_open()
             self._host_calls.append(call)
+            self._end_idle()
             self._cv.notify_all()
         if not call.done.wait(timeout):
             raise TimeoutError("scheduler did not service the host call "
@@ -1607,6 +1667,8 @@ class InferenceEngine:
                         raise ReplicaEvacuated(
                             f"replica {self.replica_id} evacuated "
                             "(drain-shrink)")
+                    if TRACING[0] and not self._trace_armed:
+                        self._arm_trace()
                     busy = bool(self._queue) or self._inflight is not None \
                         or any(s is not None for s in self._slots)
                     if self._stop and (not self._drain or not busy):
@@ -1622,8 +1684,11 @@ class InferenceEngine:
                         calls = list(self._host_calls)
                         self._host_calls.clear()
                     if not busy and not calls:
+                        if self._idle_t0 is None:
+                            self._idle_t0 = time.perf_counter()
                         self._cv.wait(0.05)
                         continue
+                    self._end_idle()
                     die = self._die_tick
                 if calls:
                     for c in calls:
@@ -1669,6 +1734,7 @@ class InferenceEngine:
             self._abort(e)
         finally:
             with self._cv:
+                self._end_idle()
                 self._stop = True
                 leftovers = list(self._queue)
                 self._queue.clear()
@@ -1699,6 +1765,63 @@ class InferenceEngine:
         ``recording()`` only: a trace span id is allocated)."""
         args = self._tick_args(rid=req.rid, **extra)
         return args if req.trace is None else req.trace.args(**args)
+
+    # -- the traced window ---------------------------------------------------
+    def _end_idle(self) -> None:
+        """Work reached the scheduler (a request or a host call queued),
+        or it stops: its stretch with none (no queue, no open slot, no
+        tick in flight, no host call) ends, one ``serving.idle`` span on
+        the scheduler's line where anything records. Under ``self._cv``,
+        on whichever thread brought the work: the scheduler's wake-up is
+        time with work."""
+        t0, self._idle_t0 = self._idle_t0, None
+        if t0 is not None and recording():
+            emit_complete("serving.idle", t0, time.perf_counter() - t0,
+                          cat="serving", tid=self._tid())
+
+    def _tid(self) -> int:
+        return (self._thread.ident or 0) & 0x7FFFFFFF
+
+    def _arm_trace(self) -> None:
+        """Register the window's ``on_stop`` callback, once a window."""
+        with self._cv:
+            if not self._trace_armed:
+                self._trace_armed = True
+                on_stop(self._on_trace_stop)
+
+    def _note_program(self, fn, args, signature: str) -> None:
+        """A dispatch under tracing: the window keeps the avals of
+        ``fn`` (one program at one signature, ``_program``) the first time
+        it sees it."""
+        if self._programs.note(fn, args, signature):
+            self._arm_trace()
+
+    def _on_trace_stop(self, writer) -> None:
+        """``on_stop``: the idle stretch still open, up to now (what is
+        left of it is another window's); one ``op_scopes`` table for each
+        program the window dispatched, its labels the engine's scopes;
+        then a ``serving.op_scopes`` span over the callback's own time
+        (the engine serves on, untraced, meanwhile) and the
+        ``serving_engine`` event: the spans the engine records and the
+        callback's seconds. Runs on the thread that stops the trace."""
+        t0 = time.perf_counter()
+        with self._cv:
+            self._trace_armed = False
+            if self._idle_t0 is not None:
+                writer.add_complete("serving.idle", self._idle_t0,
+                                    t0 - self._idle_t0, tid=self._tid(),
+                                    cat="serving")
+                self._idle_t0 = t0
+        tables = 0
+        try:
+            tables = self._programs.emit(writer, known=_SCOPES)
+        finally:
+            seconds = time.perf_counter() - t0
+            writer.add_complete("serving.op_scopes", t0, seconds,
+                                cat="serving", args={"tables": tables})
+            writer.add_metadata("serving_engine", {
+                "spans": list(_SPANS), "tables": tables,
+                "seconds": seconds, "replica": self.replica_id})
 
     def _check_open(self) -> None:
         """Fail fast once the scheduler is gone: nothing will ever drain
@@ -2022,22 +2145,28 @@ class InferenceEngine:
             row = self.cache.table_row(slot)[:self._width_bucket(
                 self.cache.blocks_for(st.length + c_pad))]
             if st.tail_mode:
-                logits, self.cache.kb, self.cache.vb = self._tail_jit(
-                    self._params, self.cache.kb, self.cache.vb,
-                    jnp.asarray(row), jnp.asarray(toks),
-                    np.int32(st.length))
+                kind, args = "tail", (self._params, self.cache.kb, self.cache.vb,
+                        jnp.asarray(row), jnp.asarray(toks),
+                        np.int32(st.length))
+            elif self.draft is not None:
+                kind, args = "chunk_spec", (self._params, self._draft_params, self.cache.kb,
+                        self.cache.vb, self.draft_cache.k,
+                        self.draft_cache.v, jnp.asarray(row), np.int32(slot),
+                        jnp.asarray(toks), np.int32(st.length))
+            else:
+                kind, args = "chunk", (self._params, *self.cache.pool, jnp.asarray(row),
+                        jnp.asarray(toks), np.int32(st.length),
+                        np.int32(c_true))
+            fn, sig = self._program(kind, c_pad, row.size)
+            if TRACING[0]:
+                self._note_program(fn, args, sig)
+            got = fn(*args)
+            if st.tail_mode:
+                logits, self.cache.kb, self.cache.vb = got
             elif self.draft is not None:
                 (logits, self.cache.kb, self.cache.vb, self.draft_cache.k,
-                 self.draft_cache.v) = self._chunk_spec_jit(
-                    self._params, self._draft_params, self.cache.kb,
-                    self.cache.vb, self.draft_cache.k, self.draft_cache.v,
-                    jnp.asarray(row), np.int32(slot), jnp.asarray(toks),
-                    np.int32(st.length))
+                 self.draft_cache.v) = got
             else:
-                got = self._chunk_jit(
-                    self._params, *self.cache.pool, jnp.asarray(row),
-                    jnp.asarray(toks), np.int32(st.length),
-                    np.int32(c_true))
                 logits = got[0]
                 self.cache.pool = tuple(got[1:1 + self._n_pool])
                 if self._model.routed:
@@ -2382,11 +2511,14 @@ class InferenceEngine:
                     span_args["kv_rows_written"] = len(active) * layers
                     span_args["kv_rows_grid"] = self.n_slots * layers
                     SERVING_KV_ROWS_WRITTEN.add(len(active) * layers)
-                got = self._decode_paged_jit(
-                    self._decode_params, *self.cache.pool, tables,
-                    positions, tokens, self._prev_toks, use_prev,
-                    self._base_key, rids, steps, temps, top_ks, top_ps,
-                    mask_arg)
+                fn, sig = self._program("decode", tables.shape[1])
+                args = (self._decode_params, *self.cache.pool, tables,
+                        positions, tokens, self._prev_toks, use_prev,
+                        self._base_key, rids, steps, temps, top_ks, top_ps,
+                        mask_arg)
+                if TRACING[0]:
+                    self._note_program(fn, args, sig)
+                got = fn(*args)
                 moe_stats = health = None
                 if self._routed:
                     *got, moe_stats = got
@@ -2419,11 +2551,14 @@ class InferenceEngine:
         tables = self.cache.tables_array(active)
         tables = tables[:, :self._width_bucket(
             max(len(self.cache.block_tables[s]) for s in active))]
-        got = self._spec_paged_jit(
-            self._decode_params, self._draft_params, self.cache.kb,
-            self.cache.vb, self.draft_cache.k, self.draft_cache.v,
-            tables, positions, tokens, self._base_key, rids, steps,
-            temps, top_ks, top_ps)
+        args = (self._decode_params, self._draft_params, self.cache.kb,
+                self.cache.vb, self.draft_cache.k, self.draft_cache.v,
+                tables, positions, tokens, self._base_key, rids, steps,
+                temps, top_ks, top_ps)
+        fn, sig = self._program("spec", tables.shape[1])
+        if TRACING[0]:
+            self._note_program(fn, args, sig)
+        got = fn(*args)
         if self._watchdog is not None:
             (out, n_emit, health, self.cache.kb, self.cache.vb,
              self.draft_cache.k, self.draft_cache.v) = got
@@ -2433,11 +2568,11 @@ class InferenceEngine:
         return out, n_emit, health, None
 
     def _read(self, tick: _Tick) -> None:
-        """Block on a tick's tokens (``serving.device_wait``); then its
-        router stats, and a speculative tick's counts, go to its span's
-        args."""
+        """Block on a tick's tokens (``serving.device_wait``, whose
+        ``reads`` is the turn that dispatched it); then its router stats,
+        and a speculative tick's counts, go to its span's args."""
         with span("serving.device_wait", cat="serving",
-                  args=self._tick_args()):
+                  args=self._tick_args(reads=tick.args["tick"])):
             tick.out = np.asarray(tick.out)
             if tick.n_emit is not None:
                 tick.n_emit = np.asarray(tick.n_emit)

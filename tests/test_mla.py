@@ -424,17 +424,17 @@ def test_a_gpt_engine_is_what_it_was():
         assert eng.cache.kb is eng.cache.pool[0]
         assert eng.cache.vb is eng.cache.pool[1]
         i32 = np.zeros(2, np.int32)
-        dec = eng._decode_paged_jit.lower(
+        dec = eng._program("decode", 4)[0].lower(
             eng._decode_params, eng.cache.kb, eng.cache.vb,
             np.zeros((2, 4), np.int32), i32, i32, eng._prev_toks,
             np.zeros(2, bool), eng._base_key, i32, i32,
             np.zeros(2, np.float32), i32, np.ones(2, np.float32),
             eng._mask_dev)
-        chk = eng._chunk_jit.lower(
+        chk = eng._program("chunk", 16, 4)[0].lower(
             eng._params, eng.cache.kb, eng.cache.vb, np.zeros(4, np.int32),
             np.zeros((1, 16), np.int32), np.int32(0), np.int32(16))
-        for low, name, n_out in ((dec, "jit__decode_paged_fn", 3),
-                                 (chk, "jit__chunk_fn", 3)):
+        for low, name, n_out in ((dec, "jit__decode_paged_fn_w4", 3),
+                                 (chk, "jit__chunk_fn_c16_w4", 3)):
             text = low.as_text()
             assert f"module @{name} " in text
             donated = [a.donated for a in jax.tree_util.tree_leaves(
@@ -450,12 +450,12 @@ def test_the_programs_carry_the_router_and_experts_scopes(tiny):
     eng = _engine(cfg, params)
     try:
         i32 = np.zeros(2, np.int32)
-        low = eng._decode_paged_jit.lower(
+        low = eng._program("decode", 4)[0].lower(
             eng._decode_params, *eng.cache.pool, np.zeros((2, 4), np.int32),
             i32, i32, eng._prev_toks, np.zeros(2, bool), eng._base_key, i32,
             i32, np.zeros(2, np.float32), i32, np.ones(2, np.float32),
             eng._mask_dev)
-        assert "module @jit__decode_paged_fn " in low.as_text()
+        assert "module @jit__decode_paged_fn_w4 " in low.as_text()
         labels = set(trace.op_scopes(low.compile().as_text()).values())
         for scope in ("router", "experts", "attn", "kv_pool", "mlp", "head",
                       "embed", "sampling", "ln"):

@@ -428,14 +428,14 @@ class TestOpScopes:
         eng = engine()
         i32 = np.zeros(eng.n_slots, np.int32)
         if fn == "_decode_paged_fn":
-            low = eng._decode_paged_jit.lower(
+            low = eng._program("decode", 4)[0].lower(
                 eng._decode_params, eng.cache.kb, eng.cache.vb,
                 np.zeros((eng.n_slots, 4), np.int32), i32, i32,
                 eng._prev_toks, np.zeros(eng.n_slots, bool),
                 eng._base_key, i32, i32, np.zeros(eng.n_slots, np.float32),
                 i32, np.ones(eng.n_slots, np.float32), eng._mask_dev)
         else:
-            low = eng._chunk_jit.lower(
+            low = eng._program("chunk", 16, 4)[0].lower(
                 eng._params, eng.cache.kb, eng.cache.vb,
                 np.zeros(4, np.int32), np.zeros((1, 16), np.int32),
                 np.int32(0), np.int32(16))
@@ -573,3 +573,232 @@ class TestAnnotation:
                                 ("power_retention_chunk",
                                  "power_retention_chunk")):
             assert [n for n in names if re.search(pattern, n)] == [kernel]
+
+
+# -- the engine's own tables, and its idle -----------------------------------
+
+def _mla():
+    from paddle_tpu.models import mla_tiny
+    from paddle_tpu.models.mla import mla_init
+
+    cfg = mla_tiny()
+    return cfg, mla_init(cfg, 0), {"n_blocks": 24, "prefix_cache": False}
+
+
+def _retention():
+    from paddle_tpu.models import retention_tiny
+    from paddle_tpu.models.retention import retention_init
+
+    cfg = retention_tiny()
+    return cfg, retention_init(cfg, 0), {"n_blocks": 3, "prefill_chunk": 128}
+
+
+MODELS = {"gpt": lambda: (CFG, PARAMS, {}), "mla": _mla,
+          "retention": _retention}
+# what each model's programs must label, beside forward/sampling
+LABELS = {"gpt": {"forward/attn", "forward/mlp", "forward/kv_pool"},
+          "mla": {"forward/attn", "forward/mlp", "forward/kv_pool",
+                  "forward/router", "forward/experts"},
+          "retention": {"forward/retention", "forward/mlp"}}
+
+
+def _serve(eng, lengths=(20, 9), new=4):
+    rng = np.random.default_rng(39)
+    for n in lengths:
+        eng.submit(rng.integers(0, eng.cfg.vocab_size, n).astype(np.int32),
+                   max_new_tokens=new).result(timeout=300)
+
+
+def _tables(events):
+    return [e["args"] for e in events if e.get("ph") == "M"
+            and e["name"] == "op_scopes"]
+
+
+class TestEngineTables:
+    """A traced window of a tiny engine of each model: one ``op_scopes``
+    table per (program, signature) it ran, written at ``stop_tracing()``
+    with no compiler run; nothing kept or registered with tracing off or
+    with only the flight recorder armed; a second window asks again."""
+
+    @pytest.fixture(scope="class", params=sorted(MODELS))
+    def window(self, request):
+        import jax.monitoring
+
+        from paddle_tpu.monitor.flight import (arm_flight_recorder,
+                                               disarm_flight_recorder)
+
+        cfg, params, kw = MODELS[request.param]()
+        kw = {"n_slots": 2, "block_size": 8, "prefill_chunk": 16,
+              "seed": 0, **kw}
+        eng = InferenceEngine(cfg, params, **kw)
+        compiles = []
+
+        def listen(name, *a, **k):
+            if name.endswith("backend_compile_duration"):
+                compiles.append(name)
+
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            _serve(eng)                               # every program, once
+            off = [(dict(eng._programs._kept), list(trace._on_stop))]
+            arm_flight_recorder()
+            try:
+                _serve(eng)
+            finally:
+                disarm_flight_recorder()
+            off.append((dict(eng._programs._kept), list(trace._on_stop)))
+            runs = []
+            for _ in range(2):
+                writer = monitor.start_tracing()
+                _serve(eng)
+                mark = len(compiles)
+                monitor.stop_tracing()
+                runs.append((writer.events(), compiles[mark:]))
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+            eng.shutdown(drain=False, timeout=30)
+        return {"kind": request.param, "off": off, "runs": runs,
+                "bs": eng.block_size, "slots": eng.n_slots}
+
+    def test_one_table_per_program_and_signature_it_ran(self, window):
+        events, _ = window["runs"][0]
+        got = [(t["program"], t["signature"]) for t in _tables(events)]
+        assert len(got) == len(set(got))
+        # a jit a signature, named for it: the profiler's name of its runs
+        by = {}
+        for program, sig in got:
+            assert program.endswith("_" + sig)
+            by.setdefault(program[:-len(sig) - 1], set()).add(sig)
+        assert set(by) == {"jit__decode_paged_fn", "jit__chunk_fn"}
+        widths = {e["args"]["decode_blocks_tabled"] // window["slots"]
+                  for e in events if e["name"] == "serving.decode_step"}
+        assert by["jit__decode_paged_fn"] == {f"w{w}" for w in widths}
+        bs = window["bs"]
+        chunks = {-(-e["args"]["chunk"] // bs) * bs for e in events
+                  if e["name"] == "serving.prefill_chunk"}
+        assert {int(s[1:].split("_")[0])
+                for s in by["jit__chunk_fn"]} == chunks
+
+    def test_labels_are_the_models_scopes(self, window):
+        events, _ = window["runs"][0]
+        labels = set().union(*(t["scopes"].values()
+                               for t in _tables(events)))
+        assert LABELS[window["kind"]] | {"forward/sampling"} <= labels
+        # a kernel's own name never stands in for its caller's scope
+        assert {lab.split("/", 1)[-1] for lab in labels if "/" in lab} \
+            <= {"embed", "ln", "attn", "kv_pool", "retention", "mlp",
+                "router", "experts", "head", "sampling"}
+
+    def test_no_compile_while_the_tables_are_made(self, window):
+        assert [c for _, c in window["runs"]] == [[], []]
+
+    @pytest.mark.parametrize("how", ["tracing_off", "flight_only"])
+    def test_nothing_kept_or_registered_untraced(self, window, how):
+        kept, callbacks = window["off"][how == "flight_only"]
+        assert kept == {} and callbacks == []
+
+    def test_a_second_window_asks_again(self, window):
+        (first, _), (second, _) = window["runs"]
+        tables = [sorted((t["program"], t["signature"])
+                         for t in _tables(evs)) for evs in (first, second)]
+        assert tables[0] == tables[1] and tables[0]
+        for evs in (first, second):
+            eng = [e["args"] for e in evs if e.get("ph") == "M"
+                   and e["name"] == "serving_engine"]
+            assert len(eng) == 1 and eng[0]["tables"] == len(tables[0])
+            assert "serving.idle" in eng[0]["spans"]
+            assert 0 < eng[0]["seconds"] < 60
+            own = [e for e in evs if e["name"] == "serving.op_scopes"]
+            assert len(own) == 1 and own[0]["args"]["tables"] \
+                == eng[0]["tables"]
+
+
+class TestProgramLog:
+    @pytest.mark.parametrize("arg, want", [
+        (np.zeros(3, np.int32), "numpy"),
+        (jnp.zeros(3), "uncommitted"),
+        (jax.device_put(jnp.zeros(3), jax.devices()[0]), "committed"),
+        (jnp.float32(1) + 1, "uncommitted")])
+    def test_kept_as_a_lowering_must_see_it(self, arg, want):
+        log = trace.ProgramLog()
+        assert log.note("fn", (arg,), "s")
+        assert not log.note("fn", (arg,), "s")            # one lookup
+        (kept,) = log._kept["fn"][0]
+        if want == "numpy":
+            assert kept is arg
+            return
+        assert isinstance(kept, jax.ShapeDtypeStruct)
+        assert (kept.shape, kept.dtype) == (arg.shape, arg.dtype)
+        assert kept.weak_type == jax.typeof(arg).weak_type
+        assert (kept.sharding is not None) == (want == "committed")
+
+    @pytest.mark.parametrize("op_name, want", [
+        ("jit(f)/while/body/closed_call/attn/jit(_paged_decode)/"
+         "pallas_paged_decode/pallas_call", "forward/attn"),
+        ("jit(f)/while/body/attn/kv_pool/pool_write_rows/pallas_call",
+         "forward/kv_pool"),
+        ("jit(f)/while/body/retention/power_retention_decode/pallas_call",
+         "forward/retention"),
+        ("jit(f)/flash_forward/pallas_call", "forward")])
+    def test_a_kernel_takes_its_callers_scope(self, op_name, want):
+        hlo = (f'  %k.1 = f32[2]{{0}} custom-call(%p), '
+               f'metadata={{op_name="{op_name}"}}\n')
+        known = ("attn", "kv_pool", "retention")
+        assert trace.op_scopes(hlo, known)["k.1"] == want
+        assert trace.op_scopes(hlo)["k.1"] \
+            == "forward/" + op_name.split("/")[-2]
+
+
+class TestIdleSpan:
+    def test_one_span_a_stretch_none_while_a_request_is_open(self, engine):
+        eng = engine()
+        _serve(eng, lengths=(9,))
+        time.sleep(0.1)
+        writer = monitor.start_tracing()
+        for n in (20, 9):
+            _serve(eng, lengths=(n,))
+            time.sleep(0.2)                 # four of the scheduler's waits
+        monitor.stop_tracing()
+        evs = writer.events()
+        idle = sorted((e for e in evs if e["name"] == "serving.idle"),
+                      key=lambda e: e["ts"])
+        # before the first request, between the two, after the second:
+        # the last one written at the stop, up to it
+        assert len(idle) == 3
+        assert all("args" not in e for e in idle)
+        stop = [e for e in evs if e["name"] == "serving.op_scopes"][0]
+        assert abs(idle[-1]["ts"] + idle[-1]["dur"] - stop["ts"]) <= 1
+        assert idle[1]["dur"] >= 150e3 and idle[2]["dur"] >= 150e3
+        opened = {e["args"]["rid"]: e["ts"] for e in evs
+                  if e["name"] == "serving.queue_wait"}
+        done = {e["args"]["rid"]: e["ts"] for e in evs
+                if e["name"] == "serving.request_done"}
+        assert len(opened) == len(done) == 2
+        # the submit that queues a request ends the stretch (the
+        # scheduler's wake-up is time with work), a few microseconds
+        # after it stamps the request
+        for rid, t0 in opened.items():
+            for e in idle:
+                assert e["ts"] + e["dur"] <= t0 + 50 or e["ts"] >= done[rid]
+
+    @pytest.mark.parametrize("flight", [False, True])
+    def test_none_with_tracing_off(self, engine, flight):
+        from paddle_tpu.monitor.flight import (arm_flight_recorder,
+                                               disarm_flight_recorder)
+
+        eng = engine()
+        writer = monitor.get_writer()
+        writer.clear()
+        rec = arm_flight_recorder() if flight else None
+        try:
+            for n in (20, 9):
+                _serve(eng, lengths=(n,))
+                time.sleep(0.12)
+        finally:
+            disarm_flight_recorder()
+        assert [e for e in writer.events()
+                if e["name"] == "serving.idle"] == []
+        if flight:
+            # the flight ring takes the stretches that ended while armed
+            ring = [e for e in rec.events() if e["name"] == "serving.idle"]
+            assert len(ring) >= 2
