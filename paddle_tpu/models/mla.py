@@ -392,17 +392,18 @@ def _moe_ffn(cfg: MLAConfig, p, experts, mi, x, live=None):
             p["router_w"], p["router_b"], z32, top_k=cfg.top_k,
             scale=cfg.routed_scale)
     with jax.named_scope("experts"):
-        y, counts, held, reads = moe_ffn_held(
+        y, *stats = moe_ffn_held(
             *experts, z, gates, idx, n_experts=cfg.n_experts,
             expert_offset=cfg.expert_offset, n_held=cfg.experts_held,
             group_base=mi * cfg.experts_held, live=live,
             out_dtype=jnp.float32)
     shared = _gated_mlp(cfg.dtype, z, p["s_gate"], p["s_up"], p["s_down"])
-    return x + y + shared, (counts, held, reads)
+    return x + y + shared, tuple(stats)
 
 
 def _no_stats(cfg: MLAConfig):
-    return jnp.zeros((cfg.n_experts,), jnp.int32), jnp.int32(0), jnp.int32(0)
+    return ((jnp.zeros((cfg.n_experts,), jnp.int32),)
+            + (jnp.int32(0),) * 3)
 
 
 def _add_stats(a, b):
@@ -459,7 +460,8 @@ def _embed(cfg: MLAConfig, params, tokens):
 
 def mla_forward(cfg: MLAConfig, params, tokens):
     """tokens (B, S) int32 -> (logits (B, S, V) f32, (expert counts,
-    held rows, expert reads)). Expanded causal attention, no cache."""
+    held rows, expert reads, kernel row tiles)). Expanded causal
+    attention, no cache."""
     B, S = tokens.shape
     pos = jnp.arange(S)
     causal = pos[:, None] >= pos[None, :]

@@ -383,6 +383,7 @@ DEFAULT_STATS = (
     "moe_assignments_routed",  # tokens x top-k x expert layers, over all experts
     "moe_assignments_held",    # of those, rows computed by experts held here
     "moe_expert_reads",        # (run, layer, held expert) with at least one row
+    "moe_kernel_tiles",        # row tiles the grouped expert kernel ran
     # cross-host serving fleet (ISSUE 19)
     "fleet_hosts",            # gauge: fleet hosts with a fresh heartbeat
     "fleet_replicas",         # gauge: remote replica proxies attached to the router
@@ -508,6 +509,7 @@ MOE_TOKENS_DROPPED = _registry.get_stat("moe_tokens_dropped")
 MOE_ASSIGNMENTS_ROUTED = _registry.get_stat("moe_assignments_routed")
 MOE_ASSIGNMENTS_HELD = _registry.get_stat("moe_assignments_held")
 MOE_EXPERT_READS = _registry.get_stat("moe_expert_reads")
+MOE_KERNEL_TILES = _registry.get_stat("moe_kernel_tiles")
 FLEET_HOSTS = _registry.get_stat("fleet_hosts")
 FLEET_REPLICAS = _registry.get_stat("fleet_replicas")
 FLEET_KV_TRANSFER_BYTES = _registry.get_stat("fleet_kv_transfer_bytes")

@@ -36,6 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..ops import moe_gmm
 from ..ops.moe_dispatch import moe_combine_scatter, moe_dispatch_gather
 
 __all__ = ["moe_route", "moe_ffn", "moe_capacity", "MoELayer",
@@ -207,7 +208,7 @@ def moe_route_sigmoid(router_w, router_b, x, *, top_k: int, scale: float):
 
 def moe_ffn_held(w_gate, w_up, w_down, x, gates, idx, *, n_experts: int,
                  expert_offset: int, n_held: int, group_base=0, live=None,
-                 out_dtype=None):
+                 out_dtype=None, interpret: bool = False):
     """The part of a routed gated-SiLU expert layer that the experts
     HELD here give: ``sum over chosen e in [expert_offset, expert_offset
     + n_held) of g_e E_e(x)``. What the other experts would add is left
@@ -225,11 +226,17 @@ def moe_ffn_held(w_gate, w_up, w_down, x, gates, idx, *, n_experts: int,
 
     Dropless by construction: every assignment that falls on a held
     expert becomes one row; the rows are sorted by expert and multiplied
-    group by group (``jax.lax.ragged_dot``: on a TPU the compiler's own
-    grouped matmul, which visits only the row tiles of groups that have
-    rows, so an expert with no row is not read). Returns ``(y (T, H),
+    group by group. On a TPU (shapes ``ops/moe_gmm.tileable`` takes) that
+    is ``ops/moe_gmm``'s kernel, which reads each held expert with a row
+    once a row tile, an expert with no row not at all, and adds each
+    row's gated output into its token's row itself; anywhere else
+    ``jax.lax.ragged_dot``, the rows put back in assignment order and
+    summed by gate. ``interpret`` runs the kernel in the interpreter, on
+    any backend. Returns ``(y (T, H),
     counts (n_experts,) i32 assignments per expert over live tokens,
-    held i32 rows computed, reads i32 held experts with a row)``."""
+    held i32 rows computed, reads i32 held experts with a row, tiles i32
+    the kernel's row tiles, 0 where it did not run)``: ``tiles / reads``
+    is how often a held expert's weights were read."""
     T, k = idx.shape
     cd = x.dtype
     G = w_gate.shape[0]
@@ -240,9 +247,21 @@ def moe_ffn_held(w_gate, w_up, w_down, x, gates, idx, *, n_experts: int,
                      * on[:, None, None].astype(jnp.int32), axis=(0, 1))
     sizes = counts[lo:hi]
     n_rows = jnp.sum(sizes)
+    reads = jnp.sum((sizes > 0).astype(jnp.int32))
     # held assignments first, by expert; the rest (key n_held) after
     key = jnp.where(held, idx - lo, n_held).reshape(-1)
     order = jnp.argsort(key, stable=True)
+    od = cd if out_dtype is None else out_dtype
+    w = jnp.where(held, gates, 0.0)
+    if moe_gmm.use_kernel(x, w_gate, w_down, k, n_experts, interpret):
+        tm = moe_gmm.row_tile(T * k, n_experts)
+        walk = moe_gmm.expert_tiles(sizes, tm,
+                                    moe_gmm.n_tiles(T * k, n_held, tm))
+        y = moe_gmm.grouped_ffn(
+            x, w_gate, w_up, w_down, (order // k).astype(jnp.int32),
+            w.reshape(-1)[order], walk, group_base, tm=tm,
+            interpret=interpret).astype(od)
+        return y, counts, n_rows, reads, walk.count[0]
     rows = x[order // k]                                       # (T*k, H)
     group_sizes = jax.lax.dynamic_update_slice(
         jnp.zeros((G,), jnp.int32), sizes, (group_base,))
@@ -251,14 +270,12 @@ def moe_ffn_held(w_gate, w_up, w_down, x, gates, idx, *, n_experts: int,
     u = jax.lax.ragged_dot(rows, w_up.astype(cd), group_sizes)
     # rows past the last group are not the kernel's to define
     h = jnp.where(valid, jax.nn.silu(g) * u, 0).astype(cd)
-    od = cd if out_dtype is None else out_dtype
     out = jnp.where(valid, jax.lax.ragged_dot(
         h, w_down.astype(cd), group_sizes, preferred_element_type=od), 0)
     out = out[jnp.argsort(order)].reshape(T, k, -1)            # unsorted
-    w = jnp.where(held, gates, 0.0).astype(od)
-    y = jnp.einsum("tkh,tk->th", out, w,
+    y = jnp.einsum("tkh,tk->th", out, w.astype(od),
                    preferred_element_type=jnp.float32).astype(od)
-    return y, counts, n_rows, jnp.sum((sizes > 0).astype(jnp.int32))
+    return y, counts, n_rows, reads, jnp.int32(0)
 
 
 class MoELayer:

@@ -237,6 +237,7 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              CONSTRAINED_REQUESTS, FAULTS_INJECTED,
                              MOE_ASSIGNMENTS_HELD, MOE_ASSIGNMENTS_ROUTED,
                              MOE_EXPERT_LOAD, MOE_EXPERT_READS,
+                             MOE_KERNEL_TILES,
                              MOE_EXPERT_SHARE_PCT, MOE_TOKENS_DROPPED,
                              PREFIX_COW_COPIES, SERVING_DEADLINE_SHEDS,
                              SERVING_DECODE_BLOCKS_LIVE,
@@ -2792,23 +2793,27 @@ class InferenceEngine:
         for parity with training capacity accounting."""
         if self._model.routed:
             # a model that holds a share of its experts: (assignments per
-            # expert over all of them, rows computed here, experts read);
+            # expert over all of them, rows computed here, experts read,
+            # the grouped kernel's row tiles: 0 where it did not run);
             # dropless by construction
-            counts, held, reads = moe_stats
+            counts, held, reads, tiles = moe_stats
             dropped = 0
         else:
             (counts, dropped), held = moe_stats, None
         counts = np.asarray(counts, np.int64)
         total = int(counts.sum())
         if held is not None:
-            held, reads = int(np.asarray(held)), int(np.asarray(reads))
+            held, reads, tiles = (int(np.asarray(v))
+                                  for v in (held, reads, tiles))
             MOE_ASSIGNMENTS_ROUTED.add(total)
             MOE_ASSIGNMENTS_HELD.add(held)
             MOE_EXPERT_READS.add(reads)
+            MOE_KERNEL_TILES.add(tiles)
             if span_args is not None:
                 span_args.update(moe_assignments_routed=total,
                                  moe_assignments_held=held,
-                                 moe_expert_reads=reads)
+                                 moe_expert_reads=reads,
+                                 moe_kernel_tiles=tiles)
         if total > 0:
             shares = counts / total
             MOE_EXPERT_LOAD.set(int(float(shares.max()) * 1e6))

@@ -20,6 +20,9 @@ N = 8
 
 @pytest.fixture(autouse=True)
 def _mesh():
+    # a default group another file left behind in this process holds its
+    # own rank count
+    dist.destroy_process_group()
     mesh = create_mesh(dp=N, devices=jax.devices()[:N])
     yield mesh
     set_mesh(None)

@@ -6,7 +6,9 @@ expert FFN, the router's bias, YaRN by hand, the pool's shape, the
 engine's refusals, the three counters, and that a ``GPTConfig`` engine
 is what it was."""
 import dataclasses
+import importlib.util
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -119,7 +121,7 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(tiny):
     want = ref_logits(params, seq, sizes, len(prompt) - 1, len(seq) - 1)
     np.testing.assert_allclose(np.stack(logits_seen), want, atol=5e-4)
     # the lane that holds no request read no expert: 1 token x 2 layers
-    counts, held, reads = st
+    counts, held, reads, tiles = st
     assert int(counts.sum()) == 1 * cfg.top_k * cfg.n_moe_layers
     gaps, _ = FAMILY.served_gaps(params, prompt, served, sizes, 128)
     # positions with a near tie for the last expert are not judged
@@ -214,14 +216,15 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     d = _expert_layer()
     gates, idx = moe_route_sigmoid(d["rw"], d["rb"], d["x"], top_k=d["k"],
                                    scale=2.5)
-    whole, counts, held, reads = moe_ffn_held(
+    whole, counts, held, reads, tiles = moe_ffn_held(
         d["wg"], d["wu"], d["wd"], d["x"], gates, idx, n_experts=8,
         expert_offset=0, n_held=8)
     assert int(held) == d["T"] * d["k"] == int(counts.sum())
+    assert int(tiles) == 0                  # off the TPU: no kernel ran
     parts, rows = 0.0, 0
     for share in range(4):
         lo = 2 * share
-        y, c, h, r = moe_ffn_held(
+        y, c, h, r, _ = moe_ffn_held(
             d["wg"][lo:lo + 2], d["wu"][lo:lo + 2], d["wd"][lo:lo + 2],
             d["x"], gates, idx, n_experts=8, expert_offset=lo, n_held=2)
         np.testing.assert_array_equal(np.asarray(c), np.asarray(counts))
@@ -271,7 +274,7 @@ def test_grouped_ffn_with_an_empty_and_a_full_expert_and_stacked_layers():
     idx = np.stack([np.full(T, 5), np.where(np.arange(T) % 2, 4, 7)], 1)
     gates = np.random.default_rng(0).random((T, 2)).astype(np.float32)
     stack = lambda w: jnp.concatenate([w[:4] * 0 + 9.0, w[4:], w[:4]])  # noqa
-    y, counts, held, reads = moe_ffn_held(
+    y, counts, held, reads, _ = moe_ffn_held(
         stack(d["wg"]), stack(d["wu"]), stack(d["wd"]), d["x"],
         jnp.asarray(gates), jnp.asarray(idx, jnp.int32), n_experts=8,
         expert_offset=4, n_held=4, group_base=jnp.int32(4))
@@ -282,7 +285,7 @@ def test_grouped_ffn_with_an_empty_and_a_full_expert_and_stacked_layers():
         atol=1e-5)
     # tokens left out (lanes with no request) give nothing and read less
     live = jnp.asarray(np.arange(T) % 2 == 0)
-    y2, c2, h2, r2 = moe_ffn_held(
+    y2, c2, h2, r2, _ = moe_ffn_held(
         d["wg"], d["wu"], d["wd"], d["x"], jnp.asarray(gates),
         jnp.asarray(idx, jnp.int32), n_experts=8, expert_offset=0, n_held=8,
         live=live)
@@ -367,6 +370,18 @@ def test_the_engine_refuses_what_the_model_cannot_do(tiny, kw, sentence):
         _engine(cfg, params, **kw)
 
 
+def _serving_report(events):
+    """``tools/trace_report.py --section serving`` over ``events``."""
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with open(os.devnull, "w") as sink:
+        return mod.serving_report(mod.aggregate(events), file=sink,
+                                  events=events)
+
+
 def test_the_engine_serves_it_and_counts_a_routing_made_by_hand(tiny):
     """Greedy tokens through submit / chunked prefill / paged decode
     equal the full forward's; the three counters and the span arguments
@@ -374,7 +389,7 @@ def test_the_engine_serves_it_and_counts_a_routing_made_by_hand(tiny):
     cfg, sizes, params = tiny
     before = {k: stats.stat_get(k) for k in (
         "moe_assignments_routed", "moe_assignments_held",
-        "moe_expert_reads", "moe_tokens_dropped")}
+        "moe_expert_reads", "moe_kernel_tiles", "moe_tokens_dropped")}
     prompt = np.random.default_rng(7).integers(0, 256, 16).astype(np.int32)
     eng = _engine(cfg, params)
     monitor.start_tracing()
@@ -394,11 +409,11 @@ def test_the_engine_serves_it_and_counts_a_routing_made_by_hand(tiny):
     assert got["moe_tokens_dropped"] == 0
     held = reads = 0
     for start, n in ((0, 16), (16, 1), (17, 1), (18, 1)):
-        _, (c, h, r) = mla_forward(cfg, params,
-                                   jnp.asarray(seq[None, :start + n]))
-        _, (c0, h0, r0) = mla_forward(cfg, params,
-                                      jnp.asarray(seq[None, :start])) \
-            if start else (None, (0, 0, 0))
+        _, (c, h, r, _) = mla_forward(cfg, params,
+                                      jnp.asarray(seq[None, :start + n]))
+        _, (c0, h0, r0, _) = mla_forward(cfg, params,
+                                         jnp.asarray(seq[None, :start])) \
+            if start else (None, (0, 0, 0, 0))
         held += int(h) - int(h0)
     assert got["moe_assignments_held"] == held
     spans = [e for e in events if e.get("ph") == "X" and e["name"] in (
@@ -408,6 +423,14 @@ def test_the_engine_serves_it_and_counts_a_routing_made_by_hand(tiny):
     assert sum(e["args"]["moe_assignments_held"] for e in spans) == held
     assert sum(e["args"]["moe_expert_reads"] for e in spans) \
         == got["moe_expert_reads"] > 0
+    # off the TPU the grouped kernel does not run, and says so
+    assert got["moe_kernel_tiles"] == 0
+    assert all(e["args"]["moe_kernel_tiles"] == 0 for e in spans)
+    report = _serving_report(events)
+    assert report["chunk_moe_expert_reads"] \
+        + report["decode_moe_expert_reads"] == got["moe_expert_reads"]
+    assert report["chunk_moe_kernel_tiles"] == 0 \
+        == report["decode_moe_kernel_tiles"] == report["chunk_moe_reread"]
 
 
 def test_a_gpt_engine_is_what_it_was():

@@ -345,6 +345,34 @@ class TestChunkCounter:
         assert [out["decode_ticks_ahead"], out["decode_ticks_synced"],
                 out["decode_lanes_discarded"]] == counted
 
+    def test_expert_reads_and_kernel_tiles_in_the_serving_report(self):
+        """A model with an expert layer that holds a share of its experts:
+        the report sums the held experts read and the grouped kernel's
+        row tiles by kind of step, and gives their ratio, the weights'
+        re-read factor (0 where the kernel did not run)."""
+        def span(name, tick, reads, tiles):
+            return {"name": name, "ph": "X", "ts": tick, "dur": 1,
+                    "args": {"tick": tick, "moe_expert_reads": reads,
+                             "moe_kernel_tiles": tiles}}
+        events = [span("serving.prefill_chunk", 1, 150, 162),
+                  span("serving.prefill_chunk", 2, 160, 170),
+                  span("serving.decode_step", 3, 40, 40),
+                  span("serving.decode_step", 4, 0, 0)]
+        out = _serving_report(events)
+        assert (out["chunk_moe_expert_reads"], out["chunk_moe_kernel_tiles"],
+                out["decode_moe_expert_reads"],
+                out["decode_moe_kernel_tiles"]) == (310, 332, 40, 40)
+        assert out["chunk_moe_reread"] == pytest.approx(332 / 310)
+        assert out["decode_moe_reread"] == 1.0
+        off = _serving_report([span("serving.decode_step", 1, 12, 0)])
+        assert off["decode_moe_reread"] == 0.0
+        assert "chunk_moe_expert_reads" not in off
+        # a model with no expert layer: nothing of the kind
+        plain = _serving_report([dict(span("serving.decode_step", 1, 0, 0),
+                                      args={"tick": 1})])
+        assert not any(k.startswith(("decode_moe", "chunk_moe"))
+                       for k in plain)
+
     def test_graftlint_gauges_clean(self):
         from paddle_tpu.analysis import run_lint
 
@@ -545,8 +573,8 @@ class TestAnnotation:
 
     def test_pallas_kernels_are_named(self):
         """Every ``pallas_call`` under ops/ bears a ``name=``, and the
-        three names the benchmark's roofline patterns look for are the
-        ones those patterns match."""
+        names the benchmark's roofline patterns look for are the ones
+        those patterns match."""
         import ast
         import glob
         import re
@@ -563,8 +591,10 @@ class TestAnnotation:
                     kw = {k.arg: k.value for k in node.keywords}
                     assert "name" in kw, (path, node.lineno)
                     names.append(kw["name"].value)
-        assert len(names) == 18 and len(set(names)) == 18
+        assert len(names) == 19 and len(set(names)) == 19
         for pattern, kernel in (("flash_forward", "flash_forward"),
+                                ("ragged-dot(?!-metadata)",
+                                 "ragged-dot-experts"),
                                 ("flash_backward", "flash_backward"),
                                 ("_paged_decode", "pallas_paged_decode"),
                                 ("pool_write", "pool_write_rows"),

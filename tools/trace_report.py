@@ -395,7 +395,13 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
     ``serving_decode_ticks_ahead``, the rest
     ``serving_decode_ticks_synced``) and how many lane results were
     never pushed (``lanes_discarded``, the engine's
-    ``serving_decode_lanes_discarded``)."""
+    ``serving_decode_lanes_discarded``), and for a model with an expert
+    layer that holds a share of its experts, by kind of step (``decode``
+    ticks, prefill ``chunk``s), the held experts read
+    (``moe_expert_reads``), the grouped expert kernel's row tiles
+    (``moe_kernel_tiles``, 0 where it did not run) and their ratio, how
+    often a held expert's weights were read (``moe_reread``: 1.0 at
+    best)."""
     pre = [r for r in rows if r["name"] == "serving.prefill"]
     chk = [r for r in rows if r["name"] == "serving.prefill_chunk"]
     dec = [r for r in rows if r["name"] == "serving.decode_step"]
@@ -446,6 +452,18 @@ def serving_report(rows: list, file=None, events: list | None = None) -> dict:
                 decode_ticks_synced=len(ahead) - sum(ahead),
                 decode_lanes_discarded=sum(
                     int(a.get("lanes_discarded", 0)) for a in ticks))
+        for kind, name in (("decode", "serving.decode_step"),
+                           ("chunk", "serving.prefill_chunk")):
+            moe = [e["args"] for e in events if e.get("name") == name
+                   and "moe_expert_reads" in (e.get("args") or {})]
+            reads = sum(int(a["moe_expert_reads"]) for a in moe)
+            if moe:
+                out[f"{kind}_moe_expert_reads"] = reads
+                out[f"{kind}_moe_kernel_tiles"] = sum(
+                    int(a.get("moe_kernel_tiles", 0)) for a in moe)
+            if reads:
+                out[f"{kind}_moe_reread"] = (
+                    out[f"{kind}_moe_kernel_tiles"] / reads)
         paths = [a["sample_path"] for a in ticks if "sample_path" in a]
         for path in ("greedy", "select", "sort") if paths else ():
             out[f"sample_ticks_{path}"] = paths.count(path)
