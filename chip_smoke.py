@@ -546,6 +546,32 @@ def phase_kernels():
               normal((513, 2, 64, 640)), tables, lengths, jnp.int32(1)),
         tol=TOL_BF16)
 
+    # every lane reads its whole table: the walk's live steps fill it
+    # but for its spare entry (the step after the last, whose index maps
+    # the pipeline evaluates)
+    def full_case(bs, W, n_blocks, B=8):
+        tables = rng.permutation(n_blocks - 1)[:B * W] + 1
+        return (tables.reshape(B, W).astype(np.int32),
+                np.full(B, W * bs, np.int32))
+
+    tables, full = full_case(BLOCK, 16, 1025)
+    run("paged_attention.cells.full_lanes",
+        fn=lambda q, kb, vb, t, n, li: pa.paged_attention_arrays(
+            q, kb, vb, t, n, layer=li),
+        ref_fn=lambda q, kb, vb, t, n, li: paged_ref(
+            q, kb[:, 1], vb[:, 1], t, n),
+        args=(normal((8, 16, 128)), normal((1025, 2, 16, BLOCK, 128)),
+              normal((1025, 2, 16, BLOCK, 128)), tables, full,
+              jnp.int32(1)), tol=TOL_BF16)
+    tables, full = full_case(64, 16, 513)
+    run("mla_latent_decode.cells.full_lanes",
+        fn=lambda ql, qr, pool, t, n, li: ma.mla_decode_arrays(
+            ql, qr, pool, t, n, 0.07, li),
+        ref_fn=mla_ref,
+        args=(normal((8, 64, 512)), normal((8, 64, 64)),
+              normal((513, 2, 64, 640)), tables, full, jnp.int32(1)),
+        tol=TOL_BF16)
+
     # -- the decode tick's row writer at the serve cells' blocks: K and V
     # of 16 heads, and the latent row; the lanes of ``live_case`` (a
     # dead lane names the sink and writes nothing), then none live. The
@@ -568,6 +594,7 @@ def phase_kernels():
     for tag, shape, n_arrays in (("heads", (1025, 2, 16, BLOCK, 128), 2),
                                  ("latent", (513, 2, 64, 640), 1)):
         for lanes_tag, n in (("dead_lanes", lengths),
+                             ("all_live", np.maximum(lengths, 1)),
                              ("none_live", np.zeros_like(lengths))):
             run(f"pool_write_rows.cells.{tag}.{lanes_tag}",
                 fn=lambda pools, rows, blk, off, n, li: pw.pool_write_rows(

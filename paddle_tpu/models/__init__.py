@@ -46,6 +46,15 @@ from .retention import (
     retention_prefill_chunk,
     retention_tiny,
 )
+from .sambay import (
+    SambaYConfig,
+    phi4_mini_flash,
+    sambay_decode_step_paged,
+    sambay_init,
+    sambay_param_specs,
+    sambay_prefill_chunk,
+    sambay_tiny,
+)
 from .serving_api import ServingModel
 from .dlrm import (
     DLRMConfig,
@@ -71,6 +80,8 @@ __all__ = [
     "RetentionConfig", "brumby_14b", "retention_init", "retention_forward",
     "retention_prefill_chunk", "retention_decode_step_paged",
     "retention_param_specs", "retention_tiny",
+    "SambaYConfig", "phi4_mini_flash", "sambay_init", "sambay_prefill_chunk",
+    "sambay_decode_step_paged", "sambay_param_specs", "sambay_tiny",
     "ServingModel",
     "DLRMConfig", "dlrm_init", "dlrm_forward", "dlrm_forward_from_emb",
     "dlrm_loss", "dlrm_loss_from_emb", "dlrm_param_specs", "dlrm_score_fn",

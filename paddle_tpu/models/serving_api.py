@@ -9,11 +9,13 @@ from ``state_pad`` whether a block is a run of ``block_size`` tokens
 SEQUENCE'S WHOLE RECURRENT STATE at every layer (fixed size: a slot owns
 exactly one, whatever its length); ``serving/engine.py`` reaches every
 model function through this object, so a further model family needs no
-edit there. The engine serves the target model from the pool only, so a
-new family gives ``pool_spec``, ``param_specs``, ``prefill_chunk`` and
-``decode_step_paged``. An optional step a model lacks is ``None`` here
-and its engine option is named in ``refuses``: the engine raises that
-sentence at construction.
+edit there. A pool may also hold arrays a slot owns whole beside paged
+ones (``slot_spec``; ``models/sambay.py``: window rings and Mamba states
+beside one paged layer). The engine serves the target model from the
+pool only, so a new family gives ``pool_spec``, ``param_specs``,
+``prefill_chunk`` and ``decode_step_paged``. An optional step a model
+lacks is ``None`` here and its engine option is named in ``refuses``:
+the engine raises that sentence at construction.
 """
 from __future__ import annotations
 
@@ -54,6 +56,25 @@ class ServingModel:
     # states), and the int is the granule, in tokens, that a prefill
     # chunk is padded to: the cache's ``block_size``, whatever was asked
     state_pad: Optional[int] = None
+    # (cfg, n_slots, block_size) -> tuple of jax.ShapeDtypeStruct: arrays
+    # a SLOT owns whole, whatever its length (a recurrent layer's state,
+    # a window layer's ring), laid out as the model reads them (by slot,
+    # not by block), placed in the pool after ``pool_spec``'s paged
+    # arrays; a decode lane is its slot, and ``prefill_chunk`` takes the
+    # slot as a further argument, after n_true. None: every array of the
+    # pool is paged
+    slot_spec: Optional[Callable] = None
+    # (cfg) -> the granule, in tokens, a prefill chunk is padded to: a
+    # multiple of block_size (None: block_size)
+    chunk_pad: Optional[Callable] = None
+    # the chunk step takes, last, whether the chunk ends the prompt, and
+    # returns the logits of its last real token only, (1, 1, V): layers
+    # that serve that token alone are skipped in every other chunk
+    last_only: bool = False
+    # (cfg, contexts of a tick's live lanes (n,) int numpy) -> {name:
+    # int}: what a decode tick's span records beside ``state_slots_live``
+    # (a model with ``slot_spec``), each summed by ``serving_<name>``
+    tick_args: Optional[Callable] = None
     # every step function returns router stats as a third element:
     # (assignments per expert (E,), rows computed here, experts read)
     routed: bool = False

@@ -437,6 +437,9 @@ SERVING_DECODE_BLOCKS_TABLED = _registry.get_stat(
     "serving_decode_blocks_tabled")
 SERVING_STATE_SLOTS_LIVE = _registry.get_stat("serving_state_slots_live")
 SERVING_KV_ROWS_WRITTEN = _registry.get_stat("serving_kv_rows_written")
+SERVING_SHARED_KV_CONTEXTS = _registry.get_stat("serving_shared_kv_contexts")
+SERVING_WINDOW_ROWS = _registry.get_stat("serving_window_rows")
+SERVING_CROSS_ROWS = _registry.get_stat("serving_cross_rows")
 SERVING_SAMPLE_TICKS_GREEDY = _registry.get_stat(
     "serving_sample_ticks_greedy")
 SERVING_SAMPLE_TICKS_SELECT = _registry.get_stat(

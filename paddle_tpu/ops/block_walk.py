@@ -13,14 +13,19 @@ the kernels' scalar prefetch:
   0 none;
 - ``count`` is how many steps are live, the kernels' (dynamic) grid
   bound: a step past it never runs;
-- the arrays have the static length ``n_slots * ceil(width / group)``,
-  every slot full; the entries past ``count`` name the last live step
-  again, marked neither first nor last.
+- the arrays have the static length ``n_slots * ceil(width / group) +
+  1``: every slot full, and one step more. The entries past ``count``
+  name the last live step again, marked neither first nor last. The
+  spare one is there for the step after the last: while a step runs,
+  the pipeline evaluates the next step's index maps, and a walk exactly
+  as long as its live steps (every slot reading its whole table) made
+  that evaluation read past the scalar arrays, which halted the core on
+  the chip.
 
 It is the same for every layer of a tick, so a model builds it once,
 outside its layer loop, and hands it to the kernel at each layer.
-Both callers (``ops/paged_attention.py``, ``ops/mla_attention.py``)
-read :func:`step_block` for the step's ``j``-th block, reset their
+The callers (``ops/paged_attention.py``, ``ops/mla_attention.py``,
+``ops/hybrid_attention.py``) read :func:`step_block` for the step's ``j``-th block, reset their
 softmax state where ``first[n]`` is set and write the slot's output row
 where ``last[n]`` is.
 """
@@ -53,7 +58,7 @@ def live_steps(lengths, width: int, block_size: int, group: int) -> LiveSteps:
     steps = -(-blocks // group)
     ends = jnp.cumsum(steps)
     count = ends[-1]
-    every = jnp.arange(n_slots * -(-width // group), dtype=jnp.int32)
+    every = jnp.arange(n_slots * -(-width // group) + 1, dtype=jnp.int32)
     n = jnp.minimum(every, jnp.maximum(count - 1, 0))
     # the slot whose run of steps holds n: as many runs end at or before it
     slot = jnp.minimum(jnp.sum(n[:, None] >= ends[None, :], axis=1),
@@ -80,7 +85,7 @@ def step_block(n, j, tables, lengths, slot, col, block_size: int):
 def check_walk(walk: LiveSteps, n_slots: int, width: int, group: int):
     """Refuse a walk that was built for other slots, another table width
     or another group than the kernel's."""
-    want = n_slots * (width // group)
+    want = n_slots * (width // group) + 1
     if walk.slot.shape[0] != want:
         raise ValueError(
             f"walk of {walk.slot.shape[0]} steps is not decode_walk's for "

@@ -48,9 +48,11 @@ __all__ = ["LiveLanes", "live_lanes", "pool_put", "pool_write_rows",
 
 
 class LiveLanes(NamedTuple):
-    """``lane``: (B,) int32, the live lanes first, in lane order (the
-    entries past ``count`` are 0 and are never read); ``count``: (1,)
-    int32, how many are live."""
+    """``lane``: (B + 1,) int32, the live lanes first, in lane order;
+    the entries past ``count`` are 0, and the one past the last lane
+    keeps a list of every lane live one entry longer than its steps (the
+    spare step of ``ops/block_walk.live_steps``, for the same reason);
+    ``count``: (1,) int32, how many are live."""
     lane: jax.Array
     count: jax.Array
 
@@ -63,7 +65,8 @@ def live_lanes(lengths) -> LiveLanes:
     ids = jnp.arange(live.shape[0], dtype=jnp.int32)
     # a live lane's place in the list: the live lanes before it
     place = jnp.cumsum(live.astype(jnp.int32)) - 1
-    here = live[None, :] & (place[None, :] == ids[:, None])
+    at = jnp.arange(live.shape[0] + 1, dtype=jnp.int32)
+    here = live[None, :] & (place[None, :] == at[:, None])
     lane = jnp.sum(jnp.where(here, ids[None, :], 0), axis=1)
     return LiveLanes(lane.astype(jnp.int32),
                      jnp.sum(live, dtype=jnp.int32).reshape(1))

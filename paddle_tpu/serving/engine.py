@@ -240,6 +240,7 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              MOE_KERNEL_TILES,
                              MOE_EXPERT_SHARE_PCT, MOE_TOKENS_DROPPED,
                              PREFIX_COW_COPIES, SERVING_DEADLINE_SHEDS,
+                             SERVING_CROSS_ROWS,
                              SERVING_DECODE_BLOCKS_LIVE,
                              SERVING_STATE_SLOTS_LIVE,
                              SERVING_DECODE_BLOCKS_TABLED,
@@ -259,7 +260,7 @@ from ..monitor.stats import (CONSTRAINED_FALLBACK_TICKS,
                              SERVING_SHARDS, SERVING_SLOT_OCCUPANCY,
                              SERVING_TOKENS_PER_S,
                              SERVING_WATCHDOG_RESTARTS,
-                             SERVING_WATCHDOG_TRIPS,
+                             SERVING_WATCHDOG_TRIPS, StatRegistry,
                              SPEC_ACCEPTANCE_RATE, SPEC_ACCEPTED,
                              SPEC_PROPOSED)
 from ..resilience import faults as _faults
@@ -289,7 +290,7 @@ _NO_ROOM = object()
 # keep: the models' layers and the sampler (a Pallas kernel's own name,
 # which jax puts on the stack, passes to the scope that called it)
 _SCOPES = frozenset(("embed", "ln", "attn", "kv_pool", "retention", "mlp",
-                     "router", "experts", "head", "sampling"))
+                     "router", "experts", "head", "sampling", "ssm", "gmu"))
 # every span the engine records, as its serving_engine event lists them
 _SPANS = ("serving.turn", "serving.admit", "serving.prefill_chunk",
           "serving.first_token", "serving.decode_prep",
@@ -770,11 +771,18 @@ class InferenceEngine:
         self.cache = self._build_cache()
         self.block_size = self.cache.block_size
         self.max_len = cfg.seq_len   # positional table = per-slot cap
-        if prefill_chunk % self.block_size != 0:
+        # what a chunk is padded to: a block, or the model's own granule
+        self._chunk_pad = self.block_size if self._model.chunk_pad is None \
+            else int(self._model.chunk_pad(cfg))
+        if self._chunk_pad % self.block_size:
+            raise ValueError(
+                f"the model pads a chunk to {self._chunk_pad} tokens, not "
+                f"whole blocks of block_size={self.block_size}")
+        if prefill_chunk % self._chunk_pad != 0:
             raise ValueError(
                 f"prefill_chunk={prefill_chunk} must be a multiple of "
-                f"block_size={self.block_size} (chunks must start "
-                "block-aligned)")
+                f"{self._chunk_pad} (chunks must start block-aligned, and "
+                "the model pads them to that)")
         self.prefill_chunk = int(prefill_chunk)
         # the pool's arrays ride as positional arguments 1..n, all
         # donated: (params, kb, vb, ...) for the per-head pair
@@ -1067,12 +1075,11 @@ class InferenceEngine:
         # returns the chunk logits (only the final chunk's last live row
         # is read); args: the pool's arrays, then (table_row, tokens,
         # start, n_true: how many of the padded chunk's tokens are real,
-        # which a model whose cache is a recurrent state must know); a
-        # routed model's router stats ride last
-        pool, (table_row, tokens, start, n_true) = \
-            args[:self._n_pool], args[self._n_pool:]
-        got = self._model.prefill_chunk(
-            self.cfg, params, pool, table_row, tokens, start, n_true)
+        # which a model whose cache is a recurrent state must know; then
+        # the slot and whether the chunk ends the prompt where the model
+        # takes them); a routed model's router stats ride last
+        pool, rest = args[:self._n_pool], args[self._n_pool:]
+        got = self._model.prefill_chunk(self.cfg, params, pool, *rest)
         return (got[0],) + tuple(got[1]) + tuple(got[2:])
 
     def _chunk_spec_fn(self, params, dparams, kb, vb, dk, dv, table_row,
@@ -2100,13 +2107,14 @@ class InferenceEngine:
         if self.overload is not None:
             # brownout rung 2: shrink chunks so long prompts yield the
             # scheduler to open streams more often (re-rounded to the
-            # block size, floored at one block)
-            chunk_cap = max(self.block_size,
+            # chunk's pad granule, floored at one)
+            chunk_cap = max(self._chunk_pad,
                             (self.overload.prefill_chunk(chunk_cap)
-                             // self.block_size) * self.block_size)
+                             // self._chunk_pad) * self._chunk_pad)
         c_true = min(int(pending.size), chunk_cap)
         bs = self.block_size
-        c_pad = -(-c_true // bs) * bs    # one compile per padded length
+        pad = self._chunk_pad
+        c_pad = -(-c_true // pad) * pad  # one compile per padded length
         if st.tail_mode:
             # prefix-matched slots continue from an UNALIGNED length;
             # clamp the pad so scatter positions never run past the
@@ -2135,6 +2143,10 @@ class InferenceEngine:
         ck_args = {"slot": slot, "start": st.length, "chunk": c_true,
                    "tick": self._ticks,
                    "open_streams": self._open_decode_streams()}
+        if self._model.last_only:
+            # rows the layers that serve the prompt's last token ran
+            ck_args["cross_rows"] = int(last)
+            SERVING_CROSS_ROWS.add(int(last))
         flow = None
         if st.req.trace is not None and recording():
             ck_args.update(st.req.trace.args(rid=st.req.rid))
@@ -2158,6 +2170,10 @@ class InferenceEngine:
                 kind, args = "chunk", (self._params, *self.cache.pool, jnp.asarray(row),
                         jnp.asarray(toks), np.int32(st.length),
                         np.int32(c_true))
+                if self._model.slot_spec is not None:
+                    args += (np.int32(slot),)
+                if self._model.last_only:
+                    args += (np.bool_(last),)
             fn, sig = self._program(kind, c_pad, row.size)
             if TRACING[0]:
                 self._note_program(fn, args, sig)
@@ -2201,8 +2217,9 @@ class InferenceEngine:
         # the chunk (and whatever was queued ahead of it) to finish
         with span("serving.first_token", cat="serving",
                   args=self._tick_args(rid=st.req.rid, slot=slot)):
+            at = 0 if self._model.last_only else c_true - 1
             tok = sample_one(
-                logits[0:1, c_true - 1], self._stream_key(st.req.rid, 0),
+                logits[0:1, at], self._stream_key(st.req.rid, 0),
                 st.req.temperature, st.req.top_k, st.req.top_p,
                 mask=jnp.asarray(self._mask_row(st.req)))
             st.last_token = tok
@@ -2512,6 +2529,16 @@ class InferenceEngine:
                     span_args["kv_rows_written"] = len(active) * layers
                     span_args["kv_rows_grid"] = self.n_slots * layers
                     SERVING_KV_ROWS_WRITTEN.add(len(active) * layers)
+                if self._model.slot_spec is not None:
+                    # every active lane's own states move this tick
+                    span_args["state_slots_live"] = len(active)
+                    SERVING_STATE_SLOTS_LIVE.add(len(active))
+                    if self._model.tick_args is not None:
+                        for k, v in self._model.tick_args(
+                                self.cfg, positions[active] + 1).items():
+                            span_args[k] = v
+                            StatRegistry.instance().get_stat(
+                                "serving_" + k).add(v)
                 fn, sig = self._program("decode", tables.shape[1])
                 args = (self._decode_params, *self.cache.pool, tables,
                         positions, tokens, self._prev_toks, use_prev,

@@ -145,6 +145,14 @@ class PagedKVCache:
     out. The refcount, copy-on-write and splice machinery below serves
     the prefix cache, which such a model refuses.
 
+    A model may also keep arrays a SLOT owns whole beside paged ones
+    (``ServingModel.slot_spec``; ``models/sambay.py``: window rings and
+    Mamba states beside one paged attention layer). They follow the
+    paged arrays in ``pool``, are indexed by slot, not by block, and need
+    no accounting here: a slot's are its own from ``alloc`` to
+    ``release``, and the model's first chunk of a sequence starts them
+    from zero. Blocks, tables and admission are as for any paged model.
+
     Block-major: one (block, layer) pair is one contiguous
     ``(n_heads, block_size, head_dim)`` run, which is what the paged
     steps (models/gpt.py) address — they write the new tokens' rows and
@@ -239,9 +247,13 @@ class PagedKVCache:
                 "blocks (the first block of each shard range is its "
                 "reserved garbage sink)")
         self.dtype = cfg.dtype if dtype is None else dtype
+        specs = model.pool_spec(cfg, self.n_blocks, self.block_size)
+        # a slot's own arrays follow the paged ones, in their own types
+        own = () if model.slot_spec is None \
+            else model.slot_spec(cfg, self.n_slots, self.block_size)
         self.pool = tuple(
             jnp.zeros(a.shape, a.dtype if dtype is None else dtype)
-            for a in model.pool_spec(cfg, self.n_blocks, self.block_size))
+            for a in specs) + tuple(jnp.zeros(a.shape, a.dtype) for a in own)
         self.lengths = np.zeros(self.n_slots, np.int32)
         self.block_tables: List[List[int]] = [[] for _ in range(self.n_slots)]
         # per-shard free lists; the first block of each range is the sink

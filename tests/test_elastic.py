@@ -126,7 +126,7 @@ class TestMembership:
 
 
 WORKER = textwrap.dedent("""
-    import json, os, sys
+    import json, os, sys, time
     sys.path.insert(0, {repo!r})
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
@@ -160,8 +160,30 @@ WORKER = textwrap.dedent("""
         f.write(json.dumps({{"node": node, "rank": rank, "nproc": nproc,
                              "start": start}}) + "\\n")
 
+    # a data-parallel step is a collective: every rank of a pod enters
+    # step i together, so all have restored before rank 0 saves, and
+    # rank 0 has saved step i - 1 before any rank finishes step i. The
+    # workers here share no collective, so files in a directory of the
+    # pod's own stand in for it.
+    pod_dir = os.path.join(workdir, "barrier_" + "".join(
+        c if c.isalnum() else "_"
+        for c in os.environ["PADDLE_TRAINER_ENDPOINTS"]))
+    os.makedirs(pod_dir, exist_ok=True)
+
+    def barrier(step):
+        open(os.path.join(pod_dir, f"{{step}}_{{rank}}"), "w").close()
+        deadline = time.monotonic() + 60
+        while not all(os.path.exists(os.path.join(pod_dir, f"{{step}}_{{r}}"))
+                      for r in range(nproc)):
+            if time.monotonic() > deadline:
+                raise RuntimeError(
+                    f"rank {{rank}}: the pod's other ranks did not reach "
+                    f"step {{step}} within 60 s")
+            time.sleep(0.02)
+
     poison = os.path.join(workdir, "poison_" + node)
     for i in range(start, 4):
+        barrier(i)
         step_obj.params = {{"w": step_obj.params["w"] + 1.0}}
         step_obj._step_count = i
         if rank == 0:

@@ -129,14 +129,17 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(tiny):
 
 
 @pytest.mark.kernels
+@pytest.mark.parametrize("every_slot_full", [False, True])
 @pytest.mark.parametrize("W,group", [(8, 1), (8, 4), (2, 4), (8, 8),
                                      (32, 16)])
-def test_latent_decode_kernel_matches_its_composed_math(W, group):
+def test_latent_decode_kernel_matches_its_composed_math(W, group,
+                                                        every_slot_full):
     """Interpret mode: the Pallas kernel's mathematics on the live walk
     (G blocks a step as one tile, online softmax across a slot's steps,
     blocks past the last live one clamped and masked, a padded row, a
     traced layer), with dead slots between live ones and lengths on a
-    block's and a step's edge."""
+    block's and a step's edge, or with every slot at its table's width
+    (the live steps fill the walk but for its spare entry)."""
     rng = np.random.default_rng(2)
     B, nh, R, Dr, bs, L = 6, 4, 32, 8, 8, 2
     pool = jnp.asarray(rng.standard_normal((B * W + 1, L, bs, 128)),
@@ -144,7 +147,8 @@ def test_latent_decode_kernel_matches_its_composed_math(W, group):
     ql = jnp.asarray(rng.standard_normal((B, nh, R)), jnp.float32)
     qr = jnp.asarray(rng.standard_normal((B, nh, Dr)), jnp.float32)
     full = W * bs
-    lens = np.asarray([0, 1, min(group * bs, full), 0, max(full - 3, 1), full])
+    lens = np.full(B, full) if every_slot_full else np.asarray(
+        [0, 1, min(group * bs, full), 0, max(full - 3, 1), full])
     live = np.arange(W)[None, :] * bs < lens[:, None]
     tables = jnp.asarray(np.where(
         live, 1 + rng.permutation(B * W).reshape(B, W), 0), jnp.int32)

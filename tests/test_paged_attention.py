@@ -158,7 +158,8 @@ class TestLiveWalk:
                 jnp.asarray(lengths, jnp.int32), W, bs, G)
             slot, col, first, last = (np.asarray(a) for a in walk[:4])
             count = int(walk.count[0])
-            N = len(lengths) * -(-W // G)
+            # every slot full, and the spare step past the last
+            N = len(lengths) * -(-W // G) + 1
             assert slot.shape == col.shape == first.shape == last.shape \
                 == (N,) and slot.dtype == np.int32
             assert count == len(want)
@@ -190,16 +191,20 @@ class TestLiveWalkKernel:
     reference: dead slots between live ones, lengths on every edge, a
     traced layer, G from 1 to past the table width."""
 
+    @pytest.mark.parametrize("every_slot_full", [False, True])
     @pytest.mark.parametrize("W,G", [(1, 1), (2, 4), (8, 4), (8, 8),
                                      (32, 8)])
-    def test_matches_reference(self, W, G):
+    def test_matches_reference(self, W, G, every_slot_full):
         L, nh, hd, bs, B = 3, 4, 64, 8, 6
         nb = B * W + 1
         kb = jnp.asarray(RNG.normal(size=(nb, L, nh, bs, hd)), jnp.float32)
         vb = jnp.asarray(RNG.normal(size=(nb, L, nh, bs, hd)), jnp.float32)
         q = jnp.asarray(RNG.normal(size=(B, nh, hd)), jnp.float32)
         full = W * bs
-        lens = [0, min(G * bs, full), 1, 0, full, max(full - 3, 1)]
+        # every slot at its table's width: the live steps fill the walk
+        # but for its spare entry
+        lens = [full] * B if every_slot_full else \
+            [0, min(G * bs, full), 1, 0, full, max(full - 3, 1)]
         live = np.arange(W)[None, :] * bs < np.asarray(lens)[:, None]
         tables = jnp.asarray(np.where(
             live, 1 + RNG.permutation(B * W).reshape(B, W), 0), jnp.int32)
@@ -318,8 +323,10 @@ class TestPoolWriteRows:
         off = ((first_off % bs + 3 * np.arange(B)) % bs).astype(np.int32)
         lanes = live_lanes(jnp.asarray(np.where(alive, 1 + off, 0)))
         assert int(lanes.count[0]) == alive.sum()
-        assert list(np.asarray(lanes.lane)[:alive.sum()]) == \
-            list(np.flatnonzero(alive))
+        # one entry more than lanes, past the live ones a real lane: the
+        # step after the last ("all") reads a lane's block and offset
+        assert np.asarray(lanes.lane).tolist() == \
+            list(np.flatnonzero(alive)) + [0] * (B + 1 - alive.sum())
         layer = L - 1
         got = jax.jit(lambda li: pool_write_rows(
             pools, rows, blk, off, li, lanes=lanes, interpret=True))(

@@ -591,7 +591,7 @@ class TestAnnotation:
                     kw = {k.arg: k.value for k in node.keywords}
                     assert "name" in kw, (path, node.lineno)
                     names.append(kw["name"].value)
-        assert len(names) == 19 and len(set(names)) == 19
+        assert len(names) == 21 and len(set(names)) == 21
         for pattern, kernel in (("flash_forward", "flash_forward"),
                                 ("ragged-dot(?!-metadata)",
                                  "ragged-dot-experts"),
@@ -601,7 +601,10 @@ class TestAnnotation:
                                 ("power_retention_decode",
                                  "power_retention_decode"),
                                 ("power_retention_chunk",
-                                 "power_retention_chunk")):
+                                 "power_retention_chunk"),
+                                ("hybrid_diff_decode", "hybrid_diff_decode"),
+                                ("selective_scan_chunk",
+                                 "selective_scan_chunk")):
             assert [n for n in names if re.search(pattern, n)] == [kernel]
 
 
