@@ -38,12 +38,16 @@ cut-offs (the k-th value, the nucleus's least kept value) among a row's
 parameters that force it (top_p alone, a larger top_k, ties at the k-th
 value that overflow the candidates). The kept set is the same either
 way; only rows that sample AND enable a filter are filtered at all.
+The candidates come from a few groups of columns, not from the whole
+row (:func:`_candidates`): the same values.
 
 Everything here is pure jnp: the engine's eager first-token sample
 (:func:`sample_one`, which picks its path on the host) and its jitted
 ticks run the SAME code.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -105,15 +109,49 @@ def _sort_cutoff(scaled, top_k, top_p):
     return _nucleus_cutoff(desc, kth, top_p)
 
 
+# the grouped candidate search's group: one lane tile of columns
+GROUP_W = 128
+
+
+def _candidates(scaled, n):
+    """A row's ``n`` largest values, descending: (B, V) -> (B, n). The
+    row is viewed as groups of ``w = min(GROUP_W, V // n)`` columns (the
+    last padded with -inf), at least ``n`` of them; the ``n`` groups
+    with the largest maxima are gathered and the values taken among
+    their ``n * w`` columns. On the chip a top_k's time follows the
+    columns it reads, so this reads ``V / w`` maxima and ``n * w``
+    candidates, not the row (PERF.md §6). Exact, ties included: with t
+    the n-th largest value, fewer than n groups hold a value above t,
+    each group whose maximum is t holds one equal to t, and groups are
+    taken by their maxima, so the chosen groups hold the row's n
+    largest values."""
+    return _grouped_top(scaled, n, min(GROUP_W, scaled.shape[-1] // n))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _grouped_top(scaled, n, w):
+    """:func:`_candidates`' search, one program: called eagerly
+    (:func:`sample_one`) it is one dispatch and one compile, not six;
+    inside a jitted step it is inlined."""
+    B, V = scaled.shape
+    G = -(-V // w)
+    groups = jnp.pad(scaled, ((0, 0), (0, G * w - V)),
+                     constant_values=-jnp.inf).reshape(B, G, w)
+    best = jax.lax.top_k(jnp.max(groups, axis=-1), n)[1]          # (B, n)
+    pool = jnp.take_along_axis(groups, best[:, :, None], axis=1)
+    return jax.lax.top_k(pool.reshape(B, n * w), n)[0]
+
+
 def _select_cutoff(scaled, top_k, top_p):
     """The bounded path, for rows with 0 < top_k <= K_CAP: the same two
-    cut-offs found among the row's K_CAP largest entries, no sort of the
-    vocabulary. Returns ``(cutoff (B, 1), fits (B,))``; a row fits when
-    every value >= its k-th is among the candidates (ties at the k-th
-    can overflow them, and the nucleus would then lose mass): only a
-    row that fits may use its cutoff."""
+    cut-offs found among the row's K_CAP largest entries
+    (:func:`_candidates`), no sort of the vocabulary. Returns
+    ``(cutoff (B, 1), fits (B,))``; a row fits when every value >= its
+    k-th is among the candidates (ties at the k-th can overflow them,
+    and the nucleus would then lose mass): only a row that fits may use
+    its cutoff."""
     n = min(K_CAP, scaled.shape[-1])
-    cand = jax.lax.top_k(scaled, n)[0]                    # descending
+    cand = _candidates(scaled, n)                         # descending
     kth = jnp.take_along_axis(
         cand, (jnp.clip(top_k, 1, n) - 1)[:, None], axis=-1)
     fits = jnp.sum(scaled >= kth, axis=-1) <= n

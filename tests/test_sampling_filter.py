@@ -16,7 +16,7 @@ import pytest
 from paddle_tpu.models import gpt_init, gpt_tiny
 from paddle_tpu.serving import InferenceEngine, engine as engine_mod
 from paddle_tpu.serving import sampling
-from paddle_tpu.serving.sampling import K_CAP
+from paddle_tpu.serving.sampling import K_CAP, MASKED_LOGIT
 
 WIDTHS = (1000, 50304)
 
@@ -219,6 +219,93 @@ class TestAgainstTheTwoSortOracle:
         toks = np.asarray(jax.jit(sampling.sample_tokens_streams)(
             lg, keys, t, k, p, mask))
         assert allowed[np.arange(3), toks].all()
+
+
+# -- the grouped candidate search ---------------------------------------------
+
+def _row_kinds(kind, width, seed):
+    """Four rows of one kind: f32, bf16-rounded, mostly MASKED_LOGIT,
+    mostly -inf, or heavy ties (a handful of distinct values)."""
+    x = np.array(_logits(4, width, "bf16" if kind == "bf16" else "f32",
+                         seed))
+    rng = np.random.default_rng(seed)
+    if kind in ("masked", "-inf"):
+        off = rng.random(x.shape) < 0.9995
+        off[0, 40:] = True                # fewer than K_CAP survive
+        x[off] = MASKED_LOGIT if kind == "masked" else -np.inf
+    elif kind == "ties":
+        x = np.round(x)                   # ~15 values over the row
+        x[1, :K_CAP + 75] = x.max() + 1.0   # a tie that overflows
+    return jnp.asarray(x)
+
+
+def _whole_row(s, n):
+    """The candidate search the grouped one replaced: one top_k over
+    the row."""
+    return jax.lax.top_k(s, n)[0]
+
+
+def _top_ks(fn, *args):
+    """The top_k ops in ``fn``'s program for ``args``."""
+    return jax.jit(fn).lower(*args).as_text().count("chlo.top_k(")
+
+
+@pytest.mark.parametrize("kind", ["f32", "bf16", "masked", "-inf", "ties"])
+@pytest.mark.parametrize("width", [50304, 65536, 151936, 200064, 100003])
+def test_grouped_candidates_are_top_k_exactly(width, kind, monkeypatch):
+    """At the benchmark's vocabularies (and one not a multiple of 128)
+    the candidates come from the K_CAP groups with the largest maxima:
+    element for element ``jax.lax.top_k``'s values, and the same
+    cut-offs and ``fits`` as one top_k over the whole row."""
+    x = _row_kinds(kind, width, seed=width % 997)
+    cand = jax.jit(lambda s: sampling._candidates(s, K_CAP))(x)
+    np.testing.assert_array_equal(np.asarray(cand),
+                                  np.asarray(jax.lax.top_k(x, K_CAP)[0]))
+    t, k, p = _params([(0.8, 40, 0.95), (1.0, K_CAP, 1.0),
+                       (0.6, 1, 0.5), (0.8, 7, 0.95)])
+    scaled = sampling._scale(x, t)
+    args = (scaled, k, p)
+    # a fresh jit for each side: the patched module is read while tracing
+    grouped = jax.jit(lambda *a: sampling._select_cutoff(*a))(*args)
+    n_grouped = _top_ks(lambda *a: sampling._select_cutoff(*a), *args)
+    monkeypatch.setattr(sampling, "_candidates", _whole_row)
+    whole = jax.jit(lambda *a: sampling._select_cutoff(*a))(*args)
+    n_whole = _top_ks(lambda *a: sampling._select_cutoff(*a), *args)
+    assert (n_grouped, n_whole) == (2, 1)   # two programs, not one
+    for g, w in zip(grouped, whole):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("rounding", ["f32", "bf16"])
+@pytest.mark.parametrize("width", WIDTHS)
+def test_grouped_and_whole_row_search_sample_the_same_tokens(
+        width, rounding, monkeypatch):
+    """At both ``WIDTHS`` (groups of 15 columns at 1,000, of 128 at
+    50,304) the grouped candidate search and one top_k over the row
+    draw the same token on every row from every key."""
+    lg = _logits(12, width, rounding, seed=width + 4)
+    t, k, p = _params(bounded_rows())
+    keys = jax.random.split(jax.random.key(width), 12)
+    args = (lg, jax.random.split(keys[0], 12), t, k, p)
+    scaled = sampling._scale(lg, t)
+    toks, ops, fits = [], [], []
+    for search in (None, _whole_row):
+        if search is not None:
+            monkeypatch.setattr(sampling, "_candidates", search)
+        # a fresh jit for each side: the patched module is read while
+        # tracing
+        draw = jax.jit(lambda *a: sampling.sample_tokens_streams(*a))
+        ops.append(_top_ks(lambda *a: sampling.sample_tokens_streams(*a),
+                           *args))
+        # the rows that fit, and so keep off the sort, are the same
+        fits.append(np.asarray(jax.jit(
+            lambda *a: sampling._select_cutoff(*a))(scaled, k, p)[1]))
+        toks.append(np.stack([np.asarray(draw(lg, jax.random.split(kk, 12),
+                                               t, k, p))
+                              for kk in keys[:4]]))
+    assert ops[0] > ops[1]          # the two sides are two programs
+    np.testing.assert_array_equal(fits[0], fits[1])
+    np.testing.assert_array_equal(toks[0], toks[1])
 
 
 # -- the entry points ---------------------------------------------------------
